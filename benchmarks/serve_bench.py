@@ -67,7 +67,7 @@ import json
 import os
 
 from benchmarks.common import emit
-from repro.launch.serve import serve_benchmark
+from repro.launch.serve import enable_compile_cache, serve_benchmark
 
 VARIANTS = [
     # name, transform, w_bits, a_bits, kv_bits
@@ -550,6 +550,7 @@ if __name__ == "__main__":
                          "recorded --quick artifact")
     ap.add_argument("--out", default="results/serve_bench.json")
     a = ap.parse_args()
+    enable_compile_cache()
     if a.quick:
         main(n_requests=2, n_slots=2, gen=4, out_path=a.out, quick=True)
     else:

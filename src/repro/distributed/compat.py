@@ -1,66 +1,27 @@
-"""Version compatibility for the jax APIs that moved between releases.
+"""Mesh and shard_map spellings shared by the distributed substrate.
 
-``jax.shard_map`` graduated from ``jax.experimental.shard_map`` and
-``jax.sharding.AxisType`` (with ``jax.make_mesh(..., axis_types=...)``)
-only exists on newer releases. Import from here instead of jax directly so
-the whole distributed substrate works on both sides of the move.
+Every caller builds meshes through ``make_mesh`` so all of them get the
+same Auto axis types (sharding propagated by the compiler, not pinned by
+the type system); the rest are the JAX 0.9 APIs under the names the
+callers import.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
+from jax.sharding import AbstractMesh, AxisType
 
-try:  # jax >= 0.5
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The replication-check kwarg was renamed check_rep -> check_vma.
-_CHECK_KW = next((k for k in ("check_vma", "check_rep")
-                  if k in inspect.signature(_shard_map).parameters), None)
+shard_map = jax.shard_map
+set_mesh = jax.set_mesh
+axis_size = jax.lax.axis_size
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-    """jax.shard_map accepting the modern ``check_vma`` spelling on every
-    jax version (mapped to ``check_rep`` on 0.4.x)."""
-    if check_vma is not None and _CHECK_KW is not None:
-        kw[_CHECK_KW] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-
-
-def set_mesh(mesh):
-    """Context manager making `mesh` ambient. New jax: jax.set_mesh;
-    0.4.x: the Mesh object is itself the context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def axis_size(axis_name):
-    """Size of a mapped mesh axis (jax.lax.axis_size moved here late)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    from jax.core import axis_frame
-    frame = axis_frame(axis_name)
-    return frame if isinstance(frame, int) else frame.size
-
-
-def abstract_mesh(axis_shapes, axis_names):
-    """jax.sharding.AbstractMesh across the signature change: new jax
-    takes (sizes, names); 0.4.x takes a tuple of (name, size) pairs."""
-    from jax.sharding import AbstractMesh
-    params = inspect.signature(AbstractMesh.__init__).parameters
-    if "axis_names" in params:
-        return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
-    return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
+def abstract_mesh(axis_shapes, axis_names) -> AbstractMesh:
+    """A device-free mesh of the given axis sizes and names."""
+    return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """jax.make_mesh with Auto axis types where the kwarg exists."""
+    """jax.make_mesh with Auto axis types."""
     kw = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kw["axis_types"] = (axis_type.Auto,) * len(axis_names)
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names), **kw)

@@ -78,24 +78,31 @@ def pick(key: tuple, m: int, d: int, n: int, packed: bool,
 
     With ``REPRO_AUTOTUNE=measure`` and a ``run`` callback, times each
     candidate (one warmup + one timed call) and caches the fastest;
-    otherwise caches the heuristic.
+    otherwise caches the heuristic. A candidate the backend refuses is
+    skipped; when every candidate is refused the kernel cannot run at
+    this shape, and the last refusal is raised instead of falling back
+    to a block size that was never shown to compile.
     """
     hit = _CACHE.get(key)
     if hit is not None:
         return hit
     choice = heuristic_blocks(m, d, n, packed)
     if run is not None and os.environ.get("REPRO_AUTOTUNE") == "measure":
-        best_t = None
+        best_t, err = None, None
         for cand in _candidates(m, d, n, packed):
             try:
                 run(cand)           # compile + warm
                 t0 = time.perf_counter()
                 run(cand)
                 dt = time.perf_counter() - t0
-            except Exception:       # candidate invalid on this backend
+            except Exception as e:  # noqa: BLE001 - candidate refused here
+                err = e
                 continue
             if best_t is None or dt < best_t:
                 best_t, choice = dt, cand
+        if best_t is None:
+            raise RuntimeError(
+                f"every block-size candidate failed for {key}") from err
     _CACHE[key] = choice
     return choice
 

@@ -2,9 +2,11 @@
 
 y[..., i·k:(i+1)·k] = x[..., i·k:(i+1)·k] @ B_iᵀ  for blocks (n, k, k).
 
-With the paper's k=128 each block is exactly one MXU tile; the grid walks
-(token-tile × block) so a block matrix is loaded once per token tile and
-the working set stays tiny (TM·k in + k² weights + TM·k out).
+The grid walks (token-tile × lane group). A lane group holds g whole
+blocks spanning a multiple of 128 lanes (Mosaic's lane tile), so CAT
+blocks narrower than 128 (k=32 → g=4) still tile; with the paper's k=128
+each group is exactly one block and one MXU tile. The working set stays
+tiny (TM·g·k in + g·k² weights + TM·g·k out).
 """
 from __future__ import annotations
 
@@ -16,10 +18,11 @@ from jax.experimental import pallas as pl
 
 
 def _bdm_kernel(x_ref, b_ref, o_ref):
-    x = x_ref[...].astype(jnp.float32)           # (TM, k)
-    b = b_ref[0].astype(jnp.float32)             # (k, k)
-    o_ref[...] = jnp.dot(x, b.T, preferred_element_type=jnp.float32
-                         ).astype(o_ref.dtype)
+    x = x_ref[...].astype(jnp.float32)           # (TM, g·k)
+    g, k, _ = b_ref.shape
+    parts = [jnp.dot(x[:, i * k:(i + 1) * k], b_ref[i].astype(jnp.float32).T,
+                     preferred_element_type=jnp.float32) for i in range(g)]
+    o_ref[...] = jnp.concatenate(parts, axis=1).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_tokens", "interpret"))
@@ -36,15 +39,19 @@ def block_diag_matmul(x: jnp.ndarray, blocks: jnp.ndarray,
     pad = (-m) % tm
     if pad:
         xf = jnp.pad(xf, ((0, pad), (0, 0)))
-    grid = (xf.shape[0] // tm, n)
+    # fewest whole blocks per lane group that fill 128-lane tiles; the
+    # whole row when none divides n
+    g = next((g for g in range(1, n + 1) if n % g == 0 and g * k % 128 == 0),
+             n)
+    grid = (xf.shape[0] // tm, n // g)
     out = pl.pallas_call(
         _bdm_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tm, k), lambda i, j: (i, j)),
-            pl.BlockSpec((1, k, k), lambda i, j: (j, 0, 0)),
+            pl.BlockSpec((tm, g * k), lambda i, j: (i, j)),
+            pl.BlockSpec((g, k, k), lambda i, j: (j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((tm, k), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((tm, g * k), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(xf.shape, x.dtype),
         interpret=interpret,
     )(xf, blocks)

@@ -53,33 +53,37 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .fused_cat_matmul import _prep_operands, _transform_quant
-from .quant_matmul_w4 import _GEMV_M, _unpack_block
+from .quant_matmul_w4 import _GEMV_M, _unpack_block, int8_contract
 
 
 def _rope_rows(y, pos_f32, head_dim: int, theta: float):
     """RoPE over flat (M, H*hd) rows with per-row f32 positions (M, 1) —
-    mirrors ``models.layers.rope`` op for op (all f32)."""
+    mirrors ``models.layers.rope`` op for op (all f32). Heads are static
+    lane slices of the flat row: Mosaic cannot split the lane axis into
+    (H, hd) for hd < 128."""
     m, hn = y.shape
     half = head_dim // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    ang = pos_f32 * freq[None, :]               # (M, half)
-    cos = jnp.cos(ang)[:, None, :]              # (M, 1, half)
-    sin = jnp.sin(ang)[:, None, :]
-    yh = y.reshape(m, hn // head_dim, head_dim)
-    x1, x2 = yh[..., :half], yh[..., half:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                          axis=-1)
-    return out
+    idx = jax.lax.broadcasted_iota(jnp.int32, (1, half), 1)
+    freq = theta ** (-idx.astype(jnp.float32) / half)
+    ang = pos_f32 * freq                        # (M, half)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    parts = []
+    for h in range(0, hn, head_dim):
+        x1, x2 = y[:, h:h + half], y[:, h + half:h + head_dim]
+        parts += [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    return jnp.concatenate(parts, axis=-1)
 
 
 def _quantize_kv_rows(t, bits: int):
-    """``models.layers.quantize_kv`` op for op: symmetric per-(row, head)
-    int8 codes + f32 scales over the last axis."""
+    """``models.layers.quantize_kv`` op for op: symmetric codes + f32
+    scales over the last axis of one head's (M, hd) rows. The codes stay
+    f32 (integral values) until the scatter casts them to the pool's
+    int8: Mosaic stores a head's rows into the (M, KVH, hd) scratch only
+    for 32-bit values."""
     qmax = 2.0 ** (bits - 1) - 1
     amax = jnp.max(jnp.abs(t), axis=-1, keepdims=True)
     scale = jnp.maximum(amax, 1e-12) / qmax
-    codes = jnp.clip(jnp.round(t / scale), -qmax - 1, qmax).astype(jnp.int8)
-    return codes, scale
+    return jnp.clip(jnp.round(t / scale), -qmax - 1, qmax), scale
 
 
 def _make_prologue_kernel(*, act_bits: int, packed: bool, has_blocks: bool,
@@ -114,12 +118,8 @@ def _make_prologue_kernel(*, act_bits: int, packed: bool, has_blocks: bool,
 
         @pl.when(r == 0)
         def _contract():
-            qx = qx_ref[:, pl.ds(kk * tk, tk)].astype(jnp.int32)
-            qw = (_unpack_block(w_ref[...]) if packed
-                  else w_ref[...].astype(jnp.int32))
-            acc = jnp.dot(qx, qw,
-                          preferred_element_type=jnp.int32).astype(jnp.float32)
-            colsum = jnp.sum(qw, axis=0, keepdims=True).astype(jnp.float32)
+            qw = _unpack_block(w_ref[...]) if packed else w_ref[...]
+            acc, colsum = int8_contract(qx_ref[:, pl.ds(kk * tk, tk)], qw)
             part = sx_ref[...] * sw_ref[...] * (acc - zx_ref[...] * colsum)
 
             @pl.when(kk == 0)
@@ -134,23 +134,21 @@ def _make_prologue_kernel(*, act_bits: int, packed: bool, has_blocks: bool,
         def _epilogue():
             y = acc_ref[...]                        # (8, N_pad) f32
             posf = pos_ref[...].astype(jnp.float32)  # (8, 1)
-            qo_ref[...] = _rope_rows(y[:, :n_q], posf, head_dim,
-                                     rope_theta).reshape(_GEMV_M, n_q)
+            qo_ref[...] = _rope_rows(y[:, :n_q], posf, head_dim, rope_theta)
             k = _rope_rows(y[:, n_q:n_q + n_kv], posf, head_dim, rope_theta)
-            v = y[:, n_q + n_kv:n_q + 2 * n_kv].reshape(_GEMV_M, kvh,
-                                                        head_dim)
-            kq, ks = _quantize_kv_rows(k, kv_bits)
-            vq, vs = _quantize_kv_rows(v, kv_bits)
-            kq_ref[...] = kq
-            ks_ref[...] = ks
-            vq_ref[...] = vq
-            vs_ref[...] = vs
+            v = y[:, n_q + n_kv:n_q + 2 * n_kv]
+            for h in range(kvh):
+                cols = slice(h * head_dim, (h + 1) * head_dim)
+                kq_ref[:, h, :], ks_ref[:, h, :] = _quantize_kv_rows(
+                    k[:, cols], kv_bits)
+                vq_ref[:, h, :], vs_ref[:, h, :] = _quantize_kv_rows(
+                    v[:, cols], kv_bits)
 
         @pl.when(last_jk)
         def _scatter():
-            ko_ref[...] = kq_ref[r][None, None]     # (1, 1, KVH, hd)
-            kso_ref[...] = ks_ref[r][None, None]
-            vo_ref[...] = vq_ref[r][None, None]
+            ko_ref[...] = kq_ref[r].astype(ko_ref.dtype)[None, None]
+            kso_ref[...] = ks_ref[r][None, None]    # (1, 1, KVH, 1)
+            vo_ref[...] = vq_ref[r].astype(vo_ref.dtype)[None, None]
             vso_ref[...] = vs_ref[r][None, None]
 
     return kernel
@@ -197,8 +195,8 @@ def decode_qkv_prologue(x, blocks, ha, hb, sign, qw, sw,
     tk = min(block_k, d + d % 2)
     tk += tk % 2
     tn = min(block_n, n)
-    x, qw, sw, dims = _prep_operands(x, blocks, ha, hb, sign, qw, sw,
-                                     packed, _GEMV_M, tn, tk)
+    x, sign, qw, sw, dims = _prep_operands(x, blocks, ha, hb, sign, qw, sw,
+                                           packed, _GEMV_M, tn, tk)
     k_pad, n_pad = dims["k_pad"], qw.shape[1]
     gn = n_pad // tn
     gk = k_pad // tk
@@ -230,7 +228,7 @@ def decode_qkv_prologue(x, blocks, ha, hb, sign, qw, sw,
 
     in_specs = [
         pl.BlockSpec((_GEMV_M, d), lambda j, kk, r, pid, rid: (0, 0)),
-        pl.BlockSpec((d,), lambda j, kk, r, pid, rid: (0,)),
+        pl.BlockSpec((1, d), lambda j, kk, r, pid, rid: (0, 0)),
         pl.BlockSpec(ha.shape, lambda j, kk, r, pid, rid: (0, 0)),
         pl.BlockSpec(hb.shape, lambda j, kk, r, pid, rid: (0, 0)),
     ]
@@ -270,9 +268,9 @@ def decode_qkv_prologue(x, blocks, ha, hb, sign, qw, sw,
             pltpu.VMEM((_GEMV_M, 1), jnp.float32),       # act scale
             pltpu.VMEM((_GEMV_M, 1), jnp.float32),       # act zero point
             pltpu.VMEM((_GEMV_M, n_pad), jnp.float32),   # qkv accumulator
-            pltpu.VMEM((_GEMV_M, kvh, hd), jnp.int8),    # k codes
+            pltpu.VMEM((_GEMV_M, kvh, hd), jnp.float32),  # k codes
             pltpu.VMEM((_GEMV_M, kvh, 1), jnp.float32),  # k scales
-            pltpu.VMEM((_GEMV_M, kvh, hd), jnp.int8),    # v codes
+            pltpu.VMEM((_GEMV_M, kvh, hd), jnp.float32),  # v codes
             pltpu.VMEM((_GEMV_M, kvh, 1), jnp.float32),  # v scales
         ],
     )
@@ -288,6 +286,7 @@ def decode_qkv_prologue(x, blocks, ha, hb, sign, qw, sw,
         ],
         input_output_aliases={pool0: 1, pool0 + 1: 2, pool0 + 2: 3,
                               pool0 + 3: 4},
+        name="decode_qkv_prologue",
         interpret=interpret,
     )(page_ids, row_ids, *operands)
     return q8[:m], kp, ksc, vp, vsc

@@ -14,7 +14,7 @@ Dataflow per M-tile (grid (gm, gn, gk); K fastest, TPU iteration order):
     (j == 0 and kk == 0):                      # once per M-tile
         x (TM, D) --HBM--> VMEM
         block-CAT (static per-block dots) -> ⊙ combined-sign
-        -> Hadamard (two Kronecker-factor dots)
+        -> Hadamard (``hadamard.kron_rows``: two batched factor dots)
         -> per-token min/max -> scale/zp -> int8 codes
         -> qx scratch (TM, K_pad) int8, sx/zx scratch (TM, 1) f32
     every (j, kk):                             # the contraction
@@ -49,7 +49,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .quant_matmul_w4 import _GEMV_M, _unpack_block
+from .hadamard import kron_rows
+from .quant_matmul_w4 import _GEMV_M, _unpack_block, int8_contract
 
 
 def _transform_quant(x_ref, sign_ref, ha_ref, hb_ref, blocks_ref,
@@ -73,15 +74,8 @@ def _transform_quant(x_ref, sign_ref, ha_ref, hb_ref, blocks_ref,
     # combined elementwise vector: Hadamard randomization sign, with any
     # diagonal (Scale) CAT factor folded in by the dispatcher
     x = x * sign_ref[...].astype(jnp.float32)
-    a = ha_ref.shape[0]
-    b = hb_ref.shape[0]
-    ha = ha_ref[...].astype(jnp.float32)
-    hb = hb_ref[...].astype(jnp.float32)
-    y = jnp.dot(x.reshape(tm * a, b), hb.T,
-                preferred_element_type=jnp.float32)
-    y = y.reshape(tm, a, b).swapaxes(1, 2).reshape(tm * b, a)
-    y = jnp.dot(y, ha.T, preferred_element_type=jnp.float32)
-    y = y.reshape(tm, b, a).swapaxes(1, 2).reshape(tm, d)
+    y = kron_rows(x, ha_ref[...].astype(jnp.float32),
+                  hb_ref[...].astype(jnp.float32))
     # dynamic per-token asymmetric quant (ref.dynamic_quant semantics)
     levels = 2.0 ** act_bits - 1
     ymin = jnp.min(y, axis=-1, keepdims=True)
@@ -106,10 +100,9 @@ def _contract(qx_ref, sx_ref, zx_ref, w_ref, sw_ref, o_ref, *, kk, tk,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    qx = qx_ref[:, pl.ds(kk * tk, tk)].astype(jnp.int32)
-    qw = _unpack_block(w_ref[...]) if packed else w_ref[...].astype(jnp.int32)
-    acc = jnp.dot(qx, qw, preferred_element_type=jnp.int32).astype(jnp.float32)
-    colsum = jnp.sum(qw, axis=0, keepdims=True).astype(jnp.float32)
+    qx = qx_ref[:, pl.ds(kk * tk, tk)]
+    qw = _unpack_block(w_ref[...]) if packed else w_ref[...]
+    acc, colsum = int8_contract(qx, qw)
     o_ref[...] += (sx_ref[...] * sw_ref[...]
                    * (acc - zx_ref[...] * colsum)).astype(o_ref.dtype)
 
@@ -143,7 +136,8 @@ def _make_kernel(act_bits: int, packed: bool, has_blocks: bool, tk: int,
 
 
 def _prep_operands(x, blocks, ha, hb, sign, qw, sw, packed, tm, tn, tk):
-    """Shared padding/validation -> (padded operands, dims dict)."""
+    """Shared padding/validation -> (padded operands, dims dict); the
+    sign vector comes back as a (1, D) row."""
     m, d = x.shape
     if packed:
         k2, n = qw.shape
@@ -165,7 +159,7 @@ def _prep_operands(x, blocks, ha, hb, sign, qw, sw, packed, tm, tn, tk):
         pk_rows = pk // 2 if packed else pk
         qw = jnp.pad(qw, ((0, pk_rows), (0, pn)))
         sw = jnp.pad(sw, ((0, 0), (0, pn)), constant_values=1.0)
-    return x, qw, sw, dict(m=m, d=d, n=n, k_pad=k0 + pk)
+    return x, sign.reshape(1, d), qw, sw, dict(m=m, d=d, n=n, k_pad=k0 + pk)
 
 
 @functools.partial(jax.jit, static_argnames=("act_bits", "packed",
@@ -191,8 +185,8 @@ def fused_cat_matmul_w4(x, blocks, ha, hb, sign, qw, sw, *,
     tk = min(block_k, d + d % 2)
     tk += tk % 2
     tn = min(block_n, qw.shape[1])
-    x, qw, sw, dims = _prep_operands(x, blocks, ha, hb, sign, qw, sw,
-                                     packed, tm, tn, tk)
+    x, sign, qw, sw, dims = _prep_operands(x, blocks, ha, hb, sign, qw, sw,
+                                           packed, tm, tn, tk)
     k_pad, n = dims["k_pad"], dims["n"]
     gm = x.shape[0] // tm
     gn = qw.shape[1] // tn
@@ -201,7 +195,7 @@ def fused_cat_matmul_w4(x, blocks, ha, hb, sign, qw, sw, *,
     kern = _make_kernel(act_bits, packed, has_blocks, tk, k_pad, gemv=False)
     in_specs = [
         pl.BlockSpec((tm, d), lambda i, j, kk: (i, 0)),
-        pl.BlockSpec((d,), lambda i, j, kk: (0,)),
+        pl.BlockSpec((1, d), lambda i, j, kk: (0, 0)),
         pl.BlockSpec(ha.shape, lambda i, j, kk: (0, 0)),
         pl.BlockSpec(hb.shape, lambda i, j, kk: (0, 0)),
     ]
@@ -224,6 +218,7 @@ def fused_cat_matmul_w4(x, blocks, ha, hb, sign, qw, sw, *,
         scratch_shapes=[pltpu.VMEM((tm, k_pad), jnp.int8),
                         pltpu.VMEM((tm, 1), jnp.float32),
                         pltpu.VMEM((tm, 1), jnp.float32)],
+        name="fused_cat_matmul_w4",
         interpret=interpret,
     )(*operands)
     return out[:m, :n]
@@ -246,8 +241,8 @@ def fused_cat_gemv_w4(x, blocks, ha, hb, sign, qw, sw, *,
     tk = min(block_k, d + d % 2)
     tk += tk % 2
     tn = min(block_n, qw.shape[1])
-    x, qw, sw, dims = _prep_operands(x, blocks, ha, hb, sign, qw, sw,
-                                     packed, _GEMV_M, tn, tk)
+    x, sign, qw, sw, dims = _prep_operands(x, blocks, ha, hb, sign, qw, sw,
+                                           packed, _GEMV_M, tn, tk)
     k_pad, n = dims["k_pad"], dims["n"]
     gn = qw.shape[1] // tn
     gk = k_pad // tk
@@ -255,7 +250,7 @@ def fused_cat_gemv_w4(x, blocks, ha, hb, sign, qw, sw, *,
     kern = _make_kernel(act_bits, packed, has_blocks, tk, k_pad, gemv=True)
     in_specs = [
         pl.BlockSpec((_GEMV_M, d), lambda j, kk: (0, 0)),
-        pl.BlockSpec((d,), lambda j, kk: (0,)),
+        pl.BlockSpec((1, d), lambda j, kk: (0, 0)),
         pl.BlockSpec(ha.shape, lambda j, kk: (0, 0)),
         pl.BlockSpec(hb.shape, lambda j, kk: (0, 0)),
     ]
@@ -277,6 +272,7 @@ def fused_cat_gemv_w4(x, blocks, ha, hb, sign, qw, sw, *,
         scratch_shapes=[pltpu.VMEM((_GEMV_M, k_pad), jnp.int8),
                         pltpu.VMEM((_GEMV_M, 1), jnp.float32),
                         pltpu.VMEM((_GEMV_M, 1), jnp.float32)],
+        name="fused_cat_gemv_w4",
         interpret=interpret,
     )(*operands)
     return out[:m, :n]

@@ -134,6 +134,7 @@ def paged_attention_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
         functools.partial(_paged_attn_kernel, page_size=page_size),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
+        name="paged_attention_decode",
         interpret=interpret,
     )(lengths, page_table, qs, k_pages, k_scale, v_pages, v_scale)
 
@@ -152,37 +153,37 @@ def _ragged_attn_kernel(len_ref, pt_ref, q_ref, qpos_ref, k_ref, ks_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)              # (Q, KVH, g, hd)
-    qpos = qpos_ref[0]                            # (Q,) absolute positions
-    k = k_ref[0].astype(jnp.float32) * ks_ref[0]  # (G, KVH, hd) dequant
-    v = v_ref[0].astype(jnp.float32) * vs_ref[0]
+    q = q_ref[0].astype(jnp.float32)              # (KVH, Q·g, hd)
+    qpos = qpos_ref[0]                            # (Q·g, 1) absolute positions
+    # dequantize, then put the kv head first: (G, KVH, hd) -> (KVH, G, hd)
+    k = jnp.swapaxes(k_ref[0].astype(jnp.float32) * ks_ref[0], 0, 1)
+    v = jnp.swapaxes(v_ref[0].astype(jnp.float32) * vs_ref[0], 0, 1)
 
-    # scores for this page: (Q, KVH, g, G)
-    s = jnp.einsum("qkgd,Gkd->qkgG", q, k,
-                   preferred_element_type=jnp.float32)
-    kv_pos = i * page_size + jax.lax.iota(jnp.int32, page_size)
+    # scores for this page, batched over kv heads: (KVH, Q·g, G)
+    s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32)
+    kv_pos = i * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (1, page_size), 1)
     # per-(query, kv) causal mask inside the chunk: a prefill row at
     # position p sees exactly kv_pos <= p (its same-step chunk-mates
     # beyond p were already written but stay masked); padded query rows
     # (qpos < 0) mask everything and their garbage output is discarded
-    mask = ((kv_pos[None, :] <= qpos[:, None])
-            & (kv_pos[None, :] < len_ref[b])
-            & (qpos[:, None] >= 0))
-    s = jnp.where(mask[:, None, None, :], s, _NEG_INF)
+    mask = (kv_pos <= qpos) & (kv_pos < len_ref[b]) & (qpos >= 0)
+    s = jnp.where(mask[None], s, _NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[..., None])
+    m_prev = m_ref[...]                           # (KVH, Q·g, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
     m_ref[...] = m_new
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-    acc_ref[...] = (acc_ref[...] * corr[..., None]
-                    + jnp.einsum("qkgG,Gkd->qkgd", p, v,
-                                 preferred_element_type=jnp.float32))
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = (acc_ref[...] * corr
+                    + jax.lax.dot_general(p, v, (((2,), (1,)), ((0,), (0,))),
+                                          preferred_element_type=jnp.float32))
 
     @pl.when(i == pl.num_programs(1) - 1)
     def _finish():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         o_ref[...] = out.astype(o_ref.dtype)[None]
 
 
@@ -207,6 +208,9 @@ def paged_attention_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
                 padding rows (fully masked, output garbage — discard)
     -> (B, Q, KVH, g, hd) in q's dtype. ``q_len=1`` with
     ``q_pos = lengths - 1`` reproduces ``paged_attention_decode``.
+
+    Inside the kernel each kv head's queries form one (Q·g, hd) matrix,
+    so both contractions are matmuls batched over the kv head.
     """
     b, nq, kvh, g, hd = q.shape
     n_pages, page_size, kvh_p, _ = k_pages.shape
@@ -215,14 +219,18 @@ def paged_attention_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
     assert page_table.shape[0] == b and lengths.shape == (b,)
     assert q_pos.shape == (b, nq), (q_pos.shape, q.shape)
 
+    qg = nq * g
     qs = (q.astype(jnp.float32) * hd ** -0.5).astype(q.dtype)
+    qs = qs.transpose(0, 2, 1, 3, 4).reshape(b, kvh, qg, hd)
+    # one position per (query, group) row, as a column
+    qpos = jnp.repeat(q_pos, g, axis=1)[:, :, None]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,      # lengths, page_table
         grid=(b, n_ptab),
         in_specs=[
-            pl.BlockSpec((1, nq, kvh, g, hd),
-                         lambda bb, i, ln, pt: (bb, 0, 0, 0, 0)),
-            pl.BlockSpec((1, nq), lambda bb, i, ln, pt: (bb, 0)),
+            pl.BlockSpec((1, kvh, qg, hd),
+                         lambda bb, i, ln, pt: (bb, 0, 0, 0)),
+            pl.BlockSpec((1, qg, 1), lambda bb, i, ln, pt: (bb, 0, 0)),
             pl.BlockSpec((1, page_size, kvh, hd),
                          lambda bb, i, ln, pt: (pt[bb, i], 0, 0, 0)),
             pl.BlockSpec((1, page_size, kvh, 1),
@@ -232,20 +240,22 @@ def paged_attention_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
             pl.BlockSpec((1, page_size, kvh, 1),
                          lambda bb, i, ln, pt: (pt[bb, i], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, nq, kvh, g, hd),
-                               lambda bb, i, ln, pt: (bb, 0, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvh, qg, hd),
+                               lambda bb, i, ln, pt: (bb, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((nq, kvh, g), jnp.float32),       # running max
-            pltpu.VMEM((nq, kvh, g), jnp.float32),       # running denom
-            pltpu.VMEM((nq, kvh, g, hd), jnp.float32),   # running numerator
+            pltpu.VMEM((kvh, qg, 1), jnp.float32),       # running max
+            pltpu.VMEM((kvh, qg, 1), jnp.float32),       # running denom
+            pltpu.VMEM((kvh, qg, hd), jnp.float32),      # running numerator
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_ragged_attn_kernel, page_size=page_size),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nq, kvh, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, qg, hd), q.dtype),
+        name="paged_attention_ragged",
         interpret=interpret,
-    )(lengths, page_table, qs, q_pos, k_pages, k_scale, v_pages, v_scale)
+    )(lengths, page_table, qs, qpos, k_pages, k_scale, v_pages, v_scale)
+    return out.reshape(b, kvh, nq, g, hd).transpose(0, 2, 1, 3, 4)
 
 
 def paged_attention_ragged_fallback(q: jnp.ndarray, k_pages, k_scale,

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .quant_matmul_w4 import int8_contract
+
 
 def _qmm_kernel(x_ref, sx_ref, zx_ref, w_ref, sw_ref, o_ref):
     k = pl.program_id(2)
@@ -27,10 +29,7 @@ def _qmm_kernel(x_ref, sx_ref, zx_ref, w_ref, sw_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    qx = x_ref[...].astype(jnp.int32)
-    qw = w_ref[...].astype(jnp.int32)
-    acc = jnp.dot(qx, qw, preferred_element_type=jnp.int32).astype(jnp.float32)
-    colsum = jnp.sum(qw, axis=0, keepdims=True).astype(jnp.float32)
+    acc, colsum = int8_contract(x_ref[...], w_ref[...])
     sx = sx_ref[...]
     zx = zx_ref[...]
     sw = sw_ref[...]

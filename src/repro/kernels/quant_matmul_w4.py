@@ -31,12 +31,22 @@ from jax.experimental import pallas as pl
 
 
 def _unpack_block(pw: jnp.ndarray) -> jnp.ndarray:
-    """(TK//2, TN) packed int8 -> (TK, TN) int32 codes in [-8, 7]."""
+    """(TK//2, TN) packed int8 -> (TK, TN) int8 codes in [-8, 7]."""
     p = pw.astype(jnp.int32)
     lo = ((p & 0xF) ^ 8) - 8
     hi = (((p >> 4) & 0xF) ^ 8) - 8
     tk2, tn = p.shape
-    return jnp.stack([lo, hi], axis=1).reshape(2 * tk2, tn)
+    return jnp.stack([lo, hi], axis=1).reshape(2 * tk2, tn).astype(jnp.int8)
+
+
+def int8_contract(qx: jnp.ndarray, qw: jnp.ndarray):
+    """int8 codes (TM, TK) @ (TK, TN) -> (f32 acc, f32 (1, TN) colsum).
+
+    Both operands stay int8 into the dot: Mosaic lowers only int8 × int8
+    -> int32 to the MXU and refuses a dot on int32 vectors."""
+    acc = jnp.dot(qx, qw, preferred_element_type=jnp.int32)
+    colsum = jnp.sum(qw.astype(jnp.int32), axis=0, keepdims=True)
+    return acc.astype(jnp.float32), colsum.astype(jnp.float32)
 
 
 def _w4_accumulate(x_ref, sx_ref, zx_ref, w_ref, sw_ref, o_ref, k):
@@ -47,10 +57,7 @@ def _w4_accumulate(x_ref, sx_ref, zx_ref, w_ref, sw_ref, o_ref, k):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    qx = x_ref[...].astype(jnp.int32)
-    qw = _unpack_block(w_ref[...])
-    acc = jnp.dot(qx, qw, preferred_element_type=jnp.int32).astype(jnp.float32)
-    colsum = jnp.sum(qw, axis=0, keepdims=True).astype(jnp.float32)
+    acc, colsum = int8_contract(x_ref[...], _unpack_block(w_ref[...]))
     sx = sx_ref[...]
     zx = zx_ref[...]
     sw = sw_ref[...]

@@ -7,6 +7,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from .hadamard import kron_rows
+
 
 def hadamard_transform(x: jnp.ndarray, ha: jnp.ndarray, hb: jnp.ndarray,
                        sign: jnp.ndarray | None = None) -> jnp.ndarray:
@@ -161,15 +163,14 @@ def kernel_transform_quant(x, blocks, ha, hb, sign, *, act_bits: int = 8):
     """CAT transform + dynamic quant in the KERNEL's exact op order.
 
     Mirrors ``fused_cat_matmul._transform_quant`` operation for operation
-    (per-block dots, two Kronecker-factor dots with the same
-    reshape/transpose walk, then ``dynamic_quant`` rounding) instead of
+    (per-block dots, the kernels' own ``hadamard.kron_rows`` walk, then
+    ``dynamic_quant`` rounding) instead of
     ``hadamard_transform``'s single einsum — f32 dot association is the
     only difference, and matching it makes oracles built on this helper
     **bitwise** against the fused kernels rather than rtol-close.
     Returns (q int8 (M, D), scale f32 (M, 1), zp f32 (M, 1)).
     """
     xf = x.astype(jnp.float32)
-    m, d = xf.shape
     if blocks is not None:
         nblk, bk, _ = blocks.shape
         parts = [jnp.dot(xf[:, bi * bk:(bi + 1) * bk],
@@ -178,13 +179,7 @@ def kernel_transform_quant(x, blocks, ha, hb, sign, *, act_bits: int = 8):
                  for bi in range(nblk)]
         xf = jnp.concatenate(parts, axis=1)
     xf = xf * sign.astype(jnp.float32)
-    a, b = ha.shape[0], hb.shape[0]
-    y = jnp.dot(xf.reshape(m * a, b), hb.astype(jnp.float32).T,
-                preferred_element_type=jnp.float32)
-    y = y.reshape(m, a, b).swapaxes(1, 2).reshape(m * b, a)
-    y = jnp.dot(y, ha.astype(jnp.float32).T,
-                preferred_element_type=jnp.float32)
-    y = y.reshape(m, b, a).swapaxes(1, 2).reshape(m, d)
+    y = kron_rows(xf, ha.astype(jnp.float32), hb.astype(jnp.float32))
     return dynamic_quant(y, bits=act_bits, symmetric=False)
 
 

@@ -64,8 +64,6 @@ def collective_bytes(hlo_text: str) -> dict:
 
 def cost_summary(compiled) -> dict:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x: one dict per program
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     mem = {}
     if ma is not None:

@@ -16,6 +16,7 @@ static-batch oracle the engine is tested against (token-identical).
 from __future__ import annotations
 
 import argparse
+import os
 from typing import Optional
 
 import jax
@@ -28,6 +29,25 @@ from repro.core.qlinear import iter_qlinear, num_weight_bytes
 from repro.data import calibration_batches, make_batch, request_workload
 from repro.launch.engine import ServeEngine, jitted_model_fns
 from repro.models import build
+
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is changed. Otherwise the cache lives in ``.jax_cache/`` at
+    the root of this checkout: a fixed path, because the path is part of
+    what a later process must match to find its entries again."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def weight_memory_report(params) -> dict:
@@ -372,6 +392,7 @@ def main() -> None:
     ap.add_argument("--full-config", action="store_true")
     args = ap.parse_args()
     validate_flags(ap, args)
+    enable_compile_cache()
     out = serve_benchmark(arch=args.arch, batch=args.batch,
                           prompt_len=args.prompt_len, gen=args.gen,
                           transform=args.transform, w_bits=args.w_bits,

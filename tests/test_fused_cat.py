@@ -180,6 +180,31 @@ def test_autotune_pick_memoizes():
     assert key not in autotune.cache_info()
 
 
+def test_autotune_pick_raises_when_every_candidate_fails(monkeypatch):
+    """Measured tuning skips refused candidates but must not hide a
+    kernel that compiles at no block size behind the heuristic."""
+    monkeypatch.setenv("REPRO_AUTOTUNE", "measure")
+    autotune.cache_clear()
+    seen = []
+
+    def refuse(cand):
+        seen.append(cand)
+        raise ValueError("refused")
+
+    key = ("test-refused", 256, 512, 1024, True, False)
+    with pytest.raises(RuntimeError, match="every block-size candidate"):
+        autotune.pick(key, 256, 512, 1024, True, run=refuse)
+    assert seen and key not in autotune.cache_info()
+
+    def ok_unless_small(cand):
+        if cand[0] < 256:
+            raise ValueError("refused")
+
+    assert autotune.pick(key, 256, 512, 1024, True,
+                         run=ok_unless_small)[0] >= 256
+    autotune.cache_clear()
+
+
 # ------------------------------------------------- engine token identity
 
 @pytest.mark.slow

@@ -1,0 +1,168 @@
+"""Ahead-of-time compiles of the serving kernels for a described TPU v5e.
+
+Interpret mode (every other kernel test) runs the kernel bodies through
+XLA:CPU and accepts shapes, casts and dot types that Mosaic, the TPU
+kernel compiler, refuses. Here each kernel on the main serving path is
+lowered with ``interpret=False`` for one chip of a described (not
+attached) ``v5e:2x2`` topology at ``catlm_60m``'s published widths
+(d_model 512, 8/4 heads of 64, d_ff 1536, page size 16) and compiled;
+the compiled text must hold the Mosaic kernel (``tpu_custom_call``).
+Nothing executes, so this says nothing about results or speed.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU compiler library, and test
+workers import every test module.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+D, D_FF, N_Q, N_KV, HD = 512, 1536, 512, 256, 64
+KVH, G = N_KV // HD, N_Q // N_KV
+PAGE, N_PAGES, N_PTAB = 16, 297, 37     # 8 slots x 592 rows + null page
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_cache():
+    """Compiles for a described chip cannot be read back from the
+    persistent cache without the chip; keep them out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile(fn, shapes, sharding):
+    args = [None if s is None else
+            jax.ShapeDtypeStruct(s[0], s[1], sharding=sharding)
+            for s in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _cat_shapes(m, d):
+    return [((m, d), jnp.bfloat16), ((d // 32, 32, 32), jnp.float32),
+            ((32, 32), jnp.float32), ((d // 32, d // 32), jnp.float32),
+            ((d,), jnp.float32)]
+
+
+@pytest.mark.parametrize("m", [8, 256], ids=["gemv", "matmul"])
+@pytest.mark.parametrize("d,n", [(D, N_Q + 2 * N_KV), (D, 2 * D_FF),
+                                 (N_Q, D), (D_FF, D)],
+                         ids=["wqkv", "wgu", "wo", "wd"])
+def test_fused_cat_matmul_compiles(one_chip, no_cache, m, d, n):
+    """The four serving linears of a layer, through the serving dispatch
+    (block sizes from ``autotune``): decode rows take the GEMV kernel,
+    a 256-token step budget the tiled kernel."""
+    from repro.kernels import ops
+
+    def f(x, blocks, ha, hb, sign, qw, sw):
+        return ops.fused_cat_matmul(x, blocks, ha, hb, sign, qw, sw,
+                                    act_bits=8, packed=True,
+                                    interpret=False)
+
+    _compile(f, _cat_shapes(m, d) + [((d // 2, n), jnp.int8),
+                                     ((1, n), jnp.float32)], one_chip)
+
+
+def test_hadamard_transform_compiles(one_chip, no_cache):
+    from repro.kernels.hadamard import hadamard_transform
+    _compile(lambda x, ha, hb, s: hadamard_transform(x, ha, hb, s,
+                                                     interpret=False),
+             [((256, D), jnp.float32), ((32, 32), jnp.float32),
+              ((16, 16), jnp.float32), ((D,), jnp.float32)], one_chip)
+
+
+def test_block_diag_matmul_compiles(one_chip, no_cache):
+    from repro.kernels.block_matmul import block_diag_matmul
+    _compile(lambda x, b: block_diag_matmul(x, b, interpret=False),
+             [((256, D), jnp.float32), ((D // 32, 32, 32), jnp.float32)],
+             one_chip)
+
+
+def test_dynamic_quant_compiles(one_chip, no_cache):
+    from repro.kernels.dynamic_quant import dynamic_quant
+    _compile(lambda x: dynamic_quant(x, bits=8, interpret=False),
+             [((256, D), jnp.float32)], one_chip)
+
+
+def _qx_shapes(m, k, n, packed):
+    return [((m, k), jnp.int8), ((m, 1), jnp.float32), ((m, 1), jnp.float32),
+            ((k // 2 if packed else k, n), jnp.int8), ((1, n), jnp.float32)]
+
+
+@pytest.mark.parametrize("name", ["quant_matmul", "quant_matmul_w4",
+                                  "quant_gemv_w4"])
+def test_quant_matmul_compiles(one_chip, no_cache, name):
+    from repro.kernels import quant_matmul, quant_matmul_w4
+    fn = {"quant_matmul": quant_matmul.quant_matmul,
+          "quant_matmul_w4": quant_matmul_w4.quant_matmul_w4,
+          "quant_gemv_w4": quant_matmul_w4.quant_gemv_w4}[name]
+    m = 8 if name == "quant_gemv_w4" else 256
+    _compile(lambda *a: fn(*a, interpret=False),
+             _qx_shapes(m, D_FF, D, packed=name != "quant_matmul"),
+             one_chip)
+
+
+def _pool_shapes():
+    return [((N_PAGES, PAGE, KVH, HD), jnp.int8),
+            ((N_PAGES, PAGE, KVH, 1), jnp.float32),
+            ((N_PAGES, PAGE, KVH, HD), jnp.int8),
+            ((N_PAGES, PAGE, KVH, 1), jnp.float32)]
+
+
+def test_decode_qkv_prologue_compiles(one_chip, no_cache):
+    from repro.kernels.ops import decode_qkv_prologue
+    n = N_Q + 2 * N_KV
+
+    def f(x, blocks, ha, hb, sign, qw, sw, kp, ks, vp, vs, pid, rid, pos):
+        return decode_qkv_prologue(
+            x, blocks, ha, hb, sign, qw, sw, kp, ks, vp, vs, pid, rid, pos,
+            n_q=N_Q, head_dim=HD, rope_theta=1e4, interpret=False)
+
+    _compile(f, _cat_shapes(8, D) + [((D // 2, n), jnp.int8),
+                                     ((1, n), jnp.float32)]
+             + _pool_shapes() + [((8,), jnp.int32)] * 3, one_chip)
+
+
+def test_paged_attention_decode_compiles(one_chip, no_cache):
+    from repro.kernels.paged_attention import paged_attention_decode
+    _compile(lambda q, *a: paged_attention_decode(q, *a, interpret=False),
+             [((8, KVH, G, HD), jnp.bfloat16)] + _pool_shapes()
+             + [((8, N_PTAB), jnp.int32), ((8,), jnp.int32)], one_chip)
+
+
+def test_paged_attention_ragged_compiles(one_chip, no_cache):
+    from repro.kernels.paged_attention import paged_attention_ragged
+    nq = 256          # the smoke budget: one item may fill the step
+    _compile(lambda q, *a: paged_attention_ragged(q, *a, interpret=False),
+             [((8, nq, KVH, G, HD), jnp.bfloat16)] + _pool_shapes()
+             + [((8, N_PTAB), jnp.int32), ((8,), jnp.int32),
+                ((8, nq), jnp.int32)], one_chip)
+
+
+def test_compile_helper_finds_no_kernel_in_plain_xla(one_chip, no_cache):
+    """The kernel check is not vacuous: plain XLA has no custom call."""
+    text = jax.jit(lambda x: x * 2).lower(
+        jax.ShapeDtypeStruct((8, 128), jnp.float32,
+                             sharding=one_chip)).compile().as_text()
+    assert "tpu_custom_call" not in text
