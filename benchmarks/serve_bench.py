@@ -47,10 +47,7 @@ Pipelined row (``pipelined``, schema v4): the depth-1 asynchronous
 unified loop (device-resident sampling + one-step-ahead scheduling,
 ``ServeEngine(pipeline=True)``) vs the synchronous loop on a
 decode-heavy workload — tok/s both ways, speedup, ITL p50/p95,
-``overlap_frac`` (fraction of host planning/pack/observe time hidden
-under device compute), ``host_ms_hidden``, mispredict count, and a
-token-identity check. Runs in ``--quick`` too, where ``overlap_frac``
-is gated against the recorded artifact like ``dispatch_per_step``.
+mispredict count, and a token-identity check. Runs in ``--quick`` too.
 
 With >= 4 local devices (XLA_FLAGS=--xla_force_host_platform_device_count
 on CPU) it also serves the int4-packed variant tensor-parallel — a tp=1
@@ -374,8 +371,6 @@ def _pipelined_rows(rows, quick: bool = False) -> None:
         "sync_itl_p95_s": es["itl_p95_s"],
         "pipelined_itl_p50_s": ep["itl_p50_s"],
         "pipelined_itl_p95_s": ep["itl_p95_s"],
-        "overlap_frac": ep["overlap_frac"],
-        "host_ms_hidden": ep["host_ms_hidden"],
         "mispredicts": ep["mispredicts"],
         "dispatch_per_step": ep["dispatch_per_step"],
         "launches_per_token": ep["launches_per_token"],
@@ -385,8 +380,6 @@ def _pipelined_rows(rows, quick: bool = False) -> None:
     emit("serve_pipelined", pipe["wall_s"] * 1e6,
          f"tok_per_s={pipe['tok_per_s']:.1f} "
          f"speedup={speedup:.2f}x "
-         f"overlap={ep['overlap_frac']:.2f} "
-         f"hidden_ms={ep['host_ms_hidden']:.2f} "
          f"identical={identical}")
 
 
@@ -399,9 +392,11 @@ def _pipelined_rows(rows, quick: bool = False) -> None:
 # emitted tokens — the serving-level launch-pressure column the
 # two-launch decode work moves).
 # v4: adds the ``pipelined`` row (sync vs depth-1 asynchronous unified
-# loop: tok/s + speedup, ITL percentiles, overlap_frac, host_ms_hidden,
-# mispredicts, token_identical), present in --quick artifacts too.
-SCHEMA_VERSION = 4
+# loop: tok/s + speedup, ITL percentiles, mispredicts, token_identical),
+# present in --quick artifacts too.
+# v5: the ``pipelined`` row no longer carries the host-clock estimate of
+# hidden host time; a profiler trace gives the device's idle share.
+SCHEMA_VERSION = 5
 
 
 def _dispatch_gate(rows: dict, out_path: str) -> list:
@@ -427,28 +422,6 @@ def _dispatch_gate(rows: dict, out_path: str) -> list:
         if ref and cur and cur > ref * 1.05:
             bad.append(f"{name}: {cur:.3f} > baseline {ref:.3f}")
     return bad
-
-
-def _overlap_gate(rows: dict, out_path: str) -> list:
-    """--quick regression gate (same pattern as ``_dispatch_gate``): the
-    pipelined row's ``overlap_frac`` dropping more than 5% below the
-    previously recorded quick artifact means host work stopped hiding
-    under device compute — the pipelining win regressing. Returns the
-    offending descriptions (empty = pass / no baseline)."""
-    try:
-        with open(out_path) as f:
-            base = json.load(f)
-    except (OSError, ValueError):
-        return []
-    if (base.get("schema_version") != SCHEMA_VERSION
-            or not base.get("quick")):
-        return []
-    ref = base.get("rows", {}).get("pipelined", {}).get("overlap_frac")
-    cur = rows.get("pipelined", {}).get("overlap_frac")
-    if ref and cur is not None and cur < ref * 0.95:
-        return [f"pipelined: overlap_frac {cur:.3f} < baseline "
-                f"{ref:.3f} - 5%"]
-    return []
 
 
 def _hot_path_kib(w_bits: int, fused: bool) -> float:
@@ -516,8 +489,7 @@ def main(n_requests: int = 8, n_slots: int = 3, gen: int = 8,
         _paged_rows(rows, n_requests, n_slots)
         _unified_rows(rows, n_slots)
         _tp_rows(rows, n_requests, n_slots, gen)
-    regressed = (_dispatch_gate(rows, out_path)
-                 + _overlap_gate(rows, out_path)) if quick else []
+    regressed = _dispatch_gate(rows, out_path) if quick else []
     os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
     with open(out_path, "w") as f:
         json.dump({"schema_version": SCHEMA_VERSION, "quick": quick,
@@ -544,10 +516,9 @@ if __name__ == "__main__":
                          "prefix_shared, speculative and pipelined rows "
                          "(skips the paged/unified/tp sections); exits "
                          "nonzero if any row reports "
-                         "token_identical=false, dispatch_per_step "
-                         "regresses >5% above, or the pipelined row's "
-                         "overlap_frac drops >5% below the previously "
-                         "recorded --quick artifact")
+                         "token_identical=false, or dispatch_per_step "
+                         "regresses >5% above the previously recorded "
+                         "--quick artifact")
     ap.add_argument("--out", default="results/serve_bench.json")
     a = ap.parse_args()
     enable_compile_cache()

@@ -69,6 +69,7 @@ from repro.launch.executor import (LegacyExecutor, RaggedExecutor,
                                    jitted_model_fns)  # noqa: F401
 from repro.launch.scheduler import (Request, RequestResult, SeqState,
                                     TokenBudgetScheduler)
+from repro.launch.tracing import span
 
 
 @dataclasses.dataclass
@@ -83,13 +84,19 @@ class _Active:
 
 @dataclasses.dataclass
 class _InFlight:
-    """One dispatched-but-unobserved pipelined step: its plan, the
-    device token array its observation will fetch, and whether the
-    token vector is slot-major (fused decode fast path) or consumer-row
-    major (ragged step)."""
+    """One dispatched-but-unobserved step: its plan, the device token
+    array its observation will fetch, whether the token vector is
+    slot-major (fused decode fast path) or consumer-row major (ragged
+    step), and the engine cycle that dispatched it."""
     plan: object
     toks: object
     slot_major: bool
+    step: int
+
+    @property
+    def kind(self) -> str:
+        """``"decode"`` (the pure-decode fast path) or ``"unified"``."""
+        return "decode" if self.slot_major else "unified"
 
 
 # ------------------------------------------------------------------ engine
@@ -155,9 +162,6 @@ class ServeEngine:
                              "synchronous); pass pipeline=False or None")
         self.pipeline = bool(pipeline)
         self._inflight: Optional[_InFlight] = None
-        self._host_s = 0.0      # host-side planning/pack/observe seconds
-        self._hidden_s = 0.0    # ... of which spent while a step was in
-        #                         flight on device (the overlap win)
         if schedule == "unified":
             paged = True    # the unified step serves from the paged pool
         elif max_batch_tokens:
@@ -362,7 +366,6 @@ class ServeEngine:
         # vector + the descriptor-ring parity) so a stale step can never
         # leak into the next run — warmup reuse must start cold.
         self._inflight = None
-        self._host_s = self._hidden_s = 0.0
         if getattr(self.exec, "reset_pipeline", None) is not None:
             self.exec.reset_pipeline()
         self.exec.d2h_s = 0.0
@@ -576,9 +579,16 @@ class ServeEngine:
     def step(self) -> dict:
         """One engine cycle; returns step stats. Legacy: admit (prefill
         dispatches) + one batched decode + retire. Unified: plan one
-        token-budgeted ragged step, run it, feed tokens back, retire."""
-        if self.schedule == "unified":
-            return self._step_unified()
+        token-budgeted ragged step, run it, feed tokens back, retire;
+        the stats are the cycle's record (``_record``)."""
+        with span("engine.step", step=self.step_count):
+            if self.schedule == "unified":
+                if self.pipeline:
+                    return self._step_pipelined()
+                return self._step_sync()
+            return self._step_legacy()
+
+    def _step_legacy(self) -> dict:
         t0 = time.perf_counter()
         self._dev_acc = 0.0
         events_before = len(self.events)
@@ -619,11 +629,6 @@ class ServeEngine:
         return {"queue_depth": self.metrics["queue_depth"][-1],
                 "occupancy": occ, "active": len(self._active)}
 
-    def _step_unified(self) -> dict:
-        if self.pipeline:
-            return self._step_pipelined()
-        return self._step_sync()
-
     def _plan_and_dispatch(self):
         """Shared front half of a unified cycle: plan, account metrics,
         run the draft cycle (speculative mode), and dispatch the packed
@@ -656,6 +661,7 @@ class ServeEngine:
                 plan.spec_drafts = {
                     slot: drafts[:self.spec_k, slot]
                     for slot, _, _ in plan.spec}
+        self.exec.step_idx = self.step_count
         if (plan.decode and not plan.prefill and not plan.spec
                 and not plan.cow and self.exec.supports_decode_step):
             # pure-decode fast path: slot-major compact batch, one
@@ -668,14 +674,15 @@ class ServeEngine:
             tok, dpos, table, src = self.sched.pack_decode(plan)
             inf = _InFlight(plan,
                             self.exec.decode_step(tok, dpos, table, src),
-                            True)
+                            True, self.step_count)
         else:
             packed = self.sched.pack(plan, kernel_desc=self.paged_kernel)
             if plan.cow:
                 # COW page copies dispatch BEFORE the step so shared
                 # content is duplicated before any divergent row lands
                 self.exec.copy_pages(plan.cow)
-            inf = _InFlight(plan, self.exec.step(packed), False)
+            inf = _InFlight(plan, self.exec.step(packed), False,
+                            self.step_count)
         if plan.decode or plan.spec:
             self.metrics["decode_steps"] += 1
         return plan, inf
@@ -699,24 +706,46 @@ class ServeEngine:
         for seq in retired:
             self._retire_seq(seq)
 
-    def _step_sync(self) -> dict:
-        """The synchronous unified cycle (REPRO_SYNC_STEP /
-        pipeline=False): dispatch, block, observe — the per-step device
-        span is an honest blocked measurement."""
-        t0 = time.perf_counter()
-        plan, inf = self._plan_and_dispatch()
-        if inf is not None:
-            td = time.perf_counter()
-            toks = np.asarray(jax.block_until_ready(inf.toks))
-            dev_s = time.perf_counter() - td
-            self._observe_tokens(inf, toks)
-            self.metrics["step_s"].append(time.perf_counter() - t0)
-            self.metrics["device_s"].append(dev_s)
-        self.step_count += 1
+    def _fetch(self, inf: _InFlight) -> np.ndarray:
+        """Wait for a dispatched step's tokens and copy them to the
+        host."""
+        with span("engine.fetch", fetched=inf.step):
+            return np.asarray(jax.block_until_ready(inf.toks))
+
+    def _record(self, plan, inf: Optional[_InFlight],
+                fetched: Optional[_InFlight],
+                fetched_at: Optional[float]) -> dict:
+        """What a unified ``step()`` returns: the cycle's counters, and
+        the kind of step it dispatched (``inf``) and of the step whose
+        tokens it fetched (``fetched``, at host time ``fetched_at``)."""
         return {"queue_depth": self.metrics["queue_depth"][-1],
                 "occupancy": self.metrics["occupancy"][-1],
                 "active": len(self._active),
-                "packed_tokens": plan.n_tokens}
+                "packed_tokens": plan.n_tokens,
+                "kind": inf.kind if inf else None,
+                "prefill_tokens": plan.prefill_tokens,
+                "fetched_kind": fetched.kind if fetched else None,
+                "fetched_prefill_tokens": (fetched.plan.prefill_tokens
+                                           if fetched else 0),
+                "fetched_at": fetched_at}
+
+    def _step_sync(self) -> dict:
+        """The synchronous unified cycle (REPRO_SYNC_STEP /
+        pipeline=False): dispatch, block, observe — the per-step device
+        span is an honest blocked measurement, and the step fetched is
+        the one dispatched."""
+        t0 = time.perf_counter()
+        plan, inf = self._plan_and_dispatch()
+        t1 = None
+        if inf is not None:
+            td = time.perf_counter()
+            toks = self._fetch(inf)
+            t1 = time.perf_counter()
+            self._observe_tokens(inf, toks)
+            self.metrics["step_s"].append(time.perf_counter() - t0)
+            self.metrics["device_s"].append(t1 - td)
+        self.step_count += 1
+        return self._record(plan, inf, inf, t1)
 
     def _step_pipelined(self) -> dict:
         """The depth-1 asynchronous cycle: plan + pack + dispatch step N
@@ -726,7 +755,7 @@ class ServeEngine:
         and observe them, rolling back step N's rows for any slot whose
         prediction failed (see ``TokenBudgetScheduler.observe``). All
         host work between the dispatch and the fetch is hidden under
-        device compute; ``overlap_frac`` reports the hidden fraction.
+        device compute.
 
         Timing spans: a (step_s, device_s) pair is appended only on
         cycles that OBSERVE a step, with device_s = the token-fetch
@@ -739,31 +768,18 @@ class ServeEngine:
         plan, inf = self._plan_and_dispatch()
         if inf is not None:
             self.sched.note_dispatch(inf.plan, slot_major=inf.slot_major)
-        seg = time.perf_counter() - t0
-        self._host_s += seg
+        t1 = None
         if prev is not None:
-            self._hidden_s += seg       # packed under step N-1's compute
-        observed = prev is not None
-        if observed:
             tw = time.perf_counter()
-            toks = np.asarray(jax.block_until_ready(prev.toks))
-            wait_s = time.perf_counter() - tw
+            toks = self._fetch(prev)
             t1 = time.perf_counter()
             self._observe_tokens(prev, toks,
                                  ahead=inf.plan if inf else None)
-            seg = time.perf_counter() - t1
-            self._host_s += seg
-            if inf is not None:
-                self._hidden_s += seg   # observed under step N's compute
-        self._inflight = inf
-        if observed:
             self.metrics["step_s"].append(time.perf_counter() - t0)
-            self.metrics["device_s"].append(wait_s)
+            self.metrics["device_s"].append(t1 - tw)
+        self._inflight = inf
         self.step_count += 1
-        return {"queue_depth": self.metrics["queue_depth"][-1],
-                "occupancy": self.metrics["occupancy"][-1],
-                "active": len(self._active),
-                "packed_tokens": plan.n_tokens}
+        return self._record(plan, inf, prev, t1)
 
     def _retire_seq(self, seq: SeqState) -> None:
         """Unified-mode retirement bookkeeping (the scheduler already
@@ -873,15 +889,6 @@ class ServeEngine:
                 # capped ring and may have evicted the peak step
                 "packed_tokens_max": self.sched.packed_tokens_max,
                 "pipeline": self.pipeline,
-                # fraction of host planning/pack/observe seconds spent
-                # while a step was in flight on device (1.0 = every host
-                # cycle fully hidden under compute; 0.0 = synchronous)
-                "overlap_frac": (self._hidden_s / self._host_s
-                                 if self._host_s else 0.0),
-                # mean hidden host milliseconds per observed step — the
-                # absolute per-step latency the pipeline removes
-                "host_ms_hidden": (1e3 * self._hidden_s / len(dev_s)
-                                   if dev_s else 0.0),
                 "mispredicts": self.sched.mispredicts}
                if self.schedule == "unified" else
                # legacy: the per-decode-step token D2H fetch, attributed

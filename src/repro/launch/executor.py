@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.tracing import span
+
 
 # ------------------------------------------------------------- jit helpers
 
@@ -484,6 +486,9 @@ class RaggedExecutor(_CopyPagesMixin):
         self.mesh = mesh
         self.n_dispatch = 0     # device calls issued (hot-loop accounting)
         self.d2h_s = 0.0        # token D2H seconds (engine resets it)
+        # the engine cycle of the next step()/decode_step(): the engine
+        # sets it; the exec.* spans carry it as step=
+        self.step_idx = 0
         # previous step's device-resident token vector (the injection
         # source for rows packed before their fed token was observed —
         # pipelined serving). None until the first step; chained by
@@ -594,35 +599,36 @@ class RaggedExecutor(_CopyPagesMixin):
         pipelined caller can keep packing while the step executes.
         Synchronous callers block + ``np.asarray`` the result
         themselves."""
-        self.n_dispatch += 1
-        tokens = jnp.asarray(packed["tokens"])
-        pos = jnp.asarray(packed["pos"])
-        ptab = jnp.asarray(packed["page_table"])
-        lrows = jnp.asarray(packed["logit_rows"])
-        prev = self._prev_arr(lrows.shape[0])
-        src = jnp.asarray(packed["tok_src"])
-        desc = packed.get("ragged_desc")
-        if desc is not None:
-            desc = {k: jnp.asarray(v) for k, v in desc.items()}
-        if self.mesh is None:
-            fn = _unified_step if self._donate else _unified_step_async
-            toks, self.cache = fn(
-                self.model.ragged_step, self.paged_kernel, True,
-                self.params, self.cache, tokens, pos, ptab, lrows, desc,
-                prev, src)
-        else:
-            cache = dict(self.cache, pos=pos, page_table=ptab)
-            if self.paged_kernel:
-                toks, cache = self._mesh_step(self.params, tokens, cache,
-                                              lrows, desc, prev, src)
+        with span("exec.step", step=self.step_idx):
+            self.n_dispatch += 1
+            tokens = jnp.asarray(packed["tokens"])
+            pos = jnp.asarray(packed["pos"])
+            ptab = jnp.asarray(packed["page_table"])
+            lrows = jnp.asarray(packed["logit_rows"])
+            prev = self._prev_arr(lrows.shape[0])
+            src = jnp.asarray(packed["tok_src"])
+            desc = packed.get("ragged_desc")
+            if desc is not None:
+                desc = {k: jnp.asarray(v) for k, v in desc.items()}
+            if self.mesh is None:
+                fn = _unified_step if self._donate else _unified_step_async
+                toks, self.cache = fn(
+                    self.model.ragged_step, self.paged_kernel, True,
+                    self.params, self.cache, tokens, pos, ptab, lrows, desc,
+                    prev, src)
             else:
-                toks, cache = self._mesh_step(self.params, tokens, cache,
-                                              lrows, prev, src)
-            cache.pop("pos")
-            cache.pop("page_table")
-            self.cache = cache
-        self._prev = toks
-        return toks
+                cache = dict(self.cache, pos=pos, page_table=ptab)
+                if self.paged_kernel:
+                    toks, cache = self._mesh_step(self.params, tokens, cache,
+                                                  lrows, desc, prev, src)
+                else:
+                    toks, cache = self._mesh_step(self.params, tokens, cache,
+                                                  lrows, prev, src)
+                cache.pop("pos")
+                cache.pop("page_table")
+                self.cache = cache
+            self._prev = toks
+            return toks
 
     @property
     def supports_decode_step(self) -> bool:
@@ -637,18 +643,19 @@ class RaggedExecutor(_CopyPagesMixin):
         row (inert writes, discarded outputs). Returns the greedy token
         per slot as a DEVICE (n_slots,) int32 array without blocking
         (see ``step``)."""
-        self.n_dispatch += 1
-        if tok_src is None:
-            tok_src = np.full((self.n_slots,), -1, np.int32)
-        prev = self._prev_arr(self.n_slots)
-        fn = (_fused_decode_step if self._donate
-              else _fused_decode_step_async)
-        toks, self.cache = fn(
-            self._decode_fn, self.params, self.cache,
-            jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(table),
-            prev, jnp.asarray(tok_src))
-        self._prev = toks
-        return toks
+        with span("exec.decode_step", step=self.step_idx):
+            self.n_dispatch += 1
+            if tok_src is None:
+                tok_src = np.full((self.n_slots,), -1, np.int32)
+            prev = self._prev_arr(self.n_slots)
+            fn = (_fused_decode_step if self._donate
+                  else _fused_decode_step_async)
+            toks, self.cache = fn(
+                self._decode_fn, self.params, self.cache,
+                jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(table),
+                prev, jnp.asarray(tok_src))
+            self._prev = toks
+            return toks
 
     # ---------------------------------------------------- speculative draft
 

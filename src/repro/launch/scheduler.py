@@ -44,6 +44,8 @@ from typing import Optional
 
 import numpy as np
 
+from repro.launch.tracing import traced
+
 
 # ----------------------------------------------------------- request types
 
@@ -140,10 +142,15 @@ class StepPlan:
         return self.spec_k_of.get(slot, self.spec_width - 1) + 1
 
     @property
+    def prefill_tokens(self) -> int:
+        """Prompt rows packed this step."""
+        return sum(n for _, _, n, _ in self.prefill)
+
+    @property
     def n_tokens(self) -> int:
         return (len(self.decode)
                 + sum(self.spec_rows(s) for s, _, _ in self.spec)
-                + sum(n for _, _, n, _ in self.prefill))
+                + self.prefill_tokens)
 
     @property
     def logit_consumers(self) -> list:
@@ -285,6 +292,7 @@ class TokenBudgetScheduler:
             n = min(n, self.prefill_chunk)
         return n
 
+    @traced("sched.plan")
     def plan(self, step_idx: int) -> StepPlan:
         plan = StepPlan()
         plan.spec_width = self.spec_k + 1
@@ -475,6 +483,7 @@ class TokenBudgetScheduler:
             b["qpos"].fill(-1)
         return b
 
+    @traced("sched.pack")
     def pack(self, plan: StepPlan, *, kernel_desc: bool = False) -> dict:
         """Flatten a plan into the fixed-shape arrays the ragged device
         step consumes (ONE compile shape per engine): ``tokens`` (T, 1),
@@ -650,6 +659,7 @@ class TokenBudgetScheduler:
             src[slot] = plan.srcs.get(slot, -1)
         return tok0, pos0, table, src
 
+    @traced("sched.pack_decode")
     def pack_decode(self, plan: StepPlan):
         """Compact slot-major inputs for the pure-decode fast path:
         (tokens (n_slots, 1), pos (n_slots,), table (n_slots, n_ptab),
@@ -801,6 +811,7 @@ class TokenBudgetScheduler:
         self.tables.shrink(slot, valid)
         self.draft_tables.shrink(slot, valid)
 
+    @traced("sched.observe")
     def observe(self, plan: StepPlan, toks: np.ndarray, now: float,
                 ahead: Optional[StepPlan] = None) -> list:
         """Apply one step's argmax tokens (aligned with

@@ -412,8 +412,7 @@ def main() -> None:
     mesh_note = (f", mesh={eng['mesh']}" if eng.get("mesh") else "")
     sched_note = ""
     if eng.get("schedule") == "unified":
-        pipe = (f", pipelined {eng['overlap_frac']:.0%} overlap"
-                if eng.get("pipeline") else ", sync")
+        pipe = ", pipelined" if eng.get("pipeline") else ", sync"
         sched_note = (f", unified[{eng['max_batch_tokens']}t budget, "
                       f"itl p95 {eng['itl_p95_s'] * 1e3:.0f}ms{pipe}]")
     spec_note = ""

@@ -75,10 +75,11 @@ def test_pipelined_matches_golden_bitwise(case, kw):
 
 
 def test_pipelined_summary_and_timing_spans():
-    """Pipelined summary reports the overlap metrics, and the timing
-    spans keep the blocked-loop invariants: one (step_s, device_s) pair
-    per OBSERVED step with 0 < device_s <= step_s (device_s is the
-    token-fetch wait, a subinterval of the cycle)."""
+    """Pipelined summary reports the pipeline, each cycle's record names
+    the step it fetched (the one the cycle before dispatched), and the
+    timing spans keep the blocked-loop invariants: one (step_s,
+    device_s) pair per OBSERVED step with 0 < device_s <= step_s
+    (device_s is the token-fetch wait, a subinterval of the cycle)."""
     cfg, model, params = regenerate.build_case("fp")
     reqs = request_workload(cfg, regenerate.N_REQUESTS, gen=regenerate.GEN,
                             lengths=regenerate.LENGTHS,
@@ -87,11 +88,17 @@ def test_pipelined_summary_and_timing_spans():
                       max_len=regenerate.MAX_LEN, schedule="unified",
                       page_size=8)
     assert eng.pipeline
-    eng.run(reqs)
+    for r in reqs:
+        eng.submit(r["tokens"], r["max_new_tokens"], rid=r["rid"])
+    recs = []
+    while not eng.idle:
+        recs.append(eng.step())
     s = eng.summary()
     assert s["pipeline"] is True
-    assert 0.0 <= s["overlap_frac"] <= 1.0
-    assert s["host_ms_hidden"] >= 0.0
+    for prev, cur in zip([{"kind": None}] + recs, recs):
+        assert cur["fetched_kind"] == prev["kind"]
+    assert sum(r["fetched_at"] is not None for r in recs) \
+        == len(eng.metrics["step_s"])
     assert s["mispredicts"] == 0          # max_new retirement is predicted
     step_s, dev_s = eng.metrics["step_s"], eng.metrics["device_s"]
     assert len(step_s) == len(dev_s) > 0
@@ -345,7 +352,6 @@ def test_reset_mid_flight_refused_then_clean_after_drain():
     assert eng._inflight is None
     assert eng.sched._buf_parity == 0
     assert eng.exec._prev is None
-    assert eng._host_s == eng._hidden_s == 0.0
     res = eng.run(reqs)
     for r in reqs:
         np.testing.assert_array_equal(res[r["rid"]].tokens,
