@@ -13,15 +13,26 @@ KV quantization pays).
 The page table and per-slot lengths ride in as **scalar-prefetch**
 operands (``pltpu.PrefetchScalarGridSpec``): they are resident before the
 kernel body runs, so the k/v BlockSpec index maps can address the
-*physical* page ``pt[b, i]`` while the grid walks *logical* page slots
-``(b, i)`` — the indirection is free, folded into the DMA descriptor.
+*physical* page ``pt[b, t]`` of a *logical* table entry ``t`` — the
+indirection is free, folded into the DMA descriptor.
 
-Grid: ``(B, n_ptab)`` with the page axis innermost; VMEM scratch carries
-the flash-attention running (m, l, acc) across a slot's pages (init at
-``i == 0``, final ``acc / l`` write-out at the last page). Ragged last
-pages and dummy table entries (null page 0) are handled by the
-``kv_pos < length[b]`` mask — garbage rows get ``exp(-1e30 - m) == 0``
-weight exactly.
+Grid: one step per **live block** of ``ppb`` table pages, rows one after
+another, so the grid's length is known only from the lengths (a dynamic
+grid). ``ppb`` follows from the shapes alone (``_pages_per_block``: about
+128 KV rows, double-buffered codes and scales within a fixed share of the
+default scoped VMEM); each of a block's pages is its own operand (the
+pool passed ``ppb`` times, one BlockSpec each), since a block's pages lie
+anywhere in the pool. Entries past a row's context are never fetched:
+their operands keep the block index they last held, so the pipeline
+skips the copy, and a row of ``n`` live pages takes ``ceil(n / ppb)``
+steps (at least one, which writes its output). VMEM scratch carries the
+flash-attention running (m, l, acc) across a row's blocks (init at its
+first block, final ``acc / l`` write-out at its last). The ragged last
+page and a tail block's repeated pages are masked by ``kv_pos <
+length[b]`` — garbage rows get ``exp(-1e30 - m) == 0`` weight exactly.
+(Copying the pages by hand from a ``pl.ANY`` pool would need 128-lane
+minor dims: Mosaic refuses the DMA of a ``(G, KVH, hd=64)`` page and of
+any ``(G, KVH, 1)`` scale page.)
 
 ``repro.kernels.ref.paged_attention_decode`` is the jnp oracle;
 ``paged_attention_fallback`` is a gather-based jnp path for fp pools and
@@ -39,17 +50,82 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
 
-def _paged_attn_kernel(len_ref, pt_ref, q_ref, k_ref, ks_ref, v_ref,
-                       vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                       page_size: int):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
+# One grid step covers this many KV rows where the VMEM budget allows.
+_BLOCK_ROWS = 128
+# Budget of the double-buffered page blocks (codes and scales of K and V),
+# padded to the chip's (8 x 32-bit sublane, 128-lane) tiles: a quarter of
+# the 16 MiB default scoped VMEM, the rest left to the block's values.
+_BLOCK_VMEM_BYTES = 4 << 20
+
+
+def _tile_bytes(rows: int, lanes: int, itemsize: int) -> int:
+    """VMEM bytes of one (rows, lanes) minor slab after tile padding."""
+    sub = 8 * 4 // itemsize
+    return (-(-rows // sub) * sub) * (-(-lanes // 128) * 128) * itemsize
+
+
+def _pages_per_block(page_size: int, kvh: int, hd: int, n_ptab: int,
+                     code_bytes: int = 1, scale_bytes: int = 4) -> int:
+    """Table pages one decode grid step fetches and computes: about
+    ``_BLOCK_ROWS`` KV rows, as many as keep both buffers of K and V
+    (codes + scales) within ``_BLOCK_VMEM_BYTES``, never more than the
+    table holds."""
+    per_page = 2 * 2 * page_size * (_tile_bytes(kvh, hd, code_bytes)
+                                    + _tile_bytes(kvh, 1, scale_bytes))
+    return max(1, min(_BLOCK_ROWS // page_size,
+                      _BLOCK_VMEM_BYTES // per_page, n_ptab))
+
+
+def live_pages(lengths, page_size: int, n_ptab: int) -> int:
+    """Table pages that hold some of the live context, summed over rows:
+    ``min(ceil(len / page_size), n_ptab)`` each — the pages
+    ``paged_attention_decode`` fetches (a row shorter than one block also
+    re-reads its last live page on its unused page operands). Host-side
+    (numpy)."""
+    need = -(-np.asarray(lengths, np.int64) // page_size)
+    return int(np.minimum(need, n_ptab).sum())
+
+
+def _live_blocks(lengths, page_size: int, n_ptab: int, ppb: int):
+    """(live table pages, blocks) per row: ``ceil(len / page_size)``
+    capped at the table width, and those pages in blocks of ``ppb`` —
+    at least one block, so that every row's output is written."""
+    pages = jnp.minimum((lengths + page_size - 1) // page_size, n_ptab)
+    return pages, jnp.maximum((pages + ppb - 1) // ppb, 1)
+
+
+def _page_index(j: int, ppb: int, page_size: int, n_ptab: int):
+    """Index map of the ``j``-th page operand: the physical page of table
+    entry ``i * ppb + j`` of the step's row while that entry is live;
+    past the live context, the last live entry this operand held, so the
+    pipeline sees an unchanged block and fetches nothing. An operand with
+    no live entry in the row holds the row's last live page."""
+    def index(s, ln, pt, row, blk):
+        bb, i = row[s], blk[s]
+        last = _live_blocks(ln[bb], page_size, n_ptab, ppb)[0] - 1
+        held = jnp.where(j <= last, j + (last - j) // ppb * ppb,
+                         jnp.maximum(last, 0))
+        return (pt[bb, jnp.minimum(i * ppb + j, held)], 0, 0, 0)
+    return index
+
+
+def _paged_attn_kernel(len_ref, pt_ref, row_ref, blk_ref, q_ref, *refs,
+                       page_size: int, ppb: int):
+    k_refs, ks_refs, v_refs, vs_refs = (refs[n * ppb:(n + 1) * ppb]
+                                        for n in range(4))
+    o_ref, m_ref, l_ref, acc_ref = refs[4 * ppb:]
+    s = pl.program_id(0)
+    b, i = row_ref[s], blk_ref[s]
+    rows = ppb * page_size
+    length = len_ref[b]
+    n_blocks = _live_blocks(length, page_size, pt_ref.shape[1], ppb)[1]
 
     @pl.when(i == 0)
     def _init():
@@ -57,28 +133,39 @@ def _paged_attn_kernel(len_ref, pt_ref, q_ref, k_ref, ks_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)              # (KVH, g, hd), pre-scaled
-    k = k_ref[0].astype(jnp.float32) * ks_ref[0]  # (G, KVH, hd) dequant
-    v = v_ref[0].astype(jnp.float32) * vs_ref[0]
+    @pl.when(i * rows < length)
+    def _block():
+        def dequant(codes, scales):
+            """The block's pages, dequantized, kv head first:
+            (KVH, rows, hd)."""
+            return jnp.swapaxes(jnp.concatenate(
+                [c[0].astype(jnp.float32) * sc[0]
+                 for c, sc in zip(codes, scales)], axis=0), 0, 1)
 
-    # scores for this page: (KVH, g, G)
-    s = jnp.einsum("kgd,Gkd->kgG", q, k,
-                   preferred_element_type=jnp.float32)
-    kv_pos = i * page_size + jax.lax.iota(jnp.int32, page_size)
-    mask = kv_pos < len_ref[b]
-    s = jnp.where(mask[None, None, :], s, _NEG_INF)
+        q = q_ref[0].astype(jnp.float32)              # (KVH, g, hd), pre-scaled
+        k = dequant(k_refs, ks_refs)
+        v = dequant(v_refs, vs_refs)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[..., None])
-    corr = jnp.exp(m_prev - m_new)
-    m_ref[...] = m_new
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-    acc_ref[...] = (acc_ref[...] * corr[..., None]
-                    + jnp.einsum("kgG,Gkd->kgd", p, v,
-                                 preferred_element_type=jnp.float32))
+        # scores for this block, batched over kv heads: (KVH, g, rows);
+        # entries past the live context hold a repeated live page and get
+        # exactly zero weight
+        sc = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                 preferred_element_type=jnp.float32)
+        kv_pos = i * rows + jax.lax.iota(jnp.int32, rows)
+        sc = jnp.where((kv_pos < length)[None, None, :], sc, _NEG_INF)
 
-    @pl.when(i == pl.num_programs(1) - 1)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
+        p = jnp.exp(sc - m_new[..., None])
+        corr = jnp.exp(m_prev - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
+        acc_ref[...] = (acc_ref[...] * corr[..., None]
+                        + jax.lax.dot_general(
+                            p, v, (((2,), (1,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32))
+
+    @pl.when(i == n_blocks - 1)
     def _finish():
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]
         o_ref[...] = out.astype(o_ref.dtype)[None]
@@ -96,7 +183,7 @@ def paged_attention_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
     k/v_pages   (n_pages, G, KVH, hd) int8 codes
     k/v_scale   (n_pages, G, KVH, 1) f32 per-(token, head) scales
     page_table  (B, n_ptab) int32 physical page ids (0 = null page for
-                slots/entries beyond the sequence — masked by ``lengths``)
+                entries beyond the sequence — never fetched)
     lengths     (B,) int32 valid kv rows per slot (the decode token's row
                 included: pass ``pos + 1``)
     -> (B, KVH, g, hd) in q's dtype.
@@ -106,24 +193,29 @@ def paged_attention_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
     n_ptab = page_table.shape[1]
     assert kvh_p == kvh, (q.shape, k_pages.shape)
     assert page_table.shape[0] == b and lengths.shape == (b,)
+    ppb = _pages_per_block(page_size, kvh, hd, n_ptab,
+                           k_pages.dtype.itemsize, k_scale.dtype.itemsize)
+
+    # the grid walks the live blocks only: step s is block blk[s] of row
+    # row[s]; the rows follow one another, each over its blocks in order
+    n_blocks = _live_blocks(lengths, page_size, n_ptab, ppb)[1]
+    ends = jnp.cumsum(n_blocks)
+    steps = jnp.arange(b * pl.cdiv(n_ptab, ppb), dtype=jnp.int32)
+    row = jnp.minimum(jnp.sum(ends[None, :] <= steps[:, None], axis=1,
+                              dtype=jnp.int32), b - 1)
+    blk = steps - (ends - n_blocks)[row]
 
     qs = (q.astype(jnp.float32) * hd ** -0.5).astype(q.dtype)
+    maps = [_page_index(j, ppb, page_size, n_ptab) for j in range(ppb)]
+    codes = [pl.BlockSpec((1, page_size, kvh, hd), m) for m in maps]
+    scales = [pl.BlockSpec((1, page_size, kvh, 1), m) for m in maps]
+    per_row = pl.BlockSpec((1, kvh, g, hd),
+                           lambda s, ln, pt, row, blk: (row[s], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,      # lengths, page_table
-        grid=(b, n_ptab),
-        in_specs=[
-            pl.BlockSpec((1, kvh, g, hd), lambda bb, i, ln, pt: (bb, 0, 0, 0)),
-            pl.BlockSpec((1, page_size, kvh, hd),
-                         lambda bb, i, ln, pt: (pt[bb, i], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, kvh, 1),
-                         lambda bb, i, ln, pt: (pt[bb, i], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, kvh, hd),
-                         lambda bb, i, ln, pt: (pt[bb, i], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, kvh, 1),
-                         lambda bb, i, ln, pt: (pt[bb, i], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, kvh, g, hd),
-                               lambda bb, i, ln, pt: (bb, 0, 0, 0)),
+        num_scalar_prefetch=4,      # lengths, page_table, row, blk
+        grid=(ends[-1],),
+        in_specs=[per_row] + codes + scales + codes + scales,
+        out_specs=per_row,
         scratch_shapes=[
             pltpu.VMEM((kvh, g), jnp.float32),       # running max
             pltpu.VMEM((kvh, g), jnp.float32),       # running denom
@@ -131,12 +223,13 @@ def paged_attention_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_paged_attn_kernel, page_size=page_size),
+        functools.partial(_paged_attn_kernel, page_size=page_size, ppb=ppb),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
         name="paged_attention_decode",
         interpret=interpret,
-    )(lengths, page_table, qs, k_pages, k_scale, v_pages, v_scale)
+    )(lengths, page_table, row, blk, qs, *[k_pages] * ppb,
+      *[k_scale] * ppb, *[v_pages] * ppb, *[v_scale] * ppb)
 
 
 # ------------------------------------------------- ragged (mixed q_len)

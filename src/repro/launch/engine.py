@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention import live_pages
 # Re-exported for backward compatibility: these historically lived here.
 from repro.launch.executor import (LegacyExecutor, RaggedExecutor,
                                    jitted_model_fns)  # noqa: F401
@@ -87,11 +88,14 @@ class _InFlight:
     """One dispatched-but-unobserved step: its plan, the device token
     array its observation will fetch, whether the token vector is
     slot-major (fused decode fast path) or consumer-row major (ragged
-    step), and the engine cycle that dispatched it."""
+    step), and the engine cycle that dispatched it. A fast-path step
+    also carries the page-table pages its decode attention fetches and
+    the pages its table holds (``pages``)."""
     plan: object
     toks: object
     slot_major: bool
     step: int
+    pages: Optional[tuple] = None
 
     @property
     def kind(self) -> str:
@@ -674,7 +678,9 @@ class ServeEngine:
             tok, dpos, table, src = self.sched.pack_decode(plan)
             inf = _InFlight(plan,
                             self.exec.decode_step(tok, dpos, table, src),
-                            True, self.step_count)
+                            True, self.step_count,
+                            (live_pages(dpos + 1, self.pool.page_size,
+                                        table.shape[1]), table.size))
         else:
             packed = self.sched.pack(plan, kernel_desc=self.paged_kernel)
             if plan.cow:
@@ -717,8 +723,11 @@ class ServeEngine:
                 fetched_at: Optional[float]) -> dict:
         """What a unified ``step()`` returns: the cycle's counters, and
         the kind of step it dispatched (``inf``) and of the step whose
-        tokens it fetched (``fetched``, at host time ``fetched_at``)."""
-        return {"queue_depth": self.metrics["queue_depth"][-1],
+        tokens it fetched (``fetched``, at host time ``fetched_at``).
+        A dispatched fast-path step adds ``decode_pages_read`` (the pages
+        its decode attention fetches, by the kernel's rule) and
+        ``decode_pages_table`` (rows x table width)."""
+        rec = {"queue_depth": self.metrics["queue_depth"][-1],
                 "occupancy": self.metrics["occupancy"][-1],
                 "active": len(self._active),
                 "packed_tokens": plan.n_tokens,
@@ -728,6 +737,9 @@ class ServeEngine:
                 "fetched_prefill_tokens": (fetched.plan.prefill_tokens
                                            if fetched else 0),
                 "fetched_at": fetched_at}
+        if inf is not None and inf.pages is not None:
+            rec["decode_pages_read"], rec["decode_pages_table"] = inf.pages
+        return rec
 
     def _step_sync(self) -> dict:
         """The synchronous unified cycle (REPRO_SYNC_STEP /

@@ -280,16 +280,30 @@ def test_slot_tables_lifecycle():
 
 # ------------------------------------------------- kernel vs oracle
 
-def _rand_paged(seed, b=3, kvh=2, g=2, hd=16, page=8, n_ptab=3):
+def _rand_paged(seed, b=3, kvh=2, g=2, hd=16, page=8, n_ptab=3,
+                quantized_normal=False):
+    """Random pool, table and lengths. Codes and scales are uniform, or
+    with ``quantized_normal`` the int8 quantization (scale = amax / 127
+    per token and head) of unit-normal K and V, as the pool holds them."""
     from repro.kernels import ref
     rng = np.random.default_rng(seed)
     n_pages = 1 + b * n_ptab
     q = jnp.asarray(rng.normal(size=(b, kvh, g, hd)).astype(np.float32))
-    mk = lambda: jnp.asarray(rng.integers(  # noqa: E731
-        -127, 128, size=(n_pages, page, kvh, hd)).astype(np.int8))
-    ms = lambda: jnp.asarray(rng.uniform(  # noqa: E731
-        0.01, 0.1, size=(n_pages, page, kvh, 1)).astype(np.float32))
-    kp, vp, ks, vs = mk(), mk(), ms(), ms()
+
+    def quantized():
+        x = rng.normal(size=(n_pages, page, kvh, hd)).astype(np.float32)
+        sc = np.abs(x).max(-1, keepdims=True) / 127
+        return (jnp.asarray(np.round(x / sc).astype(np.int8)),
+                jnp.asarray(sc))
+
+    if quantized_normal:
+        (kp, ks), (vp, vs) = quantized(), quantized()
+    else:
+        mk = lambda: jnp.asarray(rng.integers(  # noqa: E731
+            -127, 128, size=(n_pages, page, kvh, hd)).astype(np.int8))
+        ms = lambda: jnp.asarray(rng.uniform(  # noqa: E731
+            0.01, 0.1, size=(n_pages, page, kvh, 1)).astype(np.float32))
+        kp, vp, ks, vs = mk(), mk(), ms(), ms()
     table = np.zeros((b, n_ptab), np.int32)
     lengths = np.zeros((b,), np.int32)
     for i in range(b):
@@ -308,6 +322,75 @@ def test_paged_attention_kernel_vs_oracle(seed):
     got = ops.paged_attention(*args)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+WIDTHS = [(4, 2, 64), (8, 4, 128), (1, 8, 128)]   # (KVH, g, hd)
+
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("kvh,g,hd", WIDTHS,
+                         ids=[f"kvh{k}g{g}hd{d}" for k, g, d in WIDTHS])
+def test_paged_attention_kernel_vs_oracle_widths(kvh, g, hd, seed):
+    """The cell's widths, Mistral-NeMo's (8 KV heads of 128) and MQA's,
+    over a table several blocks long whose width is no multiple of the
+    block."""
+    from repro.kernels import ops
+    from repro.kernels.paged_attention import _pages_per_block
+    ppb = _pages_per_block(16, kvh, hd, 64)
+    n_ptab = 2 * ppb + ppb // 2 + 1
+    assert n_ptab % ppb
+    args, want = _rand_paged(seed, b=4, kvh=kvh, g=g, hd=hd, page=16,
+                             n_ptab=n_ptab, quantized_normal=True)
+    got = ops.paged_attention(*args)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def _block_lengths(kvh=4, g=2, hd=64, page=16, seed=5):
+    """Rows of 1 kv row, one short of a block, exactly a block, one past
+    a block and the whole table, over a table whose width is no multiple
+    of the block; (args, page table, block rows)."""
+    from repro.kernels.paged_attention import _pages_per_block
+    ppb = _pages_per_block(page, kvh, hd, 64)
+    n_ptab = 2 * ppb + ppb // 2 + 1
+    rows = ppb * page
+    lengths = [1, rows - 1, rows, rows + 1, n_ptab * page]
+    (q, kp, ks, vp, vs, _, _), _ = _rand_paged(
+        seed, b=len(lengths), kvh=kvh, g=g, hd=hd, page=page, n_ptab=n_ptab,
+        quantized_normal=True)
+    table = np.zeros((len(lengths), n_ptab), np.int32)
+    for i, n in enumerate(lengths):
+        n_owned = -(-n // page)
+        table[i, :n_owned] = 1 + i * n_ptab + np.arange(n_owned)
+    return (q, kp, ks, vp, vs, table, jnp.asarray(lengths, jnp.int32))
+
+
+def test_paged_attention_block_edge_lengths_vs_oracle():
+    from repro.kernels import ops, ref
+    q, kp, ks, vp, vs, table, lengths = _block_lengths()
+    args = (q, kp, ks, vp, vs, jnp.asarray(table), lengths)
+    np.testing.assert_allclose(np.asarray(ops.paged_attention(*args)),
+                               np.asarray(ref.paged_attention_decode(*args)),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_paged_attention_dead_blocks_are_never_read():
+    """Every table entry past a row's live pages names a page of extreme
+    codes and NaN scales: the output is bitwise the clean run's, so no
+    dead page is fetched or weighted."""
+    from repro.kernels import ops
+    q, kp, ks, vp, vs, table, lengths = _block_lengths()
+    base = ops.paged_attention(q, kp, ks, vp, vs, jnp.asarray(table),
+                               lengths)
+    poison = kp.shape[0]                 # one page past the pool
+    pad = lambda x, val: jnp.concatenate(  # noqa: E731
+        [x, jnp.full((1,) + x.shape[1:], val, x.dtype)])
+    poisoned = np.where(table == 0, poison, table)
+    got = ops.paged_attention(q, pad(kp, 127), pad(ks, jnp.nan),
+                              pad(vp, -127), pad(vs, jnp.nan),
+                              jnp.asarray(poisoned), lengths)
+    assert (poisoned == poison).sum() > table.shape[1]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
 
 
 def test_paged_attention_ragged_last_page_exact_zero_weight():

@@ -151,6 +151,20 @@ def test_paged_attention_decode_compiles(one_chip, no_cache):
              + [((8, N_PTAB), jnp.int32), ((8,), jnp.int32)], one_chip)
 
 
+def test_paged_attention_decode_compiles_nemo_widths(one_chip, no_cache):
+    """Mistral-NeMo's widths (8 KV heads of 128, 32 query heads) over the
+    cell's 320-page tables: the block of pages one grid step fetches,
+    sized from the shapes, fits the default scoped VMEM."""
+    from repro.kernels.paged_attention import paged_attention_decode
+    kvh, hd, n_ptab = 8, 128, 320
+    n_pages = 1 + 8 * n_ptab
+    pool = [((n_pages, PAGE, kvh, hd), jnp.int8),
+            ((n_pages, PAGE, kvh, 1), jnp.float32)] * 2
+    _compile(lambda q, *a: paged_attention_decode(q, *a, interpret=False),
+             [((8, kvh, 4, hd), jnp.bfloat16)] + pool
+             + [((8, n_ptab), jnp.int32), ((8,), jnp.int32)], one_chip)
+
+
 def test_paged_attention_ragged_compiles(one_chip, no_cache):
     from repro.kernels.paged_attention import paged_attention_ragged
     nq = 256          # the smoke budget: one item may fill the step

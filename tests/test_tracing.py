@@ -117,3 +117,30 @@ def test_step_record_invariants(pipeline):
     assert sum(r["prefill_tokens"] for r in recs) \
         == sum(len(r["tokens"]) for r in reqs)
     assert all(r["packed_tokens"] >= r["prefill_tokens"] for r in recs)
+
+
+def test_decode_records_count_the_pages_the_kernel_reads():
+    """A fast-path record counts, for the positions its step shipped,
+    each row's pages up to its last kv row (``ceil((pos + 1) / page)``,
+    at most the table width) against rows x table width; a ragged step's
+    record carries neither count."""
+    eng, _ = _engine(pipeline=True)
+    page, n_ptab = eng.pool.page_size, eng.tables.table.shape[1]
+    shipped = []
+    decode_step = eng.exec.decode_step
+
+    def spy(tok, pos, table, src=None):
+        shipped.append(np.array(pos))
+        return decode_step(tok, pos, table, src)
+
+    eng.exec.decode_step = spy
+    recs = _drain(eng)
+    decode = [r for r in recs if r["kind"] == "decode"]
+    assert len(decode) == len(shipped) > 0
+    for r, pos in zip(decode, shipped):
+        want = sum(min(-(-(int(p) + 1) // page), n_ptab) for p in pos)
+        assert r["decode_pages_read"] == want
+        assert r["decode_pages_table"] == len(pos) * n_ptab
+        assert 0 < r["decode_pages_read"] <= r["decode_pages_table"]
+    assert not any("decode_pages_read" in r for r in recs
+                   if r["kind"] != "decode")
