@@ -244,7 +244,12 @@ def one_chip(seed: int, dev) -> None:
         vocab=cfg.vocab, weight_bytes=mem["weight_bytes"],
         qlinear_layers=mem["qlinear_layers"])
     requests = workload(cfg, seed)
+    prompt = requests[0]["tokens"]
+    with jax.default_matmul_precision("highest"):
+        want = prefill_logits(build(cfg.scaled(dtype="float32")), params,
+                              prompt)
 
+    # the engine takes ``params`` over; phase B serves phase A's tree
     eng_a, res_a, steps_a, _ = serve_phase("A", model, params, requests, cfg)
     text = unified_step_text(eng_a, steps_a[0][0][0])
     n_a = kernel_calls(text)
@@ -252,17 +257,14 @@ def one_chip(seed: int, dev) -> None:
     check(n_a > 0 and "fused_cat_matmul_w4" in text,
           "phase A: the unified step holds no fused CAT kernel")
 
-    prompt = requests[0]["tokens"]
     got = prefill_logits(model, eng_a.params, prompt)
-    with jax.default_matmul_precision("highest"):
-        want = prefill_logits(build(cfg.scaled(dtype="float32")), params,
-                              prompt)
     err = rel_err(got, want)
     agree = float((got.argmax(-1) == want.argmax(-1)).mean())
     say(phase="logits", prompt_len=len(prompt), rel_err=err,
         rtol=LOGIT_RTOL, argmax_agreement=agree)
     check(err <= LOGIT_RTOL, f"kernel-route logits rel err {err:.3g} > "
                              f"{LOGIT_RTOL}")
+    params = eng_a.params
     del eng_a
 
     eng_b, res_b, steps_b, decodes_b = serve_phase(

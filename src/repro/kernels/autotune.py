@@ -15,6 +15,12 @@ Two modes:
 
 The cache is process-local and keyed on the caller's shape tuple; entries
 are never evicted (a serving process sees a few dozen shapes at most).
+
+``vmem_params`` turns a kernel's VMEM estimate for its chosen blocks
+(``tile_bytes`` counts the chip's tile padding) into the scoped-VMEM limit
+the kernel asks Mosaic for, where the default is too small: at
+Mistral-NeMo-12B's widths the fused CAT linears and the ragged attention
+kernel need more than 16 MiB.
 """
 from __future__ import annotations
 
@@ -25,6 +31,10 @@ from typing import Callable, Sequence
 # Conservative slice of the ~16 MiB/core VMEM: leaves headroom for
 # Mosaic's own double-buffering of the streamed weight blocks.
 VMEM_BUDGET = 8 * 2**20
+# Mosaic's scoped VMEM limit for a kernel that sets none, and the most a
+# kernel asks for (a v5e TensorCore has 128 MiB of VMEM).
+DEFAULT_SCOPED_VMEM = 16 * 2**20
+MAX_SCOPED_VMEM = 96 * 2**20
 
 _CACHE: dict = {}
 
@@ -45,6 +55,25 @@ def _fused_working_set(tm: int, tn: int, tk: int, d: int, packed: bool) -> int:
     w_blk = (tk // 2 if packed else tk) * tn  # int8/packed weight block
     out = tm * tn * 4
     return x_tile + scratch + w_blk + out
+
+
+def tile_bytes(rows: int, lanes: int, itemsize: int) -> int:
+    """VMEM bytes of one (rows, lanes) minor slab after tile padding."""
+    sub = 8 * 4 // itemsize
+    return (-(-rows // sub) * sub) * (-(-lanes // 128) * 128) * itemsize
+
+
+def vmem_params(need: int):
+    """Mosaic compiler parameters for a kernel whose blocks, scratch and
+    in-kernel values take about ``need`` bytes of VMEM: None where the
+    default scoped limit holds that, so the kernel compiles as it always
+    has, else a limit of twice ``need`` (the estimate leaves out Mosaic's
+    own temporaries), at most ``MAX_SCOPED_VMEM``."""
+    if need <= DEFAULT_SCOPED_VMEM:
+        return None
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(2 * need, MAX_SCOPED_VMEM))
 
 
 def heuristic_blocks(m: int, d: int, n: int, packed: bool,
@@ -132,4 +161,4 @@ def prologue_blocks(d: int, n: int, n_kv: int, packed: bool,
 
 __all__: Sequence[str] = ("pick", "heuristic_blocks", "gemv_blocks",
                           "prologue_blocks", "cache_info", "cache_clear",
-                          "VMEM_BUDGET")
+                          "tile_bytes", "vmem_params", "VMEM_BUDGET")

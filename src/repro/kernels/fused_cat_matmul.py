@@ -43,12 +43,14 @@ kernels' dots.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .autotune import tile_bytes, vmem_params
 from .hadamard import kron_rows
 from .quant_matmul_w4 import _GEMV_M, _unpack_block, int8_contract
 
@@ -162,6 +164,26 @@ def _prep_operands(x, blocks, ha, hb, sign, qw, sw, packed, tm, tn, tk):
     return x, sign.reshape(1, d), qw, sw, dict(m=m, d=d, n=n, k_pad=k0 + pk)
 
 
+def _vmem_bytes(operands, tm: int, tn: int, tk: int, k_pad: int,
+                packed: bool) -> int:
+    """VMEM one grid step takes, in the chip's padded tiles: two buffers
+    of each input block (the (tm, D) activation tile, the transform
+    factors, the weight and scale blocks) and of the (tm, tn) f32 output
+    block, the code scratch, and four f32 copies of the activation tile
+    for the in-kernel transform. The (k, k) CAT blocks pad to 128 lanes:
+    at k = 32 they take four times their size."""
+    x, *factors = operands[:-2]
+    d = x.shape[1]
+    mats = sum(tile_bytes(*f.shape[-2:], 4) * math.prod(f.shape[:-2])
+               for f in factors)
+    ins = (tile_bytes(tm, d, x.dtype.itemsize) + mats
+           + tile_bytes(tk // 2 if packed else tk, tn, 1)
+           + tile_bytes(1, tn, 4))
+    scratch = tile_bytes(tm, k_pad, 1) + 2 * tile_bytes(tm, 1, 4)
+    return (2 * (ins + tile_bytes(tm, tn, 4)) + scratch
+            + 4 * tile_bytes(tm, d, 4))
+
+
 @functools.partial(jax.jit, static_argnames=("act_bits", "packed",
                                              "block_m", "block_n", "block_k",
                                              "out_dtype", "interpret"))
@@ -219,6 +241,8 @@ def fused_cat_matmul_w4(x, blocks, ha, hb, sign, qw, sw, *,
                         pltpu.VMEM((tm, 1), jnp.float32),
                         pltpu.VMEM((tm, 1), jnp.float32)],
         name="fused_cat_matmul_w4",
+        compiler_params=vmem_params(_vmem_bytes(operands, tm, tn, tk, k_pad,
+                                                packed)),
         interpret=interpret,
     )(*operands)
     return out[:m, :n]
@@ -273,6 +297,8 @@ def fused_cat_gemv_w4(x, blocks, ha, hb, sign, qw, sw, *,
                         pltpu.VMEM((_GEMV_M, 1), jnp.float32),
                         pltpu.VMEM((_GEMV_M, 1), jnp.float32)],
         name="fused_cat_gemv_w4",
+        compiler_params=vmem_params(_vmem_bytes(operands, _GEMV_M, tn, tk,
+                                                k_pad, packed)),
         interpret=interpret,
     )(*operands)
     return out[:m, :n]

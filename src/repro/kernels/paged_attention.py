@@ -54,6 +54,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .autotune import tile_bytes, vmem_params
+
 _NEG_INF = -1e30
 
 
@@ -65,20 +67,14 @@ _BLOCK_ROWS = 128
 _BLOCK_VMEM_BYTES = 4 << 20
 
 
-def _tile_bytes(rows: int, lanes: int, itemsize: int) -> int:
-    """VMEM bytes of one (rows, lanes) minor slab after tile padding."""
-    sub = 8 * 4 // itemsize
-    return (-(-rows // sub) * sub) * (-(-lanes // 128) * 128) * itemsize
-
-
 def _pages_per_block(page_size: int, kvh: int, hd: int, n_ptab: int,
                      code_bytes: int = 1, scale_bytes: int = 4) -> int:
     """Table pages one decode grid step fetches and computes: about
     ``_BLOCK_ROWS`` KV rows, as many as keep both buffers of K and V
     (codes + scales) within ``_BLOCK_VMEM_BYTES``, never more than the
     table holds."""
-    per_page = 2 * 2 * page_size * (_tile_bytes(kvh, hd, code_bytes)
-                                    + _tile_bytes(kvh, 1, scale_bytes))
+    per_page = 2 * 2 * page_size * (tile_bytes(kvh, hd, code_bytes)
+                                    + tile_bytes(kvh, 1, scale_bytes))
     return max(1, min(_BLOCK_ROWS // page_size,
                       _BLOCK_VMEM_BYTES // per_page, n_ptab))
 
@@ -280,6 +276,23 @@ def _ragged_attn_kernel(len_ref, pt_ref, q_ref, qpos_ref, k_ref, ks_ref,
         o_ref[...] = out.astype(o_ref.dtype)[None]
 
 
+def _ragged_vmem_bytes(kvh: int, qg: int, hd: int, page_size: int,
+                       q_bytes: int, code_bytes: int, scale_bytes: int
+                       ) -> int:
+    """VMEM one ragged grid step takes, in the chip's padded tiles: two
+    buffers of the item's (KVH, Q·g, hd) query and output blocks, of its
+    positions and of one page's codes and scales; the (m, l, acc)
+    scratch; and the f32 values of a page (scores, weights, the product
+    into acc)."""
+    per_head = (4 * tile_bytes(qg, hd, q_bytes)      # q, out buffers
+                + 2 * tile_bytes(qg, 1, 4)            # m, l
+                + 3 * tile_bytes(qg, hd, 4)           # acc, acc update
+                + 2 * tile_bytes(qg, page_size, 4))   # scores, weights
+    pages = 2 * 2 * page_size * (tile_bytes(kvh, hd, code_bytes)
+                                 + tile_bytes(kvh, 1, scale_bytes))
+    return kvh * per_head + 2 * tile_bytes(qg, 1, 4) + pages
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
                            k_scale: jnp.ndarray, v_pages: jnp.ndarray,
@@ -346,6 +359,9 @@ def paged_attention_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, qg, hd), q.dtype),
         name="paged_attention_ragged",
+        compiler_params=vmem_params(_ragged_vmem_bytes(
+            kvh, qg, hd, page_size, q.dtype.itemsize,
+            k_pages.dtype.itemsize, k_scale.dtype.itemsize)),
         interpret=interpret,
     )(lengths, page_table, qs, qpos, k_pages, k_scale, v_pages, v_scale)
     return out.reshape(b, kvh, nq, g, hd).transpose(0, 2, 1, 3, 4)

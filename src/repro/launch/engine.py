@@ -103,6 +103,21 @@ class _InFlight:
         return "decode" if self.slot_major else "unified"
 
 
+def serving_params(make_serving_params, params):
+    """``make_serving_params(params)`` as one jitted program. Run eagerly,
+    its per-layer steps unpacked every concatenated weight of all layers
+    to int32 at once; at Mistral-NeMo-12B's widths that ran the chip out
+    of memory. On an accelerator the program takes ``params`` over (their
+    buffers are donated); on the CPU, where they live in host memory, the
+    caller's tree stays valid. A tree that is served already (the program
+    would change none of its structure) is returned as it is."""
+    served = jax.eval_shape(make_serving_params, params)
+    if jax.tree.structure(served) == jax.tree.structure(params):
+        return params
+    donate = (0,) if jax.default_backend() != "cpu" else ()
+    return jax.jit(make_serving_params, donate_argnums=donate)(params)
+
+
 # ------------------------------------------------------------------ engine
 
 class ServeEngine:
@@ -111,6 +126,11 @@ class ServeEngine:
     ``model``/``params`` follow ``repro.models.Model``; the model family
     must support per-slot position vectors in its decode cache (dense
     does). ``max_len`` bounds prompt + generated tokens per slot.
+
+    On an accelerator the engine takes ``params`` over when it serves
+    them (``fused``, see ``serving_params``): their buffers are donated.
+    A caller that builds another engine from the same weights passes
+    ``engine.params``, which are served already and taken as they are.
     """
 
     _SLOT_FAMILIES = ("dense", "moe", "vlm")   # families with (B,) pos decode
@@ -281,7 +301,7 @@ class ServeEngine:
         msp = getattr(model, "make_serving_params", None)
         self.fused = bool(fused and msp is not None and mesh is None)
         if self.fused:
-            params = msp(params)
+            params = serving_params(msp, params)
         tp_kw = dict(mesh=mesh, tp_axis=tp_axis, tp_mode=tp_mode,
                      tp_kernels=tp_kernels)
         if schedule == "unified":

@@ -180,3 +180,77 @@ def test_compile_helper_finds_no_kernel_in_plain_xla(one_chip, no_cache):
         jax.ShapeDtypeStruct((8, 128), jnp.float32,
                              sharding=one_chip)).compile().as_text()
     assert "tpu_custom_call" not in text
+
+
+# Mistral-NeMo-12B's widths: d_model 5120, 32 query heads and 8 KV heads
+# of 128 (q_dim 4096, groups of 4), d_ff 14336; the ``nemo8.azconv``
+# cell's 512-token step budget and 8 slots of 320 pages.
+NEMO_D, NEMO_FF, NEMO_Q, NEMO_KV, NEMO_HD = 5120, 14336, 4096, 1024, 128
+NEMO_KVH, NEMO_G = NEMO_KV // NEMO_HD, NEMO_Q // NEMO_KV
+
+
+def _nemo_cat_shapes(m, d):
+    from repro.core.hadamard import hadamard_factors
+    ha, hb = hadamard_factors(d)
+    return [((m, d), jnp.bfloat16), ((d // 32, 32, 32), jnp.float32),
+            (ha.shape, jnp.float32), (hb.shape, jnp.float32),
+            ((d,), jnp.float32)]
+
+
+def _nemo_pool(n_ptab=320):
+    n_pages = 1 + 8 * n_ptab
+    return [((n_pages, PAGE, NEMO_KVH, NEMO_HD), jnp.int8),
+            ((n_pages, PAGE, NEMO_KVH, 1), jnp.float32)] * 2
+
+
+@pytest.mark.parametrize("m", [8, 512], ids=["gemv", "matmul"])
+@pytest.mark.parametrize("d,n", [(NEMO_D, NEMO_Q + 2 * NEMO_KV),
+                                 (NEMO_D, 2 * NEMO_FF), (NEMO_Q, NEMO_D),
+                                 (NEMO_FF, NEMO_D)],
+                         ids=["wqkv", "wgu", "wo", "wd"])
+def test_fused_cat_matmul_compiles_nemo_widths(one_chip, no_cache, m, d, n):
+    """K up to 14336 and N up to 28672 through the serving dispatch: a
+    512-row step asks for more than the default scoped VMEM, and the
+    kernel sets its limit from the shapes."""
+    from repro.kernels import ops
+
+    def f(x, blocks, ha, hb, sign, qw, sw):
+        return ops.fused_cat_matmul(x, blocks, ha, hb, sign, qw, sw,
+                                    act_bits=8, packed=True,
+                                    interpret=False)
+
+    _compile(f, _nemo_cat_shapes(m, d) + [((d // 2, n), jnp.int8),
+                                          ((1, n), jnp.float32)], one_chip)
+
+
+def test_decode_qkv_prologue_compiles_nemo_widths(one_chip, no_cache):
+    from repro.kernels.ops import decode_qkv_prologue
+    n = NEMO_Q + 2 * NEMO_KV
+
+    def f(x, blocks, ha, hb, sign, qw, sw, kp, ks, vp, vs, pid, rid, pos):
+        return decode_qkv_prologue(
+            x, blocks, ha, hb, sign, qw, sw, kp, ks, vp, vs, pid, rid, pos,
+            n_q=NEMO_Q, head_dim=NEMO_HD, rope_theta=1e6, interpret=False)
+
+    _compile(f, _nemo_cat_shapes(8, NEMO_D)
+             + [((NEMO_D // 2, n), jnp.int8), ((1, n), jnp.float32)]
+             + _nemo_pool() + [((8,), jnp.int32)] * 3, one_chip)
+
+
+@pytest.mark.parametrize("kvh,g,hd,pool", [
+    (NEMO_KVH, NEMO_G, NEMO_HD, "nemo"), (KVH, G, HD, "catlm")],
+    ids=["nemo", "catlm"])
+def test_paged_attention_ragged_compiles_cell_budget(one_chip, no_cache, kvh,
+                                                     g, hd, pool):
+    """Each of 8 work items a 512-row query block over 320-page tables,
+    as the cells' unified steps run it: more than the default scoped VMEM
+    at both models' widths, so the kernel sets its limit."""
+    from repro.kernels.paged_attention import paged_attention_ragged
+    nq, n_ptab = 512, 320
+    pools = _nemo_pool(n_ptab) if pool == "nemo" else [
+        ((1 + 8 * n_ptab, PAGE, kvh, hd), jnp.int8),
+        ((1 + 8 * n_ptab, PAGE, kvh, 1), jnp.float32)] * 2
+    _compile(lambda q, *a: paged_attention_ragged(q, *a, interpret=False),
+             [((8, nq, kvh, g, hd), jnp.bfloat16)] + pools
+             + [((8, n_ptab), jnp.int32), ((8,), jnp.int32),
+                ((8, nq), jnp.int32)], one_chip)
