@@ -41,12 +41,17 @@ backends without Pallas.
 ``paged_attention_ragged`` generalizes the q_len=1 decode kernel to a
 *block of queries per sequence* with a per-(query, kv) causal mask — the
 shape of a unified token-budget step, where one launch covers every
-prefill chunk and decode token packed into the step and each sequence's
-pages stream exactly once (see ``repro.launch.scheduler``).
+prefill chunk and decode token packed into the step (see
+``repro.launch.scheduler``). Its grid is the same kind of dynamic grid,
+one step per live (item, query tile, KV block): an item's query rows are
+cut into tiles of up to 128 rows, a tile without a live row takes no
+step, and a tile's blocks stop at its causal horizon. ``ragged_work``
+counts what it computes, on the host.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -54,7 +59,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .autotune import tile_bytes, vmem_params
+from .autotune import DEFAULT_SCOPED_VMEM, tile_bytes, vmem_params
 
 _NEG_INF = -1e30
 
@@ -97,19 +102,33 @@ def _live_blocks(lengths, page_size: int, n_ptab: int, ppb: int):
     return pages, jnp.maximum((pages + ppb - 1) // ppb, 1)
 
 
-def _page_index(j: int, ppb: int, page_size: int, n_ptab: int):
+def _page_index(j: int, ppb: int, page_size: int, n_ptab: int, where):
     """Index map of the ``j``-th page operand: the physical page of table
-    entry ``i * ppb + j`` of the step's row while that entry is live;
-    past the live context, the last live entry this operand held, so the
-    pipeline sees an unchanged block and fetches nothing. An operand with
-    no live entry in the row holds the row's last live page."""
-    def index(s, ln, pt, row, blk):
-        bb, i = row[s], blk[s]
-        last = _live_blocks(ln[bb], page_size, n_ptab, ppb)[0] - 1
+    entry ``i * ppb + j`` of the step's table row while that entry is
+    live; past the live context, the last live entry this operand held,
+    so the pipeline sees an unchanged block and fetches nothing. An
+    operand with no live entry in the row holds the row's last live
+    page. ``where(s, *prefetch)`` gives step ``s``'s (page table, table
+    row, live KV rows, block)."""
+    def index(s, *prefetch):
+        pt, bb, length, i = where(s, *prefetch)
+        last = _live_blocks(length, page_size, n_ptab, ppb)[0] - 1
         held = jnp.where(j <= last, j + (last - j) // ppb * ppb,
                          jnp.maximum(last, 0))
         return (pt[bb, jnp.minimum(i * ppb + j, held)], 0, 0, 0)
     return index
+
+
+def _decode_step(s, ln, pt, row, blk):
+    """Page table, table row, KV length and block of decode step ``s``."""
+    return pt, row[s], ln[row[s]], blk[s]
+
+
+def _dequant(codes, scales):
+    """A block's pages, dequantized, kv head first: (KVH, rows, hd)."""
+    return jnp.swapaxes(jnp.concatenate(
+        [c[0].astype(jnp.float32) * sc[0] for c, sc in zip(codes, scales)],
+        axis=0), 0, 1)
 
 
 def _paged_attn_kernel(len_ref, pt_ref, row_ref, blk_ref, q_ref, *refs,
@@ -131,16 +150,9 @@ def _paged_attn_kernel(len_ref, pt_ref, row_ref, blk_ref, q_ref, *refs,
 
     @pl.when(i * rows < length)
     def _block():
-        def dequant(codes, scales):
-            """The block's pages, dequantized, kv head first:
-            (KVH, rows, hd)."""
-            return jnp.swapaxes(jnp.concatenate(
-                [c[0].astype(jnp.float32) * sc[0]
-                 for c, sc in zip(codes, scales)], axis=0), 0, 1)
-
         q = q_ref[0].astype(jnp.float32)              # (KVH, g, hd), pre-scaled
-        k = dequant(k_refs, ks_refs)
-        v = dequant(v_refs, vs_refs)
+        k = _dequant(k_refs, ks_refs)
+        v = _dequant(v_refs, vs_refs)
 
         # scores for this block, batched over kv heads: (KVH, g, rows);
         # entries past the live context hold a repeated live page and get
@@ -202,7 +214,8 @@ def paged_attention_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
     blk = steps - (ends - n_blocks)[row]
 
     qs = (q.astype(jnp.float32) * hd ** -0.5).astype(q.dtype)
-    maps = [_page_index(j, ppb, page_size, n_ptab) for j in range(ppb)]
+    maps = [_page_index(j, ppb, page_size, n_ptab, _decode_step)
+            for j in range(ppb)]
     codes = [pl.BlockSpec((1, page_size, kvh, hd), m) for m in maps]
     scales = [pl.BlockSpec((1, page_size, kvh, 1), m) for m in maps]
     per_row = pl.BlockSpec((1, kvh, g, hd),
@@ -230,11 +243,124 @@ def paged_attention_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
 
 # ------------------------------------------------- ragged (mixed q_len)
 
-def _ragged_attn_kernel(len_ref, pt_ref, q_ref, qpos_ref, k_ref, ks_ref,
-                        v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                        page_size: int):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
+# A ragged grid step multiplies at most this many query rows: the MXU's
+# height.
+_TILE_ROWS = 128
+
+
+def _ragged_vmem_bytes(kvh: int, tile: int, hd: int, page_size: int,
+                       ppb: int, q_bytes: int, code_bytes: int,
+                       scale_bytes: int) -> int:
+    """VMEM one ragged grid step takes, in the chip's padded tiles: two
+    buffers of the tile's (KVH, tile, hd) query and output blocks, of
+    its positions and of ``ppb`` pages' codes and scales of K and V; the
+    (m, l, acc) scratch; and the f32 values of a block (the query,
+    dequantized K and V, scores, weights, the product into acc)."""
+    rows = ppb * page_size
+    per_head = (4 * tile_bytes(tile, hd, q_bytes)       # q, out buffers
+                + 2 * tile_bytes(tile, 1, 4)             # m, l
+                + 4 * tile_bytes(tile, hd, 4)            # q, acc, update
+                + 2 * tile_bytes(tile, rows, 4)          # scores, weights
+                + 2 * tile_bytes(rows, hd, 4))           # K, V
+    pages = 2 * 2 * rows * (tile_bytes(kvh, hd, code_bytes)
+                            + tile_bytes(kvh, 1, scale_bytes))
+    return kvh * per_head + 2 * tile_bytes(tile, 1, 4) + pages
+
+
+def _ragged_blocks(qg: int, g: int, kvh: int, hd: int, page_size: int,
+                   n_ptab: int, code_bytes: int = 1,
+                   scale_bytes: int = 4) -> tuple:
+    """(query tile, pages per block) of a ragged call. The tile is the
+    item's whole ``Q·g`` rows where they are at most ``_TILE_ROWS``,
+    else whole queries in whole 16-row sublane tiles (even for f32 and
+    bf16), up to ``_TILE_ROWS``, and fewer where a step's VMEM need,
+    its query block counted as f32, would pass the default scoped
+    limit. ``ppb`` is the decode kernel's."""
+    ppb = _pages_per_block(page_size, kvh, hd, n_ptab, code_bytes,
+                           scale_bytes)
+    if qg <= _TILE_ROWS:
+        return qg, ppb
+    unit = math.lcm(g, 16)
+    tile = max(unit, _TILE_ROWS // unit * unit)
+    while tile > unit and _ragged_vmem_bytes(
+            kvh, tile, hd, page_size, ppb, 4, code_bytes,
+            scale_bytes) > DEFAULT_SCOPED_VMEM:
+        tile -= unit
+    return tile, ppb
+
+
+def _row_positions(q_pos, g: int, tile: int, xp):
+    """(B, n_tiles·tile) position of each query row: query-major, ``g``
+    rows a query, padded with -1 to whole tiles. ``xp`` is ``numpy`` or
+    ``jax.numpy``."""
+    nq = q_pos.shape[1]
+    pad = -(-nq * g // tile) * tile - nq * g
+    return xp.pad(xp.repeat(q_pos, g, axis=1), ((0, 0), (0, pad)),
+                  constant_values=-1)
+
+
+def _tile_ends(rows, lengths, tile: int, xp):
+    """(B, n_tiles) end of each query tile's KV range: ``min(max qpos in
+    the tile + 1, length)``; -1 for a tile without a live row."""
+    top = rows.reshape(rows.shape[0], -1, tile).max(axis=-1)
+    return xp.where(top >= 0, xp.minimum(top + 1, lengths[:, None]), -1)
+
+
+def _tile_blocks(ends, page_size: int, n_ptab: int, ppb: int, xp):
+    """KV blocks a tile of KV range ``ends`` takes: its live pages in
+    blocks of ``ppb``, at least one for a live tile (which writes its
+    output), none for a tile without a live row."""
+    pages = xp.minimum(-(-xp.maximum(ends, 0) // page_size), n_ptab)
+    return xp.where(ends >= 0, xp.maximum(-(-pages // ppb), 1), 0)
+
+
+def ragged_work(lengths, q_pos, g: int, kvh: int, hd: int, page_size: int,
+                n_ptab: int) -> tuple:
+    """(query rows x KV pages ``paged_attention_ragged`` computes, the
+    same of a walk of every row over the whole table: ``B x Q·g x
+    n_ptab``) for an int8 pool. Each visited (item, tile, block) step
+    counts its tile's rows times its ``ppb`` pages; a call with no live
+    row still takes one step. Host-side (numpy), by the kernel's own
+    rule."""
+    q_pos = np.asarray(q_pos, np.int64)
+    b, nq = q_pos.shape
+    tile, ppb = _ragged_blocks(nq * g, g, kvh, hd, page_size, n_ptab)
+    ends = _tile_ends(_row_positions(q_pos, g, tile, np),
+                      np.asarray(lengths, np.int64), tile, np)
+    steps = max(int(_tile_blocks(ends, page_size, n_ptab, ppb, np).sum()),
+                1)
+    return steps * tile * ppb, b * nq * g * n_ptab
+
+
+def _ragged_grid(rows, lengths, tile: int, page_size: int, n_ptab: int,
+                 ppb: int):
+    """The ragged kernel's dynamic grid over row positions ``rows``
+    (``_row_positions``): -> (ends, work, blk, steps). ``ends``
+    (B·n_tiles,) is each work unit's KV range (``_tile_ends``, flattened
+    item-major); step ``s < steps`` computes block ``blk[s]`` of work
+    unit ``work[s]`` = item x n_tiles + tile. Live units follow one
+    another, each over its blocks in order; units without a live row
+    take no step (one step in all where none is live)."""
+    ends = _tile_ends(rows, lengths, tile, jnp).reshape(-1)
+    n_blocks = _tile_blocks(ends, page_size, n_ptab, ppb, jnp)
+    stops = jnp.cumsum(n_blocks)
+    bound = ends.shape[0] * pl.cdiv(n_ptab, ppb)
+    steps = jnp.arange(bound, dtype=jnp.int32)
+    work = jnp.minimum(jnp.sum(stops[None, :] <= steps[:, None], axis=1,
+                               dtype=jnp.int32), ends.shape[0] - 1)
+    blk = steps - (stops - n_blocks)[work]
+    return ends, work, blk, jnp.maximum(stops[-1], 1)
+
+
+def _ragged_attn_kernel(pt_ref, end_ref, work_ref, blk_ref, q_ref,
+                        qpos_ref, *refs, page_size: int, ppb: int):
+    k_refs, ks_refs, v_refs, vs_refs = (refs[n * ppb:(n + 1) * ppb]
+                                        for n in range(4))
+    o_ref, m_ref, l_ref, acc_ref = refs[4 * ppb:]
+    s = pl.program_id(0)
+    i, end = blk_ref[s], end_ref[work_ref[s]]
+    rows = ppb * page_size
+    n_blocks = _live_blocks(end, page_size, pt_ref.shape[1], ppb)[1]
 
     @pl.when(i == 0)
     def _init():
@@ -242,55 +368,46 @@ def _ragged_attn_kernel(len_ref, pt_ref, q_ref, qpos_ref, k_ref, ks_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)              # (KVH, Q·g, hd)
-    qpos = qpos_ref[0]                            # (Q·g, 1) absolute positions
-    # dequantize, then put the kv head first: (G, KVH, hd) -> (KVH, G, hd)
-    k = jnp.swapaxes(k_ref[0].astype(jnp.float32) * ks_ref[0], 0, 1)
-    v = jnp.swapaxes(v_ref[0].astype(jnp.float32) * vs_ref[0], 0, 1)
+    @pl.when(i * rows < end)
+    def _block():
+        q = q_ref[0].astype(jnp.float32)          # (KVH, tile, hd)
+        qpos = qpos_ref[0]                        # (tile, 1) positions
+        k = _dequant(k_refs, ks_refs)             # (KVH, rows, hd)
+        v = _dequant(v_refs, vs_refs)
 
-    # scores for this page, batched over kv heads: (KVH, Q·g, G)
-    s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32)
-    kv_pos = i * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)
-    # per-(query, kv) causal mask inside the chunk: a prefill row at
-    # position p sees exactly kv_pos <= p (its same-step chunk-mates
-    # beyond p were already written but stay masked); padded query rows
-    # (qpos < 0) mask everything and their garbage output is discarded
-    mask = (kv_pos <= qpos) & (kv_pos < len_ref[b]) & (qpos >= 0)
-    s = jnp.where(mask[None], s, _NEG_INF)
+        # scores for this block, batched over kv heads: (KVH, tile, rows)
+        sc = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                 preferred_element_type=jnp.float32)
+        kv_pos = i * rows + jax.lax.broadcasted_iota(
+            jnp.int32, (1, rows), 1)
+        # per-(query, kv) causal mask: a prefill row at position p sees
+        # exactly kv_pos <= p (its same-step chunk-mates beyond p were
+        # already written but stay masked); the tile's range end also
+        # masks a tail block's repeated pages; padded query rows (qpos
+        # < 0) mask everything and their garbage output is discarded
+        mask = (kv_pos <= qpos) & (kv_pos < end) & (qpos >= 0)
+        sc = jnp.where(mask[None], sc, _NEG_INF)
 
-    m_prev = m_ref[...]                           # (KVH, Q·g, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    m_ref[...] = m_new
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = (acc_ref[...] * corr
-                    + jax.lax.dot_general(p, v, (((2,), (1,)), ((0,), (0,))),
-                                          preferred_element_type=jnp.float32))
+        m_prev = m_ref[...]                       # (KVH, tile, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * corr
+                        + jax.lax.dot_general(
+                            p, v, (((2,), (1,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32))
 
-    @pl.when(i == pl.num_programs(1) - 1)
+    @pl.when(i == n_blocks - 1)
     def _finish():
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         o_ref[...] = out.astype(o_ref.dtype)[None]
 
 
-def _ragged_vmem_bytes(kvh: int, qg: int, hd: int, page_size: int,
-                       q_bytes: int, code_bytes: int, scale_bytes: int
-                       ) -> int:
-    """VMEM one ragged grid step takes, in the chip's padded tiles: two
-    buffers of the item's (KVH, Q·g, hd) query and output blocks, of its
-    positions and of one page's codes and scales; the (m, l, acc)
-    scratch; and the f32 values of a page (scores, weights, the product
-    into acc)."""
-    per_head = (4 * tile_bytes(qg, hd, q_bytes)      # q, out buffers
-                + 2 * tile_bytes(qg, 1, 4)            # m, l
-                + 3 * tile_bytes(qg, hd, 4)           # acc, acc update
-                + 2 * tile_bytes(qg, page_size, 4))   # scores, weights
-    pages = 2 * 2 * page_size * (tile_bytes(kvh, hd, code_bytes)
-                                 + tile_bytes(kvh, 1, scale_bytes))
-    return kvh * per_head + 2 * tile_bytes(qg, 1, 4) + pages
+def _ragged_step(s, pt, ends, work, blk, *, n_tiles: int):
+    """Page table, table row, KV range and block of ragged step ``s``."""
+    return pt, work[s] // n_tiles, ends[work[s]], blk[s]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -301,9 +418,8 @@ def paged_attention_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
                            interpret: bool = True) -> jnp.ndarray:
     """Mixed-q_len paged attention: the q_len=1 decode kernel generalized
     to a *block of queries per sequence*, so one launch serves a unified
-    token-budget step — each grid row is one work item (a prefill chunk
-    OR a decode token) and its pages stream from HBM exactly once for
-    all of its queries.
+    token-budget step — each work item is one prefill chunk OR one
+    decode token (or a speculative verify block).
 
     q           (B, Q, KVH, g, hd)  per-item query blocks (right-padded)
     k/v_pages   (n_pages, G, KVH, hd) int8 codes
@@ -315,8 +431,15 @@ def paged_attention_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
     -> (B, Q, KVH, g, hd) in q's dtype. ``q_len=1`` with
     ``q_pos = lengths - 1`` reproduces ``paged_attention_decode``.
 
-    Inside the kernel each kv head's queries form one (Q·g, hd) matrix,
-    so both contractions are matmuls batched over the kv head.
+    Each kv head's queries form one (Q·g, hd) matrix, query-major, cut
+    into tiles of ``tile`` rows (``_ragged_blocks``). The grid walks only
+    live (item, tile, KV block) triples, built from ``lengths`` and
+    ``q_pos`` (``_ragged_grid``): a tile without a live row takes no
+    step, and a tile's blocks stop at its causal horizon, ``min(max qpos
+    in the tile + 1, length)``. A block is ``ppb`` pages, each its own
+    operand, so one step's contractions are (tile, hd) x (hd, ppb·G)
+    matmuls batched over the kv head. Rows of tiles never visited are
+    garbage, like padded rows.
     """
     b, nq, kvh, g, hd = q.shape
     n_pages, page_size, kvh_p, _ = k_pages.shape
@@ -326,45 +449,54 @@ def paged_attention_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
     assert q_pos.shape == (b, nq), (q_pos.shape, q.shape)
 
     qg = nq * g
+    tile, ppb = _ragged_blocks(qg, g, kvh, hd, page_size, n_ptab,
+                               k_pages.dtype.itemsize,
+                               k_scale.dtype.itemsize)
+    n_tiles = pl.cdiv(qg, tile)
     qs = (q.astype(jnp.float32) * hd ** -0.5).astype(q.dtype)
     qs = qs.transpose(0, 2, 1, 3, 4).reshape(b, kvh, qg, hd)
-    # one position per (query, group) row, as a column
-    qpos = jnp.repeat(q_pos, g, axis=1)[:, :, None]
+    qs = jnp.pad(qs, ((0, 0), (0, 0), (0, n_tiles * tile - qg), (0, 0)))
+    rows = _row_positions(q_pos, g, tile, jnp)
+    ends, work, blk, steps = _ragged_grid(rows, lengths, tile, page_size,
+                                          n_ptab, ppb)
+
+    where = functools.partial(_ragged_step, n_tiles=n_tiles)
+    maps = [_page_index(j, ppb, page_size, n_ptab, where)
+            for j in range(ppb)]
+    codes = [pl.BlockSpec((1, page_size, kvh, hd), m) for m in maps]
+    scales = [pl.BlockSpec((1, page_size, kvh, 1), m) for m in maps]
+    per_tile = pl.BlockSpec(
+        (1, kvh, tile, hd),
+        lambda s, pt, e, w, bk: (w[s] // n_tiles, 0, w[s] % n_tiles, 0))
+    pos_tile = pl.BlockSpec(
+        (1, tile, 1),
+        lambda s, pt, e, w, bk: (w[s] // n_tiles, w[s] % n_tiles, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,      # lengths, page_table
-        grid=(b, n_ptab),
-        in_specs=[
-            pl.BlockSpec((1, kvh, qg, hd),
-                         lambda bb, i, ln, pt: (bb, 0, 0, 0)),
-            pl.BlockSpec((1, qg, 1), lambda bb, i, ln, pt: (bb, 0, 0)),
-            pl.BlockSpec((1, page_size, kvh, hd),
-                         lambda bb, i, ln, pt: (pt[bb, i], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, kvh, 1),
-                         lambda bb, i, ln, pt: (pt[bb, i], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, kvh, hd),
-                         lambda bb, i, ln, pt: (pt[bb, i], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, kvh, 1),
-                         lambda bb, i, ln, pt: (pt[bb, i], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, kvh, qg, hd),
-                               lambda bb, i, ln, pt: (bb, 0, 0, 0)),
+        num_scalar_prefetch=4,      # page_table, ends, work, blk
+        grid=(steps,),
+        in_specs=[per_tile, pos_tile] + codes + scales + codes + scales,
+        out_specs=per_tile,
         scratch_shapes=[
-            pltpu.VMEM((kvh, qg, 1), jnp.float32),       # running max
-            pltpu.VMEM((kvh, qg, 1), jnp.float32),       # running denom
-            pltpu.VMEM((kvh, qg, hd), jnp.float32),      # running numerator
+            pltpu.VMEM((kvh, tile, 1), jnp.float32),     # running max
+            pltpu.VMEM((kvh, tile, 1), jnp.float32),     # running denom
+            pltpu.VMEM((kvh, tile, hd), jnp.float32),    # running numerator
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_ragged_attn_kernel, page_size=page_size),
+        functools.partial(_ragged_attn_kernel, page_size=page_size,
+                          ppb=ppb),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, qg, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, n_tiles * tile, hd),
+                                       q.dtype),
         name="paged_attention_ragged",
         compiler_params=vmem_params(_ragged_vmem_bytes(
-            kvh, qg, hd, page_size, q.dtype.itemsize,
+            kvh, tile, hd, page_size, ppb, q.dtype.itemsize,
             k_pages.dtype.itemsize, k_scale.dtype.itemsize)),
         interpret=interpret,
-    )(lengths, page_table, qs, qpos, k_pages, k_scale, v_pages, v_scale)
-    return out.reshape(b, kvh, nq, g, hd).transpose(0, 2, 1, 3, 4)
+    )(page_table, ends, work, blk, qs, rows[:, :, None], *[k_pages] * ppb,
+      *[k_scale] * ppb, *[v_pages] * ppb, *[v_scale] * ppb)
+    out = out[:, :, :qg].reshape(b, kvh, nq, g, hd)
+    return out.transpose(0, 2, 1, 3, 4)
 
 
 def paged_attention_ragged_fallback(q: jnp.ndarray, k_pages, k_scale,

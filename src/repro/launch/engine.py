@@ -64,7 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.paged_attention import live_pages
+from repro.kernels.paged_attention import live_pages, ragged_work
 # Re-exported for backward compatibility: these historically lived here.
 from repro.launch.executor import (LegacyExecutor, RaggedExecutor,
                                    jitted_model_fns)  # noqa: F401
@@ -88,14 +88,16 @@ class _InFlight:
     """One dispatched-but-unobserved step: its plan, the device token
     array its observation will fetch, whether the token vector is
     slot-major (fused decode fast path) or consumer-row major (ragged
-    step), and the engine cycle that dispatched it. A fast-path step
-    also carries the page-table pages its decode attention fetches and
-    the pages its table holds (``pages``)."""
+    step), and the engine cycle that dispatched it. A step whose
+    attention runs a paged kernel also carries (work done, work of a
+    walk over whole tables) by that kernel's rule (``work``): pages
+    fetched for a fast-path step, query rows x pages computed for a
+    unified one."""
     plan: object
     toks: object
     slot_major: bool
     step: int
-    pages: Optional[tuple] = None
+    work: Optional[tuple] = None
 
     @property
     def kind(self) -> str:
@@ -708,7 +710,7 @@ class ServeEngine:
                 # content is duplicated before any divergent row lands
                 self.exec.copy_pages(plan.cow)
             inf = _InFlight(plan, self.exec.step(packed), False,
-                            self.step_count)
+                            self.step_count, self._ragged_work(packed))
         if plan.decode or plan.spec:
             self.metrics["decode_steps"] += 1
         return plan, inf
@@ -746,7 +748,10 @@ class ServeEngine:
         tokens it fetched (``fetched``, at host time ``fetched_at``).
         A dispatched fast-path step adds ``decode_pages_read`` (the pages
         its decode attention fetches, by the kernel's rule) and
-        ``decode_pages_table`` (rows x table width)."""
+        ``decode_pages_table`` (rows x table width); a dispatched unified
+        step that runs the ragged kernel adds ``ragged_work_read`` (query
+        rows x KV pages it computes) and ``ragged_work_table`` (items x
+        query rows x table width)."""
         rec = {"queue_depth": self.metrics["queue_depth"][-1],
                 "occupancy": self.metrics["occupancy"][-1],
                 "active": len(self._active),
@@ -757,9 +762,22 @@ class ServeEngine:
                 "fetched_prefill_tokens": (fetched.plan.prefill_tokens
                                            if fetched else 0),
                 "fetched_at": fetched_at}
-        if inf is not None and inf.pages is not None:
-            rec["decode_pages_read"], rec["decode_pages_table"] = inf.pages
+        if inf is not None and inf.work is not None:
+            name = "decode_pages" if inf.slot_major else "ragged_work"
+            rec[name + "_read"], rec[name + "_table"] = inf.work
         return rec
+
+    def _ragged_work(self, packed: dict) -> Optional[tuple]:
+        """``kernels.paged_attention.ragged_work`` of a packed unified
+        step whose attention runs the ragged kernel (its descriptor over
+        an int8 pool), else None."""
+        desc, cfg = packed.get("ragged_desc"), self.model.cfg
+        if desc is None or not cfg.kv_quant_bits:
+            return None
+        return ragged_work(desc["lengths"], desc["qpos"],
+                           cfg.n_heads // cfg.n_kv_heads, cfg.n_kv_heads,
+                           cfg.head_dim, self.pool.page_size,
+                           self.tables.n_ptab)
 
     def _step_sync(self) -> dict:
         """The synchronous unified cycle (REPRO_SYNC_STEP /

@@ -90,11 +90,21 @@ def test_ragged_attention_keeps_its_blocks(calls):
         _s((SLOTS, BUDGET), jnp.int32))
     (name, grid, blocks, params), = calls
     assert name == "paged_attention_ragged"
-    # one work item's whole query block against one page a grid step
-    assert grid == (SLOTS, N_PTAB)
-    assert blocks == [(1, KVH, 2 * BUDGET, HD), (1, 2 * BUDGET, 1),
-                      (1, PAGE, KVH, HD), (1, PAGE, KVH, 1),
-                      (1, PAGE, KVH, HD), (1, PAGE, KVH, 1)]
-    # its blocks and values need more than the default 16 MiB of scoped
-    # VMEM at this budget; the limit is set from the shapes
-    assert 16 << 20 < params.vmem_limit_bytes <= autotune.MAX_SCOPED_VMEM
+    # a 128-row query tile against a block of 8 pages a grid step; the
+    # grid is one dimension, its length the live steps
+    tile, ppb = 128, 8
+    assert pa._ragged_blocks(2 * BUDGET, 2, KVH, HD, PAGE, N_PTAB) == (
+        tile, ppb)
+    assert len(grid) == 1
+    assert blocks == ([(1, KVH, tile, HD), (1, tile, 1)]
+                      + ([(1, PAGE, KVH, HD)] * ppb
+                         + [(1, PAGE, KVH, 1)] * ppb) * 2)
+    # at most every slot's 8 tiles over 40 blocks of its table
+    _, work, blk, _ = pa._ragged_grid(
+        jnp.full((SLOTS, 2 * BUDGET), -1), jnp.zeros((SLOTS,), jnp.int32),
+        tile, PAGE, N_PTAB, ppb)
+    assert work.shape == blk.shape == (SLOTS * 8 * 40,)
+    # its blocks and values fit the default 16 MiB of scoped VMEM
+    assert pa._ragged_vmem_bytes(KVH, tile, HD, PAGE, ppb, 2, 1, 4) \
+        <= autotune.DEFAULT_SCOPED_VMEM
+    assert params is None

@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels import paged_attention as pa
 from repro.kernels.paged_attention import (paged_attention_decode,
                                            paged_attention_ragged,
                                            paged_attention_ragged_fallback)
@@ -63,15 +64,37 @@ def _case(seed, b, nq, kvh, g, hd, page_size, n_ptab, q_lens, lengths):
             jnp.asarray(qpos))
 
 
-@pytest.mark.parametrize("seed", [0, 1])
-@pytest.mark.parametrize("q_lens,lengths,page_size,n_ptab", [
+# (q_lens, lengths, page_size, n_ptab) of a ragged batch (``_case``)
+CASES = [
     # mixed: a decode singleton, a mid-size chunk, a full-block chunk
     ((1, 3, 6), (9, 7, 6), 4, 3),
     # ragged last pages: lengths far from page multiples
     ((2, 5, 1), (11, 5, 13), 4, 4),
     # single page, GQA-free edge
     ((1, 2, 2), (1, 2, 8), 8, 1),
-], ids=["mixed", "ragged-pages", "one-page"])
+    # an empty item (length 0, every qpos -1) between live ones
+    ((3, 0, 1), (9, 0, 5), 4, 3),
+    # decode items only: one live query tile each
+    ((1, 1, 1, 1), (5, 16, 1, 33), 8, 5),
+    # a 200-query chunk from position 0 is four 128-row tiles; the first
+    # two stop after one 128-row KV block of the three a decode item
+    # beside it takes
+    ((200, 1), (200, 300), 16, 20),
+    # lengths exactly at page and block ends (8 pages of 16 a block)
+    ((3, 1, 2), (128, 16, 256), 16, 16),
+    # 21 table entries in blocks of 16 pages: a 5-page tail block
+    ((2, 1, 4), (168, 100, 9), 8, 21),
+    # 140 live rows: a full tile and one of 12 live rows
+    ((70, 5), (150, 90), 16, 10),
+]
+CASE_IDS = ["mixed", "ragged-pages", "one-page", "empty-item",
+            "decode-only", "causal-tiles", "boundaries", "tail-block",
+            "partial-tile"]
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("q_lens,lengths,page_size,n_ptab", CASES,
+                         ids=CASE_IDS)
 def test_ragged_kernel_matches_oracle(seed, q_lens, lengths, page_size,
                                       n_ptab):
     args = _case(seed, len(q_lens), max(q_lens), kvh=2, g=2, hd=8,
@@ -84,6 +107,75 @@ def test_ragged_kernel_matches_oracle(seed, q_lens, lengths, page_size,
     np.testing.assert_allclose(np.asarray(got)[valid],
                                np.asarray(want)[valid],
                                rtol=RTOL, atol=1e-5)
+
+
+def _live_steps(q_pos, lengths, g, tile, ppb, page_size, n_ptab):
+    """Brute force: every (item, query tile, KV block) with a live query
+    row in the tile whose block starts before the tile's causal horizon
+    (its last live position + 1) and the item's length; a live tile
+    whose horizon is 0 still takes its first block."""
+    out = []
+    for j, (row, length) in enumerate(zip(q_pos, lengths)):
+        rows = [int(p) for p in row for _ in range(g)]
+        for t in range(-(-len(rows) // tile)):
+            live = [p for p in rows[t * tile:(t + 1) * tile] if p >= 0]
+            if not live:
+                continue
+            horizon = min(max(live) + 1, int(length))
+            for i in range(-(-n_ptab // ppb)):
+                if i == 0 or i * ppb * page_size < horizon:
+                    out.append((j, t, i))
+    return out
+
+
+@pytest.mark.parametrize("q_lens,lengths,page_size,n_ptab", CASES,
+                         ids=CASE_IDS)
+def test_ragged_grid_visits_only_live_work(q_lens, lengths, page_size,
+                                           n_ptab):
+    """The wrapper's dynamic grid is exactly the live (item, tile, block)
+    triples, in order, and ``ragged_work`` counts its steps' rows x
+    pages against the whole walk."""
+    g, kvh, hd = 2, 2, 8
+    *_, ln, qpos = _case(0, len(q_lens), max(q_lens), kvh, g, hd,
+                         page_size, n_ptab, q_lens, lengths)
+    nq = qpos.shape[1]
+    tile, ppb = pa._ragged_blocks(nq * g, g, kvh, hd, page_size, n_ptab)
+    n_tiles = -(-nq * g // tile)
+    _, work, blk, steps = pa._ragged_grid(
+        pa._row_positions(qpos, g, tile, jnp), ln, tile, page_size, n_ptab,
+        ppb)
+    steps = int(steps)
+    got = [(int(w) // n_tiles, int(w) % n_tiles, int(i))
+           for w, i in zip(work[:steps], blk[:steps])]
+    want = _live_steps(np.asarray(qpos), lengths, g, tile, ppb, page_size,
+                       n_ptab)
+    assert got == want
+    assert work.shape == (len(q_lens) * n_tiles * -(-n_ptab // ppb),)
+    read, table = pa.ragged_work(lengths, qpos, g, kvh, hd, page_size,
+                                 n_ptab)
+    assert read == len(want) * tile * ppb
+    assert table == len(q_lens) * nq * g * n_ptab
+
+
+def test_ragged_work_of_a_cell_chunk_step():
+    """At ``nemo8.azconv``'s widths (8 KV heads of 128, groups of 4,
+    256-query items, 308-page tables of 16) a step of one 256-token chunk
+    ending at 1,206 and seven decode items at 1,200 computes 5.8% of the
+    whole walk: the chunk's eight 32-query tiles end at 982, 1,014, ...,
+    1,206 and take 8, 8, 9, 9, 9, 9, 10, 10 blocks of 8 pages, each
+    decode item 10; every step computes 128 rows."""
+    q_pos = np.full((8, 256), -1)
+    q_pos[0] = 950 + np.arange(256)
+    q_pos[1:, 0] = 1199
+    lengths = np.r_[1206, np.full(7, 1200)]
+    read, table = pa.ragged_work(lengths, q_pos, 4, 8, 128, 16, 308)
+    assert (read, table) == ((72 + 7 * 10) * 128 * 8, 8 * 1024 * 308)
+    assert read / table < 0.1
+
+
+def test_ragged_work_with_no_live_row_is_one_step():
+    read, table = pa.ragged_work([0, 0], np.full((2, 4), -1), 2, 2, 8, 4, 3)
+    assert (read, table) == (8 * 3, 2 * 8 * 3)
 
 
 def test_padded_query_rows_do_not_contaminate():

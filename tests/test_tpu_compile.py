@@ -242,9 +242,9 @@ def test_decode_qkv_prologue_compiles_nemo_widths(one_chip, no_cache):
     ids=["nemo", "catlm"])
 def test_paged_attention_ragged_compiles_cell_budget(one_chip, no_cache, kvh,
                                                      g, hd, pool):
-    """Each of 8 work items a 512-row query block over 320-page tables,
-    as the cells' unified steps run it: more than the default scoped VMEM
-    at both models' widths, so the kernel sets its limit."""
+    """Each of 8 work items a 512-query block over 320-page tables, as
+    the cells' unified steps run it: 128-row query tiles against blocks
+    of 8 pages fit the default scoped VMEM at both models' widths."""
     from repro.kernels.paged_attention import paged_attention_ragged
     nq, n_ptab = 512, 320
     pools = _nemo_pool(n_ptab) if pool == "nemo" else [

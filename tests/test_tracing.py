@@ -17,6 +17,7 @@ import pytest
 from golden import regenerate
 
 from repro.data import request_workload
+from repro.kernels.paged_attention import ragged_work
 from repro.launch import tracing
 from repro.launch.engine import ServeEngine
 
@@ -144,3 +145,33 @@ def test_decode_records_count_the_pages_the_kernel_reads():
         assert 0 < r["decode_pages_read"] <= r["decode_pages_table"]
     assert not any("decode_pages_read" in r for r in recs
                    if r["kind"] != "decode")
+
+
+def test_unified_records_count_the_ragged_work():
+    """A dispatched unified step's record counts the query rows x KV pages
+    its ragged attention computes, by the kernel's rule, for the
+    descriptor it shipped (``ragged_work``), never more than the walk of
+    every item's query block over its whole table; a fast-path record
+    carries neither count."""
+    eng, _ = _engine(pipeline=True)
+    cfg = eng.model.cfg
+    shipped = []
+    step = eng.exec.step
+
+    def spy(packed):
+        desc = packed["ragged_desc"]
+        shipped.append((np.array(desc["lengths"]), np.array(desc["qpos"])))
+        return step(packed)
+
+    eng.exec.step = spy
+    recs = _drain(eng)
+    unified = [r for r in recs if r["kind"] == "unified"]
+    assert len(unified) == len(shipped) > 0
+    for r, (lengths, qpos) in zip(unified, shipped):
+        want = ragged_work(lengths, qpos, cfg.n_heads // cfg.n_kv_heads,
+                           cfg.n_kv_heads, cfg.head_dim,
+                           eng.pool.page_size, eng.tables.n_ptab)
+        assert (r["ragged_work_read"], r["ragged_work_table"]) == want
+        assert 0 < r["ragged_work_read"] <= r["ragged_work_table"]
+    assert not any("ragged_work_read" in r for r in recs
+                   if r["kind"] != "unified")
