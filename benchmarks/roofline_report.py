@@ -132,8 +132,7 @@ def decode_launch_table() -> str:
 
 def serve_bytes_table(arch: str = "catlm_60m", smoke: bool = True) -> str:
     """Per-token HBM traffic of the serving hot path, fused vs unfused,
-    at the bench's weight widths — the roofline context for the
-    serve_bench tok/s rows (``python -m benchmarks.roofline_report
+    at the bench's weight widths (``python -m benchmarks.roofline_report
     --serve-bytes``)."""
     from repro.configs import get_config
     cfg = get_config(arch)

@@ -11,7 +11,7 @@ import traceback
 
 SUITES = ["fig2_sqnr_approx", "fig3_bitwidth", "fig4_concentration",
           "fig5_alignment", "fig6_sqnr_layers", "table1_e2e",
-          "kernels_bench", "serve_bench", "dryrun_readout"]
+          "kernels_bench", "dryrun_readout"]
 
 
 def dryrun_readout() -> None:

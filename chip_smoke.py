@@ -25,7 +25,8 @@ LOGIT_RTOL.
 Four chips: serves the same model and workload on a (1, 4)
 ("data", "model") mesh, checks the outputs as above, checks that each
 chip holds a quarter of every sharded weight and KV leaf, and compares
-first-prefill logits of the sharded forward with the same params on one
+the logits of the first prompt, packed as one prefill chunk of a unified
+ragged step, between the tensor-parallel step and the same step on one
 device, within TP_RTOL. Nothing else runs on that path.
 
 Every reading goes to stdout on its own line; the last line is
@@ -184,8 +185,8 @@ def kernel_calls(text: str) -> int:
 
 
 def prefill_logits(model, params, prompt):
-    """(P, V) f32 logits at every prompt position from the slot-cache
-    prefill."""
+    """(P, V) f32 logits at every prompt position from the model's own
+    prefill over a one-sequence cache."""
     import jax
     import jax.numpy as jnp
 
@@ -194,6 +195,42 @@ def prefill_logits(model, params, prompt):
     logits, _ = jax.jit(model.prefill)(params, jnp.asarray(prompt)[None],
                                        cache, logits_at=jnp.arange(p))
     return jnp.asarray(logits[0], jnp.float32)
+
+
+def ragged_prefill_logits(model, params, prompt, mesh=None):
+    """(P, V) f32 logits at every prompt position from one unified ragged
+    step (``model.ragged_step``) that packs the whole prompt as one
+    prefill chunk over a paged pool; under ``mesh`` the step runs
+    tensor-parallel in shard_map with the specs ``RaggedExecutor`` uses
+    (weights and pools sharded on "model", descriptors replicated)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distributed import sharding as shlib
+    from repro.distributed.compat import shard_map
+
+    page = 16
+    p = len(prompt)
+    n_ptab = -(-p // page)
+    rows = jnp.arange(p, dtype=jnp.int32)
+    cache = dict(model.init_paged_cache(1 + n_ptab, page), pos=rows,
+                 page_table=jnp.tile(jnp.arange(1, 1 + n_ptab,
+                                                dtype=jnp.int32), (p, 1)))
+    step = model.ragged_step
+    if mesh is not None:
+        tp_kw = dict(tp_axis="model", tp_mode="gather", tp_kernels=False)
+        cspecs = shlib.tp_cache_specs(cache, mesh, axis="model")
+        step = shard_map(
+            lambda prm, t, c, r: model.ragged_step(prm, t, c, r, **tp_kw),
+            mesh=mesh,
+            in_specs=(shlib.tp_param_specs(params, mesh, axis="model",
+                                           cfg=model.cfg),
+                      P(None, None), cspecs, P(None)),
+            out_specs=(P(None, None, None), cspecs), check_vma=False)
+    tokens = jnp.asarray(prompt, jnp.int32)[:, None]
+    logits, _ = jax.jit(step)(params, tokens, cache, rows)
+    return jnp.asarray(logits[:, 0], jnp.float32)
 
 
 def rel_err(got, want) -> float:
@@ -214,7 +251,7 @@ def serve_phase(name: str, model, params, requests, cfg, **engine_kw):
 
     max_len = max(len(r["tokens"]) for r in requests) + GEN + 8
     eng = ServeEngine(model, params, n_slots=N_SLOTS, max_len=max_len,
-                      schedule="unified", max_batch_tokens=MAX_BATCH_TOKENS,
+                      max_batch_tokens=MAX_BATCH_TOKENS,
                       **engine_kw)
     check(eng.pipeline, f"{name}: the pipelined loop is not on")
     steps = record_calls(eng.exec, "step")
@@ -303,9 +340,6 @@ def shard_fractions(tree) -> list:
 
 
 def four_chips(seed: int, devs) -> None:
-    import jax.numpy as jnp
-
-    from repro.launch.executor import LegacyExecutor
     from repro.launch.serve import build_served_model, parse_mesh
 
     cfg, model, params, mem = build_served_model(
@@ -333,18 +367,12 @@ def four_chips(seed: int, devs) -> None:
             sharded_bytes=total, bytes_per_chip=total // 4)
 
     prompt = requests[0]["tokens"]
-    p = len(prompt)
-    logits = {}
-    for name, m in (("tp4", mesh), ("one", None)):
-        cache = {k: v for k, v in model.init_cache(1, p).items()
-                 if k != "pos"}
-        ex = LegacyExecutor(model, params, cache, n_slots=1, mesh=m)
-        logits[name] = ex.prefill_slot(prompt, 0, p - 1)
-    got = jnp.asarray(logits["tp4"], jnp.float32)
-    want = jnp.asarray(logits["one"], jnp.float32)
+    got = ragged_prefill_logits(model, params, prompt, mesh)
+    want = ragged_prefill_logits(model, params, prompt)
     err = rel_err(got, want)
-    say(phase="TP4_logits", prompt_len=p, rel_err=err, rtol=TP_RTOL,
-        argmax_equal=bool(got.argmax() == want.argmax()))
+    say(phase="TP4_logits", prompt_len=len(prompt), rel_err=err,
+        rtol=TP_RTOL,
+        argmax_agreement=float((got.argmax(-1) == want.argmax(-1)).mean()))
     check(err <= TP_RTOL, f"tp=4 vs one-device logits rel err {err:.3g} > "
                           f"{TP_RTOL}")
     say(peak_bytes_in_use_per_chip=[peak_bytes(d) for d in devs[:4]])

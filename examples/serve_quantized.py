@@ -58,7 +58,7 @@ def main():
     print("  q4 :", gen_q[0][:24].tolist())
 
     # --- the same quantized model behind the continuous-batching engine:
-    # mixed-prompt FIFO queue, int8 slot KV cache, prefill-on-admit
+    # mixed-prompt FIFO queue, int8 paged KV pool, token-budget steps
     import dataclasses
     from repro.data import request_workload
     from repro.launch.engine import ServeEngine

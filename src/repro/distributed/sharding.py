@@ -335,23 +335,16 @@ def tp_param_specs(params, mesh, axis: str = "model", cfg=None,
         or (hasattr(x, "shape") and not isinstance(x, dict)))
 
 
-def tp_cache_specs(cache, mesh, axis: str = "model",
-                   dp_axis: Optional[str] = None):
+def tp_cache_specs(cache, mesh, axis: str = "model"):
     """PartitionSpec tree for a decode cache under shard_map: KV codes
     AND their per-token scales shard the head axis congruently when the
     head count divides; ``pos`` and anything non-divisible replicate.
-    ``dp_axis`` additionally shards the slot/batch axis when it divides
-    (the engine's batched decode step; prefill is batch-1, replicated).
 
-    Paged pools ride the same rule: axis 3 is the head axis for both the
-    slot layout (L, B, T, KV, hd) and the page layout (L, n_pages,
-    page_size, KV, hd), so codes/scales shard congruently either way —
-    but pass ``dp_axis=None`` for paged caches (the page axis must stay
-    whole; the engine enforces tp-only meshes for paged serving) and the
-    ``page_table`` replicates alongside ``pos``."""
+    Axis 3 is the head axis for both the contiguous layout (L, B, T, KV,
+    hd) and the paged pool (L, n_pages, page_size, KV, hd), so
+    codes/scales shard congruently either way; the page axis stays whole
+    and the ``page_table`` replicates alongside ``pos``."""
     tp = mesh.shape[axis]
-    dp = mesh.shape[dp_axis] if dp_axis else 1
-    paged = isinstance(cache, dict) and "page_table" in cache
 
     def walk(path, leaf):
         nd = len(leaf.shape)
@@ -360,9 +353,7 @@ def tp_cache_specs(cache, mesh, axis: str = "model",
             return P(*([None] * nd))
         heads = leaf.shape[3]
         hspec = axis if heads % tp == 0 else None
-        bspec = dp_axis if (dp_axis and not paged
-                            and leaf.shape[1] % dp == 0) else None
-        return P(None, bspec, None, hspec, None)
+        return P(None, None, None, hspec, None)
 
     return jax.tree_util.tree_map_with_path(
         walk, cache,

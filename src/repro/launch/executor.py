@@ -1,94 +1,30 @@
-"""Device side of the serve stack: jitted/shard_mapped prefill, decode,
-and the unified ragged step, with donated caches.
+"""Device side of the serve stack: the unified ragged step, its
+pure-decode fast path, the speculative draft pass and the COW page copy,
+each one jitted (or shard_mapped) program over the donated paged pool.
 
 The engine façade (``repro.launch.engine``) keeps all host-side policy
 (queues, slots, budgets — see ``repro.launch.scheduler``); everything
-that touches a jax array lives here:
+that touches a jax array lives here, in ``RaggedExecutor``: ONE
+invocation per engine step runs the flat packed (T, 1) token batch —
+decode rows and prefill-chunk rows together — against the paged KV pool
+with per-token positions and page-table rows, returning the greedy token
+only at the packed rows the scheduler marked
+(``models.dense.ragged_step``). The cache is donated, so pools update in
+place on donation-capable backends.
 
-- ``LegacyExecutor`` — the prefill-on-admit + batched-decode pair the
-  engine has always dispatched: fused single-dispatch slot prefill,
-  paged prefill spans with donated pools, one batched decode step, and
-  the tensor-parallel shard_map variants of each.
-- ``RaggedExecutor`` — the unified token-budget step: ONE jitted (or
-  shard_mapped) invocation per engine step that runs the flat packed
-  (T, 1) token batch — decode rows and prefill-chunk rows together —
-  against the paged KV pool with per-token positions and page-table
-  rows, returning logits only at the packed rows the scheduler marked
-  (``models.dense.ragged_step``). The cache is donated, so pools update
-  in place on donation-capable backends.
-
-Both executors own ``params`` and ``cache`` (device_put with the
+The executor owns ``params`` and ``cache`` (device_put with the
 quantization-aware shardings from ``distributed.sharding`` in mesh mode)
-and expose small host-facing methods taking/returning numpy.
+and exposes small host-facing methods taking numpy.
 """
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.launch.tracing import span
-
-
-# ------------------------------------------------------------- jit helpers
-
-@functools.lru_cache(maxsize=8)
-def jitted_model_fns(model):
-    """(jit prefill, jit decode) cached per model so repeated engine /
-    oracle runs over the same model share compilations."""
-    return jax.jit(model.prefill), jax.jit(model.decode)
-
-
-@functools.lru_cache(maxsize=8)
-def jitted_paged_fns(model, paged_kernel: bool):
-    """Paged-serving (jit prefill, jit decode) — cached per (model,
-    kernel flag) like ``jitted_model_fns``, so rebuilding an engine over
-    the same model (benchmark variants, warmup/steady re-runs) reuses
-    compilations instead of re-tracing per engine instance. The global
-    pool round-trips through every call, so the cache arg is donated."""
-    prefill = jax.jit(model.prefill, donate_argnums=(2,))
-    dec = (lambda p, t, c: model.decode(p, t, c, paged_kernel=True)
-           ) if paged_kernel else model.decode
-    return prefill, jax.jit(dec, donate_argnums=(2,))
-
-
-@jax.jit
-def _take_slot(cache, slot):
-    """Slice one slot's batch-1 cache out of the shared (L, n_slots, ...)
-    arrays (leaf layout: layer axis 0, slot axis 1)."""
-    return jax.tree.map(
-        lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=1), cache)
-
-
-# Donating the shared cache lets XLA write the slot rows in place on
-# backends with buffer donation (TPU); CPU falls back to a copy.
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _put_slot(cache, part, slot):
-    return jax.tree.map(
-        lambda a, p: jax.lax.dynamic_update_slice_in_dim(a, p, slot, axis=1),
-        cache, part)
-
-
-# Single-device admissions run take -> prefill -> put as ONE jitted
-# program: the slot's rows are sliced, prefilled, and written back without
-# the per-slot part ever surfacing as separate host-boundary buffers
-# between three dispatches (the old take/prefill/put ping-pong). The
-# shared cache is donated so XLA can update the slot rows in place.
-# ``prefill_fn`` is static (one compile per model × token shape).
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
-def _prefill_slot_fused(prefill_fn, params, cache, tokens, slot, logits_at):
-    part = jax.tree.map(
-        lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=1), cache)
-    logits, part = prefill_fn(params, tokens, dict(part, pos=jnp.int32(0)),
-                              logits_at=logits_at)
-    part.pop("pos")
-    cache = jax.tree.map(
-        lambda a, p: jax.lax.dynamic_update_slice_in_dim(a, p, slot, axis=1),
-        cache, part)
-    return logits, cache
 
 
 # Device-side token injection (pipelined serving): a packed row whose fed
@@ -145,7 +81,7 @@ _unified_step_async = functools.partial(
 # two-launch fused path (``models.dense._fused_decode_attn``: QKV-prologue
 # kernel + paged attention, no XLA glue between them) on TPU, and is
 # bitwise identical to the ragged step's decode rows everywhere (same
-# per-row numerics — the property the unified/legacy golden fixtures pin).
+# per-row numerics — the property the golden fixtures pin).
 # ``decode_fn`` is static; the cache (the global paged pools) is donated.
 # Returns the (n_slots,) greedy token ids (device-resident sampling —
 # argmax in the same program, only int32 tokens cross D2H).
@@ -201,15 +137,6 @@ _draft_scan_async = functools.partial(
     jax.jit, static_argnums=(0, 1))(_draft_scan_impl)
 
 
-# Greedy sampling for the legacy batched decode: argmax on device so
-# only (n_slots,) int32 tokens cross D2H instead of the full (n_slots,
-# 1, V) logits tensor (which used to be copied inside the timed device
-# span and charged to compute).
-@jax.jit
-def _greedy_rows(logits):
-    return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-
-
 # COW page copy (prefix caching): duplicate src pages' rows into dst
 # pages across every pool leaf before the step that writes the divergent
 # rows. ``copy_fn`` (model.copy_paged_pages) is static; the cache is
@@ -230,39 +157,11 @@ _copy_pages_async = functools.partial(
     jax.jit, static_argnums=(0,))(_copy_pages_impl)
 
 
-class _CopyPagesMixin:
-    """Host-facing COW dispatch shared by both executors."""
-
-    def copy_pages(self, pairs) -> None:
-        """Device-copy each (src, dst) page pair in ONE dispatch (issued
-        strictly before the step/prefill that writes past the shared
-        boundary — dispatch order is device order on a stream, so even a
-        src freed and reallocated within the same plan is read before
-        its new owner writes it)."""
-        if not pairs:
-            return
-        copy_fn = self.model.copy_paged_pages
-        if copy_fn is None:
-            raise NotImplementedError(
-                f"family {getattr(self.model.cfg, 'family', '?')!r} has "
-                f"no paged-pool page copy (copy_paged_pages)")
-        self.n_dispatch += 1
-        width = 1 << (max(len(pairs), self.n_slots) - 1).bit_length()
-        src = np.zeros((width,), np.int32)
-        dst = np.zeros((width,), np.int32)
-        for i, (s, d) in enumerate(pairs):
-            src[i], dst[i] = s, d
-        fn = (_copy_pages if getattr(self, "_donate", True)
-              else _copy_pages_async)
-        self.cache = fn(copy_fn, self.cache, jnp.asarray(src),
-                        jnp.asarray(dst))
-
-
-# ------------------------------------------------- shared mesh validation
+# ---------------------------------------------------- mesh validation
 
 def _validate_tp(cfg, mesh, tp_axis: str, tp_mode: str, params) -> int:
-    """Shared tensor-parallel admissibility checks (whole heads per
-    shard; int4-packed row shards hold whole bytes). Returns tp size."""
+    """Tensor-parallel admissibility checks (whole heads per shard;
+    int4-packed row shards hold whole bytes). Returns tp size."""
     from repro.core.qlinear import iter_qlinear
 
     if cfg.n_experts:
@@ -287,180 +186,9 @@ def _validate_tp(cfg, mesh, tp_axis: str, tp_mode: str, params) -> int:
     return tp
 
 
-# --------------------------------------------------------- legacy executor
-
-class LegacyExecutor(_CopyPagesMixin):
-    """Prefill-on-admit + batched-decode dispatch (the engine's original
-    device path, unchanged numerics — it stays the oracle the unified
-    step is golden-tested against)."""
-
-    def __init__(self, model, params, cache, *, n_slots: int,
-                 paged: bool = False, paged_kernel: bool = False,
-                 mesh=None, tp_axis: str = "model",
-                 tp_mode: str = "gather", tp_kernels: bool = False):
-        self.model, self.params, self.cache = model, params, cache
-        self.paged, self.mesh = paged, mesh
-        self.n_slots = n_slots
-        self.n_dispatch = 0     # device calls issued (hot-loop accounting)
-        self.d2h_s = 0.0        # token D2H seconds (attributed separately)
-        if mesh is None:
-            if paged:
-                # paged prefill/decode round-trip the ENTIRE global pool
-                # (not a batch-1 slot part), so the cache arg is donated —
-                # in-place pool updates on donation-capable backends,
-                # mirroring what _prefill_slot_fused does for slots
-                self._prefill, self._decode = jitted_paged_fns(model,
-                                                               paged_kernel)
-            else:
-                self._prefill, self._decode = jitted_model_fns(model)
-        else:
-            self._init_mesh_fns(mesh, tp_axis, tp_mode, tp_kernels,
-                                paged_kernel)
-
-    def _init_mesh_fns(self, mesh, tp_axis: str, tp_mode: str,
-                       tp_kernels: bool, paged_kernel: bool) -> None:
-        """Tensor-parallel serving: params and the shared slot KV cache
-        are device_put with quantization-aware shardings
-        (``distributed.sharding.tp_param_specs`` / ``tp_cache_specs``) and
-        prefill/decode run the TP forward inside shard_map. Slot
-        bookkeeping (queue, free list, positions) stays host-side in the
-        engine and is identical to the single-device path; in
-        ``tp_mode="gather"`` (default) the decoded tokens are
-        bit-identical to it too."""
-        from jax.sharding import PartitionSpec as P
-
-        from repro.distributed.compat import shard_map
-        from repro.distributed import sharding as shlib
-
-        cfg = self.model.cfg
-        _validate_tp(cfg, mesh, tp_axis, tp_mode, self.params)
-        dp_axis = next((a for a in ("data", "pod")
-                        if a in mesh.axis_names
-                        and self.n_slots % mesh.shape[a] == 0
-                        and mesh.shape[a] > 1), None)
-        if self.paged and dp_axis is not None:
-            raise NotImplementedError(
-                "paged mesh serving is tensor-parallel only: the page pool "
-                "is a global (not per-slot) allocation, so its writes "
-                "cannot shard over a data axis — use a (1, tp) mesh")
-
-        pspecs = shlib.tp_param_specs(self.params, mesh, axis=tp_axis,
-                                      cfg=cfg, row_mode=tp_mode)
-        dec_cspecs = shlib.tp_cache_specs(self.cache, mesh, axis=tp_axis,
-                                          dp_axis=dp_axis)
-        if self.paged:
-            # prefill sees the same global pool as decode (only the page
-            # table narrows to the admitted slot's row)
-            pre_cspecs = dec_cspecs
-        else:
-            part_shapes = jax.eval_shape(
-                lambda c: jax.tree.map(lambda a: a[:, :1], c), self.cache)
-            pre_cspecs = shlib.tp_cache_specs(part_shapes, mesh,
-                                              axis=tp_axis)
-        self.params = jax.device_put(self.params, shlib.named(pspecs, mesh))
-        self.cache = jax.device_put(self.cache,
-                                    shlib.named(dec_cspecs, mesh))
-        tok_spec = P(dp_axis, None)
-        # the (B,) per-slot position vector shards with the slot axis
-        pos_spec = P(dp_axis) if dp_axis else P()
-        tp_kw = dict(tp_axis=tp_axis, tp_mode=tp_mode, tp_kernels=tp_kernels)
-        if self.paged:
-            # page tables replicate (every shard gathers/scatters its own
-            # head slice of the same physical pages)
-            pt_spec = {"page_table": P(None, None)}
-            pre_extra = dict(pt_spec, pos=P())
-            dec_extra = dict(pt_spec, pos=pos_spec)
-        else:
-            pre_extra, dec_extra = {"pos": P()}, {"pos": pos_spec}
-        model = self.model
-        pk = paged_kernel
-
-        def pre(p, t, c, la):
-            return model.prefill(p, t, c, logits_at=la, **tp_kw)
-
-        def dec(p, t, c):
-            if pk:
-                return model.decode(p, t, c, paged_kernel=True, **tp_kw)
-            return model.decode(p, t, c, **tp_kw)
-
-        self._prefill = jax.jit(shard_map(
-            pre, mesh=mesh,
-            in_specs=(pspecs, P(None, None), dict(pre_cspecs, **pre_extra),
-                      P()),
-            out_specs=(P(None, None, None), dict(pre_cspecs, **pre_extra)),
-            check_vma=False))
-        self._decode = jax.jit(shard_map(
-            dec, mesh=mesh,
-            in_specs=(pspecs, tok_spec, dict(dec_cspecs, **dec_extra)),
-            out_specs=(P(dp_axis, None, None),
-                       dict(dec_cspecs, **dec_extra)),
-            check_vma=False))
-
-    # ----------------------------------------------------------- dispatch
-
-    def prefill_slot(self, toks: np.ndarray, slot: int, last: int):
-        """Slot-cache prefill: fused take->prefill->put in one dispatch
-        (single device) or explicit take/put around the shard_map'd
-        forward (mesh). Returns the prefill logits."""
-        self.n_dispatch += 1
-        if self.mesh is None:
-            logits, self.cache = _prefill_slot_fused(
-                self.model.prefill, self.params, self.cache, toks[None],
-                np.int32(slot), jnp.int32(last))
-            return logits
-        part = dict(_take_slot(self.cache, np.int32(slot)),
-                    pos=jnp.int32(0))
-        logits, part = self._prefill(self.params, toks[None], part,
-                                     jnp.int32(last))
-        part.pop("pos")
-        self.cache = _put_slot(self.cache, part, np.int32(slot))
-        return logits
-
-    def prefill_paged_span(self, toks: np.ndarray, row, off: int,
-                           last: int):
-        """One paged prefill span at cache offset ``off`` against page
-        table ``row`` (1, n_ptab). Returns (logits, rebound row) — the
-        input row buffer was donated with the cache."""
-        self.n_dispatch += 1
-        cache = dict(self.cache, page_table=row, pos=jnp.int32(off))
-        if self.mesh is None:
-            logits, cache = self._prefill(self.params, toks[None], cache,
-                                          logits_at=jnp.int32(last))
-        else:
-            logits, cache = self._prefill(self.params, toks[None], cache,
-                                          jnp.int32(last))
-        cache.pop("pos")
-        row = cache.pop("page_table")
-        self.cache = cache
-        return logits, row
-
-    def decode(self, toks: np.ndarray, pos: np.ndarray,
-               table=None) -> np.ndarray:
-        """One batched decode step over all slots; returns the greedy
-        next token per slot as (n_slots,) int32 numpy. The argmax runs
-        on device (``_greedy_rows``) so the D2H copy is n_slots ints,
-        not the logits tensor; the copy itself is timed into ``d2h_s``
-        (not the engine's device span — it is transfer, not compute).
-        Blocks on the tokens so the engine's timed device span measures
-        execution, not enqueue."""
-        self.n_dispatch += 1
-        cache = dict(self.cache, pos=jnp.asarray(pos))
-        if table is not None:
-            cache["page_table"] = jnp.asarray(table)
-        logits, cache = self._decode(self.params, jnp.asarray(toks), cache)
-        cache.pop("pos")
-        cache.pop("page_table", None)
-        self.cache = cache
-        tokens = jax.block_until_ready(_greedy_rows(logits))
-        td = time.perf_counter()
-        out = np.asarray(tokens)
-        self.d2h_s += time.perf_counter() - td
-        return out
-
-
 # --------------------------------------------------------- ragged executor
 
-class RaggedExecutor(_CopyPagesMixin):
+class RaggedExecutor:
     """The unified token-budget step: one ragged model invocation per
     engine step over the flat packed token batch (see module docstring
     and ``scheduler.TokenBudgetScheduler.pack``)."""
@@ -485,7 +213,6 @@ class RaggedExecutor(_CopyPagesMixin):
         self.paged_kernel = paged_kernel
         self.mesh = mesh
         self.n_dispatch = 0     # device calls issued (hot-loop accounting)
-        self.d2h_s = 0.0        # token D2H seconds (engine resets it)
         # the engine cycle of the next step()/decode_step(): the engine
         # sets it; the exec.* spans carry it as step=
         self.step_idx = 0
@@ -519,7 +246,7 @@ class RaggedExecutor(_CopyPagesMixin):
     def _init_mesh(self, mesh, tp_axis: str, tp_mode: str,
                    tp_kernels: bool) -> None:
         """Unified step under shard_map: pools shard the head axis on
-        ``model`` exactly as in legacy paged serving; the host-built
+        ``model`` (the page axis stays whole); the host-built
         descriptors (packed tokens, positions, page-table rows, logit
         rows, kernel query blocks) all replicate
         (``distributed.sharding.ragged_desc_specs``)."""
@@ -656,6 +383,29 @@ class RaggedExecutor(_CopyPagesMixin):
                 prev, jnp.asarray(tok_src))
             self._prev = toks
             return toks
+
+    def copy_pages(self, pairs) -> None:
+        """Device-copy each (src, dst) page pair in ONE dispatch (issued
+        strictly before the step that writes past the shared boundary —
+        dispatch order is device order on a stream, so even a src freed
+        and reallocated within the same plan is read before its new
+        owner writes it)."""
+        if not pairs:
+            return
+        copy_fn = self.model.copy_paged_pages
+        if copy_fn is None:
+            raise NotImplementedError(
+                f"family {getattr(self.model.cfg, 'family', '?')!r} has "
+                f"no paged-pool page copy (copy_paged_pages)")
+        self.n_dispatch += 1
+        width = 1 << (max(len(pairs), self.n_slots) - 1).bit_length()
+        src = np.zeros((width,), np.int32)
+        dst = np.zeros((width,), np.int32)
+        for i, (s, d) in enumerate(pairs):
+            src[i], dst[i] = s, d
+        fn = _copy_pages if self._donate else _copy_pages_async
+        self.cache = fn(copy_fn, self.cache, jnp.asarray(src),
+                        jnp.asarray(dst))
 
     # ---------------------------------------------------- speculative draft
 

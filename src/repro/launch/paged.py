@@ -140,7 +140,7 @@ class SlotPageTables:
     tail entries). Pages are allocated lazily: the prompt's pages at
     admission, then one page at a time as decode crosses page
     boundaries — resident KV bytes track actual sequence lengths instead
-    of the slot-cache's ``n_slots × max_len`` worst case.
+    of a contiguous cache's ``n_slots × max_len`` worst case.
 
     Admission additionally *reserves* the request's worst-case page count
     (prompt + decode budget) without allocating it: ``can_admit`` only

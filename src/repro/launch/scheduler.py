@@ -2,7 +2,7 @@
 scheduler.
 
 ``TokenBudgetScheduler`` is the vLLM-style planner behind
-``ServeEngine(schedule="unified")``: each engine step it packs up to
+``ServeEngine``: each engine step it packs up to
 ``max_batch_tokens`` of work — one decode token for every running slot
 plus prefill chunks for admitting/in-flight ones — into a single
 :class:`StepPlan` that the device executor (``repro.launch.executor``)
@@ -14,8 +14,7 @@ compile shapes.
 
 Everything here is pure host-side bookkeeping (numpy + python); the only
 device-adjacent state it touches is the paged-KV page table
-(``repro.launch.paged``), which it grows/releases exactly like the legacy
-engine does.
+(``repro.launch.paged``), which it reserves, grows and releases.
 
 Planning order per step (all FIFO-preserving):
 
@@ -29,7 +28,7 @@ Planning order per step (all FIFO-preserving):
 3. **admission** — while the queue head fits (free slot, page reservation
    for its worst case, budget left), pop it and schedule its first
    chunk. The head never yields to a younger request (head-of-line wait,
-   FIFO preserved — same rule as the legacy paged engine).
+   FIFO preserved).
 
 Invariants (property-tested in ``tests/test_scheduler_properties.py``):
 every plan's packed token count is <= ``max_batch_tokens``; admission
@@ -298,8 +297,8 @@ class TokenBudgetScheduler:
         plan.spec_width = self.spec_k + 1
         budget = self.max_batch_tokens
         # 1. decode: one token per running slot (slot order = packing
-        # order, deterministic). Page growth happens here, mirroring the
-        # legacy engine's pre-step ``ensure``. In speculative mode every
+        # order, deterministic). Page growth happens here, before the
+        # step writes the new row. In speculative mode every
         # decoding slot instead packs a k+1-row verify item (its last
         # token plus k drafts, positions pos..pos+k) and BOTH pools grow
         # to cover the drafted positions up front — observe() shrinks the

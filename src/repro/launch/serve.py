@@ -5,17 +5,16 @@ CAT-transformed int8/int4-packed weights, dynamic act quant, int8 KV cache.
     PYTHONPATH=src python -m repro.launch.serve --arch catlm_60m \
         --requests 8 --n-slots 4 --gen 32 --transform cat --kv-bits 8
 
-Requests enter a FIFO queue deeper than the slot count; the engine
-prefills on admit, steps the occupied slots as one batch, and retires /
-reuses slots as requests finish — or, with ``--schedule unified``, packs
-decode tokens and prefill chunks into one token-budgeted ragged step per
-cycle (``--max-batch-tokens``; flat ITL under long-prompt admission,
-token-identical output). ``greedy_generate`` stays here as the
+Requests enter a FIFO queue deeper than the slot count; each cycle the
+engine packs decode tokens and prefill chunks into one token-budgeted
+ragged step over the paged KV pool (``--max-batch-tokens``) and retires /
+reuses slots as requests finish. ``greedy_generate`` stays here as the
 static-batch oracle the engine is tested against (token-identical).
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import os
 from typing import Optional
 
@@ -27,7 +26,7 @@ from repro.configs import get_config
 from repro.core.pipeline import QuantizeConfig, quantize_model
 from repro.core.qlinear import iter_qlinear, num_weight_bytes
 from repro.data import calibration_batches, make_batch, request_workload
-from repro.launch.engine import ServeEngine, jitted_model_fns
+from repro.launch.engine import ServeEngine
 from repro.models import build
 
 
@@ -59,6 +58,13 @@ def weight_memory_report(params) -> dict:
         "weight_bytes": int(sum(num_weight_bytes(l) for l in leaves)),
         "packed_int4": any(l.packed for l in leaves),
     }
+
+
+@functools.lru_cache(maxsize=8)
+def jitted_model_fns(model):
+    """(jit prefill, jit decode) cached per model so repeated oracle runs
+    over the same model share compilations."""
+    return jax.jit(model.prefill), jax.jit(model.decode)
 
 
 def greedy_generate(model, params, prompts: jnp.ndarray, gen: int,
@@ -116,7 +122,7 @@ def build_served_model(arch: str, transform: str, w_bits: int, a_bits: int,
                        kv_bits: int, smoke: bool, seed: int,
                        cfg_overrides: Optional[dict] = None):
     """-> (cfg, model, params, weight-memory report). ``transform='fp'``
-    skips PTQ; ``kv_bits>0`` serves from the int8 slot KV cache;
+    skips PTQ; ``kv_bits>0`` serves from the int8 KV cache;
     ``cfg_overrides`` are extra ``cfg.scaled`` fields (e.g. a
     TP-divisible head count for mesh serving)."""
     cfg = get_config(arch)
@@ -152,9 +158,10 @@ def build_draft_model(arch: str, smoke: bool, seed: int,
 
 
 def parse_mesh(spec: str):
-    """``--mesh dp,tp`` -> a ("data", "model") device mesh (None when the
-    spec is empty or 1,1). Needs dp*tp local devices — force host devices
-    with XLA_FLAGS=--xla_force_host_platform_device_count=N on CPU."""
+    """``--mesh 1,tp`` -> a ("data", "model") device mesh (None when the
+    spec is empty or 1,1). Needs tp local devices — force host devices
+    with XLA_FLAGS=--xla_force_host_platform_device_count=N on CPU. The
+    engine refuses a data axis > 1."""
     if not spec:
         return None
     dp, tp = (int(v) for v in spec.split(","))
@@ -171,9 +178,8 @@ def serve_benchmark(arch: str = "catlm_60m", batch: int = 4,
                     kv_bits: int = 8, n_slots: int = 0,
                     n_requests: int = 0, mixed: bool = False,
                     mesh=None, cfg_overrides: Optional[dict] = None,
-                    paged: bool = False, page_size: int = 16,
-                    prefill_chunk: int = 0, max_len: int = 0,
-                    schedule: str = "legacy", max_batch_tokens: int = 0,
+                    page_size: int = 16, prefill_chunk: int = 0,
+                    max_len: int = 0, max_batch_tokens: int = 0,
                     warmup: int = 0, prefix_cache: bool = False,
                     shared_prefix: int = 0, speculative: int = 0,
                     adaptive_spec: bool = False,
@@ -185,31 +191,28 @@ def serve_benchmark(arch: str = "catlm_60m", batch: int = 4,
     seeded mixed-prompt-length workload instead (per-request sequences in
     ``results``). ``n_slots`` defaults to ``batch`` (0 = auto). ``mesh``
     serves tensor-parallel (sharded int4 weights + sharded KV cache,
-    token-identical to single-device — see launch/README.md). ``paged``
-    swaps the slot cache for the paged KV pool (``page_size`` tokens per
-    page; ``prefill_chunk`` feeds prompts through in fixed chunks so
-    prefill compiles once) — token-identical to the slot engine.
-    ``schedule="unified"`` packs decode tokens + prefill chunks into one
-    token-budgeted ragged step per cycle (``max_batch_tokens``) —
-    token-identical again, with flat ITL under long-prompt admission.
-    ``warmup=N`` (N >= 1) drains the workload once untimed then reports
+    token-identical to single-device — see launch/README.md). The engine
+    packs decode tokens + prefill chunks into one token-budgeted ragged
+    step per cycle (``max_batch_tokens``) over the paged KV pool
+    (``page_size`` tokens per page; ``prefill_chunk`` caps a step's
+    chunk of one prompt). ``warmup=N`` (N >= 1) drains the workload once untimed then reports
     the fastest of N steady passes (``run_steady``), so the metrics are
     steady-state and compilation cost lands in the separate
-    ``compile_s`` summary field. ``prefix_cache=True`` (paged/unified
-    only) shares cached prefix pages across requests copy-on-write and
+    ``compile_s`` summary field. ``prefix_cache=True`` shares cached
+    prefix pages across requests copy-on-write and
     skips their prefill entirely; pair with ``shared_prefix=S`` to give
     the mixed workload an S-token common system prompt so the cache has
-    something to hit. ``speculative=k`` (unified only) drafts k tokens
+    something to hit. ``speculative=k`` drafts k tokens
     per slot per cycle with the int4-packed quantization of the same
     checkpoint and verifies them in one ragged target step — output
     stays token-identical to ``speculative=0``. ``adaptive_spec=True``
     lowers each slot's per-cycle draft depth toward its running
     acceptance rate (k stays the hard cap; output unchanged).
-    ``pipeline`` selects the depth-1 asynchronous unified loop (pack +
-    dispatch step N+1 while N runs on device; token-identical; see
-    launch/README.md) — default None means ON for unified unless
-    REPRO_SYNC_STEP is set; ``pipeline=False`` forces the synchronous
-    loop with honest blocked per-step timing spans."""
+    ``pipeline`` selects the depth-1 asynchronous loop (pack + dispatch
+    step N+1 while N runs on device; token-identical; see
+    launch/README.md) — default None means ON unless REPRO_SYNC_STEP is
+    set; ``pipeline=False`` forces the synchronous loop with honest
+    blocked per-step timing spans."""
     cfg, model, params, mem = build_served_model(
         arch, transform, w_bits, a_bits, kv_bits, smoke, seed,
         cfg_overrides=cfg_overrides)
@@ -230,8 +233,7 @@ def serve_benchmark(arch: str = "catlm_60m", batch: int = 4,
     max_prompt = max(len(r["tokens"]) for r in requests)
     engine = ServeEngine(model, params, n_slots=n_slots or batch,
                          max_len=max_len or max_prompt + gen + 8, mesh=mesh,
-                         paged=paged, page_size=page_size,
-                         prefill_chunk=prefill_chunk, schedule=schedule,
+                         page_size=page_size, prefill_chunk=prefill_chunk,
                          max_batch_tokens=max_batch_tokens,
                          prefix_cache=prefix_cache,
                          speculative_k=speculative, draft=draft,
@@ -261,11 +263,6 @@ def validate_flags(ap: argparse.ArgumentParser, args) -> None:
     asserts or deep-stack ValueErrors."""
     from repro.models.layers import KV_QUANT_GROUP
 
-    unified = args.schedule == "unified"
-    if (args.page_size != 16 or args.prefill_chunk) and not (args.paged
-                                                             or unified):
-        ap.error("--page-size/--prefill-chunk need --paged (or --schedule "
-                 "unified, which serves from the paged pool)")
     if args.page_size < 1:
         ap.error(f"--page-size must be >= 1 (got {args.page_size})")
     if args.kv_bits and args.page_size % KV_QUANT_GROUP:
@@ -273,39 +270,22 @@ def validate_flags(ap: argparse.ArgumentParser, args) -> None:
                  f"group (got {args.page_size}, group {KV_QUANT_GROUP})")
     if args.prefill_chunk < 0:
         ap.error(f"--prefill-chunk must be >= 0 (got {args.prefill_chunk})")
-    if args.prefill_chunk and args.prefill_chunk % args.page_size \
-            and not unified:
-        ap.error(f"--prefill-chunk must be a multiple of --page-size "
-                 f"(got {args.prefill_chunk}, page {args.page_size}); "
-                 f"legacy chunks write whole pages — only --schedule "
-                 f"unified slices chunks freely")
-    if args.prefix_cache and not (args.paged or unified):
-        ap.error("--prefix-cache needs --paged (or --schedule unified): "
-                 "cached prefixes are shared pages of the paged KV pool")
     if args.shared_prefix < 0:
         ap.error(f"--shared-prefix must be >= 0 "
                  f"(got {args.shared_prefix})")
-    if args.max_batch_tokens and not unified:
-        ap.error(f"--max-batch-tokens needs --schedule unified "
-                 f"(got {args.max_batch_tokens} with --schedule "
-                 f"{args.schedule})")
     if args.max_batch_tokens and args.max_batch_tokens < args.batch:
         ap.error(f"--max-batch-tokens must be >= --n-slots (got "
                  f"{args.max_batch_tokens}, slots {args.batch}; every "
                  f"running slot decodes one token per step)")
-    if unified and args.mesh:
+    if args.mesh:
         dp = args.mesh.split(",")[0]
         if dp.strip() not in ("", "1"):
-            ap.error(f"--schedule unified is tensor-parallel only — use "
-                     f"--mesh 1,tp (got --mesh {args.mesh}; the paged "
-                     f"pool is a global allocation and cannot shard over "
-                     f"a data axis)")
+            ap.error(f"serving is tensor-parallel only — use --mesh 1,tp "
+                     f"(got --mesh {args.mesh}; the paged pool is a "
+                     f"global allocation and cannot shard over a data "
+                     f"axis)")
     if args.speculative < 0:
         ap.error(f"--speculative must be >= 0 (got {args.speculative})")
-    if args.speculative and not unified:
-        ap.error(f"--speculative needs --schedule unified (got "
-                 f"--schedule {args.schedule}; the draft/verify cycle "
-                 f"runs inside the token-budgeted ragged step)")
     if (args.speculative and args.max_batch_tokens
             and args.max_batch_tokens < args.batch *
             (args.speculative + 1)):
@@ -316,9 +296,6 @@ def validate_flags(ap: argparse.ArgumentParser, args) -> None:
     if args.adaptive_spec and not args.speculative:
         ap.error("--adaptive-spec needs --speculative K (it tunes the "
                  "per-slot draft depth below K)")
-    if args.pipeline and not unified:
-        ap.error("--pipeline needs --schedule unified (legacy "
-                 "prefill-on-admit is inherently synchronous)")
 
 
 def main() -> None:
@@ -341,32 +318,21 @@ def main() -> None:
     ap.add_argument("--kv-bits", type=int, default=8,
                     help="KV-cache quant bits (0 = fp cache)")
     ap.add_argument("--mesh", default="",
-                    help="dp,tp device mesh (axes data,model) for "
+                    help="1,tp device mesh (axes data,model) for "
                          "tensor-parallel serving, e.g. --mesh 1,4")
-    ap.add_argument("--paged", action="store_true",
-                    help="serve from the paged KV pool (lazy per-page "
-                         "allocation) instead of the slot cache")
     ap.add_argument("--page-size", type=int, default=16,
                     help="tokens per KV page (must be a multiple of the "
-                         "KV quant scale group; needs --paged)")
+                         "KV quant scale group)")
     ap.add_argument("--prefill-chunk", type=int, default=0,
-                    help="feed prompts through prefill in fixed chunks of "
-                         "this many tokens — ONE prefill compile total "
-                         "(multiple of --page-size; needs --paged); in "
-                         "unified mode, a cap on per-step prefill chunks")
-    ap.add_argument("--schedule", default="legacy",
-                    choices=["legacy", "unified"],
-                    help="unified: pack decode tokens + prefill chunks "
-                         "into one token-budgeted ragged step per cycle "
-                         "(implies the paged KV pool)")
+                    help="cap on the tokens of one prompt a step packs "
+                         "(0 = the budget's remainder)")
     ap.add_argument("--max-batch-tokens", type=int, default=0,
-                    help="unified-schedule token budget per step "
-                         "(>= --n-slots; default 2×slots)")
+                    help="token budget per step (>= --n-slots; default "
+                         "max(16, 2×slots))")
     ap.add_argument("--prefix-cache", action="store_true",
                     help="share cached prompt-prefix pages across "
                          "requests (refcounted, copy-on-write) and skip "
-                         "their prefill — needs --paged or --schedule "
-                         "unified")
+                         "their prefill")
     ap.add_argument("--shared-prefix", type=int, default=0,
                     help="prepend this many common system-prompt tokens "
                          "to every request (the workload --prefix-cache "
@@ -376,19 +342,18 @@ def main() -> None:
                          "int4-packed quantization of the same checkpoint "
                          "and verify all K+1 positions in one ragged "
                          "target step (greedy acceptance — output stays "
-                         "token-identical; needs --schedule unified)")
+                         "token-identical)")
     ap.add_argument("--adaptive-spec", action="store_true",
                     help="lower each slot's per-cycle draft depth toward "
                          "its running acceptance rate (K stays the hard "
                          "cap; needs --speculative)")
     ap.add_argument("--pipeline", default=None,
                     action=argparse.BooleanOptionalAction,
-                    help="depth-1 asynchronous unified loop: pack + "
-                         "dispatch step N+1 while N runs on device "
-                         "(token-identical; default ON for --schedule "
-                         "unified unless REPRO_SYNC_STEP is set); "
-                         "--no-pipeline forces the synchronous loop with "
-                         "blocked per-step timing spans")
+                    help="depth-1 asynchronous loop: pack + dispatch "
+                         "step N+1 while N runs on device (token-"
+                         "identical; default ON unless REPRO_SYNC_STEP "
+                         "is set); --no-pipeline forces the synchronous "
+                         "loop with blocked per-step timing spans")
     ap.add_argument("--full-config", action="store_true")
     args = ap.parse_args()
     validate_flags(ap, args)
@@ -399,9 +364,8 @@ def main() -> None:
                           a_bits=args.a_bits, smoke=not args.full_config,
                           kv_bits=args.kv_bits, n_requests=args.requests,
                           mixed=args.mixed, mesh=parse_mesh(args.mesh),
-                          paged=args.paged, page_size=args.page_size,
+                          page_size=args.page_size,
                           prefill_chunk=args.prefill_chunk,
-                          schedule=args.schedule,
                           max_batch_tokens=args.max_batch_tokens,
                           prefix_cache=args.prefix_cache,
                           shared_prefix=args.shared_prefix,
@@ -410,11 +374,9 @@ def main() -> None:
                           pipeline=args.pipeline)
     eng = out["engine"]
     mesh_note = (f", mesh={eng['mesh']}" if eng.get("mesh") else "")
-    sched_note = ""
-    if eng.get("schedule") == "unified":
-        pipe = ", pipelined" if eng.get("pipeline") else ", sync"
-        sched_note = (f", unified[{eng['max_batch_tokens']}t budget, "
-                      f"itl p95 {eng['itl_p95_s'] * 1e3:.0f}ms{pipe}]")
+    pipe = ", pipelined" if eng["pipeline"] else ", sync"
+    sched_note = (f", unified[{eng['max_batch_tokens']}t budget, "
+                  f"itl p95 {eng['itl_p95_s'] * 1e3:.0f}ms{pipe}]")
     spec_note = ""
     if eng.get("speculative_k"):
         adapt = ", adaptive" if eng.get("adaptive_spec") else ""
@@ -426,15 +388,10 @@ def main() -> None:
         prefix_note = (f", prefix[{eng['prefix_hit_rate']:.0%} hit, "
                        f"{eng['prefix_hit_tokens']}t prefill skipped, "
                        f"{eng['cow_copies']} cow]")
-    # KV footprint in BOTH modes (slot-vs-paged rows compare like for
-    # like): paged resident bytes track live pages, the slot cache
-    # reserves its full capacity up front
     kv_note = (f", paged[{eng['page_size']}t/page, "
                f"{eng['resident_kv_bytes_mean'] / 2**10:.0f}KiB "
-               f"resident vs {eng['kv_capacity_bytes'] / 2**10:.0f}"
-               f"KiB slot-equivalent]") if eng.get("paged") else (
-               f", slot[{eng['resident_kv_bytes_mean'] / 2**10:.0f}KiB "
-               f"resident = capacity]")
+               f"resident of {eng['kv_capacity_bytes'] / 2**10:.0f}"
+               f"KiB]")
     print(f"{out['arch']} [{out['transform']}]: "
           f"{out['tok_per_s']:.1f} tok/s ({out['wall_s']:.2f}s wall) | "
           f"{eng['n_requests']} reqs on {eng['n_slots']} slots, "

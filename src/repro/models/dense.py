@@ -618,7 +618,7 @@ def ragged_step(cfg, params, tokens, cache, logit_rows, greedy=False,
     reads). Intra-chunk causality needs no special casing: all packed
     rows write k/v before attention, and the causal ``q_pos >= kv_pos``
     test masks same-chunk future tokens — per-row numerics are bitwise
-    identical to the legacy prefill/decode dispatches.
+    identical to ``prefill``/``decode`` over a contiguous cache.
 
     ``logit_rows`` (R,) generalizes prefill's ``logits_at`` to the
     ragged batch: logits are computed only at those packed rows (the

@@ -2,8 +2,9 @@
 three serving configs (fp, int8-KV, int4-packed weights + int8 KV) on the
 small catlm config, checked into ``tests/golden/*.json``.
 
-``tests/test_golden_outputs.py`` diffs live engine output against these
-files, so a kernel/engine refactor that silently changes decoded tokens
+``tests/test_golden_outputs.py`` diffs live output of the default
+``ServeEngine`` (unified token-budget step over the paged pool) against
+these files, so a kernel/engine refactor that silently changes decoded tokens
 fails loudly instead of drifting. When a change *intentionally* alters
 numerics (new quantizer, different accumulation), regenerate with
 
@@ -60,9 +61,9 @@ def run_case(name: str, **engine_kw) -> dict:
 
     ``engine_kw`` forwards to ``ServeEngine`` so the bitwise tests can
     replay the same fixture workload through every serving configuration
-    (paged, chunked, unified token-budget, mesh) — the fixtures
-    themselves are always regenerated with the default (legacy, slot
-    cache) engine."""
+    (page sizes, token budgets, chunk caps, mesh) — the fixtures
+    themselves are always regenerated with the default engine (unified
+    token-budget step over the paged pool, pipelined)."""
     from repro.data import request_workload
     from repro.launch.engine import ServeEngine
 

@@ -10,81 +10,19 @@ lengths / decode budgets / eos positions:
     position-faithful python simulation predicts (scheduler independence:
     batching/slot reuse must not leak between requests)
 
+The stub (``test_scheduler_properties._stub``) honours the engine's
+model contract: a paged cache and a ragged step whose token at each
+logit row is the stub rule applied to that row's fed token and position.
+
 Runs via tests/_hypothesis_shim: property cases when hypothesis is
 installed, the seeded deterministic ports always."""
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from _hypothesis_shim import given, settings, st
+from test_scheduler_properties import _V, _simulate, _stub
 
 from repro.launch.engine import ServeEngine
-
-_V = 64          # stub vocab
-
-
-def _next_token(tok, pos):
-    """Pure next-token rule: mixes token and absolute position so any
-    cache-position bug (wrong slot offset, stale row) changes output."""
-    return (tok * 7 + pos * 13 + 1) % _V
-
-
-class _StubModel:
-    """Dense-family stand-in honoring the engine's model contract:
-    prefill predicts from the last prompt token at position P-1; decode
-    predicts from the fed token at its (per-slot) cache position."""
-
-    def __init__(self, cfg):
-        self.cfg = cfg
-
-    def init_cache(self, batch, max_len):
-        return {"k": jnp.zeros((1, batch, max_len, 1, 1), jnp.float32),
-                "v": jnp.zeros((1, batch, max_len, 1, 1), jnp.float32),
-                "pos": jnp.int32(0)}
-
-    def prefill(self, params, tokens, cache, logits_at=None):
-        # honor the engine's bucketing contract: logits (and the predicted
-        # next token) come from the row at ``logits_at`` — rows past it
-        # are padding a real model would causally ignore
-        if logits_at is None:
-            logits_at = jnp.int32(tokens.shape[1] - 1)
-        tok = jax.lax.dynamic_slice_in_dim(tokens, logits_at, 1, axis=1)
-        pos = cache["pos"] + logits_at
-        nxt = _next_token(tok[:, 0], pos)
-        logits = jax.nn.one_hot(nxt, _V)[:, None, :]
-        return logits, dict(cache, pos=pos + 1)
-
-    def decode(self, params, token, cache):
-        nxt = _next_token(token[:, 0], cache["pos"])   # pos: (B,) per slot
-        return (jax.nn.one_hot(nxt, _V)[:, None, :],
-                dict(cache, pos=cache["pos"] + 1))
-
-
-_STUB = None
-
-
-def _stub() -> _StubModel:
-    """One shared instance so jitted_model_fns' lru_cache is hit across
-    cases (hypothesis-safe: no pytest fixture inside @given)."""
-    global _STUB
-    if _STUB is None:
-        from repro.configs import get_config
-        _STUB = _StubModel(get_config("catlm_60m").smoke())
-    return _STUB
-
-
-def _simulate(prompt, max_new, eos_id):
-    """The per-request ground truth the engine must reproduce."""
-    toks = list(prompt)
-    tok, pos = int(prompt[-1]), len(prompt) - 1
-    for _ in range(max_new):
-        tok = (tok * 7 + pos * 13 + 1) % _V
-        toks.append(tok)
-        pos += 1
-        if tok == eos_id:
-            break
-    return toks
 
 
 def _check_invariants(lengths, budgets, n_slots, eos_id):
@@ -159,11 +97,17 @@ def test_slot_allocator_invariants_ports(seed, n_slots):
 
 def test_eos_on_prefill_token_retires_without_decode():
     """A request whose very first (prefill-emitted) token is eos must
-    retire before ever joining a decode batch."""
+    retire before ever joining a decode batch: the synchronous loop
+    dispatches no decode; the pipelined loop dispatches one before it
+    has seen the eos, and discards it as a mispredict."""
     prompt = np.asarray([3, 5], np.int32)
     first = _simulate(prompt, 1, None)[-1]
-    engine = ServeEngine(_stub(), {}, n_slots=2, max_len=16,
-                         eos_id=first)
-    out = engine.run([{"rid": 0, "tokens": prompt, "max_new_tokens": 5}])
-    assert out[0].tokens.tolist() == [3, 5, first]
-    assert engine.metrics["decode_steps"] == 0
+    for pipeline in (False, True):
+        engine = ServeEngine(_stub(), {}, n_slots=2, max_len=16,
+                             eos_id=first, pipeline=pipeline)
+        out = engine.run([{"rid": 0, "tokens": prompt,
+                           "max_new_tokens": 5}])
+        assert out[0].tokens.tolist() == [3, 5, first]
+        assert engine.metrics["generated_tokens"] == 1
+        assert engine.metrics["decode_steps"] == int(pipeline)
+        assert engine.summary()["mispredicts"] == int(pipeline)

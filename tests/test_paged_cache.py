@@ -1,13 +1,14 @@
 """Paged KV cache: equivalence, allocator, and kernel coverage.
 
-- **Paged-vs-contiguous equivalence**: the paged engine (all variants —
-  whole-prompt bucketed prefill, chunked prefill, unbucketed) must
-  reproduce the checked-in golden token fixtures *bitwise* for fp,
-  int8-KV, and w4-packed configs; the gathered logical view is the same
-  tensor the slot cache holds, so this is equality, not tolerance. The
-  tp=4 mesh variant pins token identity against the solo engine (the
-  golden cfg is GQA n_kv_heads=2, which tp=4 correctly rejects — same
-  MHA-override convention as tests/test_tp_serve.py).
+- **Golden equivalence across pool geometries**: the engine (page 8
+  under a 4-token budget, page 4 with 8-token prefill chunks, page 8 at
+  the default budget) must reproduce the checked-in golden token
+  fixtures *bitwise* for fp, int8-KV, and w4-packed configs; the
+  gathered logical view is the same tensor whatever the page size, so
+  this is equality, not tolerance. The tp=4 mesh variant pins token
+  identity against the solo engine (the golden cfg is GQA n_kv_heads=2,
+  which tp=4 correctly rejects — same MHA-override convention as
+  tests/test_tp_serve.py).
 - **Allocator properties** (``repro.launch.paged``): no double
   allocation, exactly-once free, null page never handed out, and
   fragmentation bounded — any free page satisfies any request, so
@@ -67,16 +68,16 @@ def golden_tokens(case):
 # ------------------------------------------------ golden bitwise equivalence
 
 PAGED_VARIANTS = {
-    "paged8": dict(paged=True, page_size=8),
-    "chunked": dict(paged=True, page_size=4, prefill_chunk=8),
-    "unbucketed": dict(paged=True, page_size=8, bucket=False),
+    "paged8": dict(page_size=8, max_batch_tokens=4),
+    "chunked": dict(page_size=4, prefill_chunk=8),
+    "unbucketed": dict(page_size=8),
 }
 
 
 @pytest.mark.parametrize("case", sorted(regenerate.CASES))
 @pytest.mark.parametrize("variant", sorted(PAGED_VARIANTS))
 def test_paged_engine_matches_golden(case, variant):
-    """Every paged serving variant decodes the exact fixture tokens."""
+    """Every pool geometry decodes the exact fixture tokens."""
     got, eng = drain_paged(case, **PAGED_VARIANTS[variant])
     want = golden_tokens(case)
     assert set(got) == set(want)
@@ -89,14 +90,13 @@ def test_paged_engine_matches_golden(case, variant):
 
 def test_paged_resident_bytes_below_slot_cache():
     """The economics: on the mixed-length workload the paged pool's mean
-    resident KV bytes sit well under the slot cache's flat allocation."""
-    got, eng = drain_paged("int8_kv", paged=True, page_size=4)
-    slot_eng = ServeEngine(*built("int8_kv")[1:],
-                           n_slots=regenerate.N_SLOTS,
-                           max_len=regenerate.MAX_LEN)
+    resident KV bytes sit well under the flat reservation a slot cache
+    makes (every slot holds a whole page table's rows up front)."""
+    got, eng = drain_paged("int8_kv", page_size=4)
+    slot_bytes = regenerate.N_SLOTS * eng.tables.n_ptab * eng._page_bytes
     s = eng.summary()
-    assert s["paged"] and s["resident_kv_bytes_mean"] > 0
-    assert s["resident_kv_bytes_mean"] < slot_eng.resident_kv_bytes()
+    assert s["resident_kv_bytes_mean"] > 0
+    assert s["resident_kv_bytes_mean"] < slot_bytes
     assert s["resident_kv_bytes_peak"] <= s["kv_capacity_bytes"]
 
 
@@ -104,9 +104,9 @@ def test_paged_resident_bytes_below_slot_cache():
 @pytest.mark.parametrize("quantize", [False, True],
                          ids=["int8_kv", "w4_packed"])
 def test_paged_mesh_tp4_token_identical(quantize):
-    """Paged engine on a (1, 4) tp mesh: sharded page pool (heads on
-    'model', pages whole, table replicated) decodes token-identically to
-    the single-device slot engine."""
+    """Engine on a (1, 4) tp mesh: sharded page pool (heads on 'model',
+    pages whole, table replicated) decodes token-identically to the
+    single-device engine."""
     from repro.configs import get_config
     from repro.distributed.compat import make_mesh
     from repro.models import build
@@ -128,7 +128,7 @@ def test_paged_mesh_tp4_token_identical(quantize):
     reqs = request_workload(cfg, 5, gen=4, lengths=(6, 10), seed=3)
     solo = ServeEngine(model, params, n_slots=2, max_len=24).run(reqs)
     meshed = ServeEngine(model, params, n_slots=2, max_len=24, mesh=mesh,
-                         paged=True, page_size=8, prefill_chunk=8).run(reqs)
+                         page_size=8, prefill_chunk=8).run(reqs)
     for r in reqs:
         np.testing.assert_array_equal(meshed[r["rid"]].tokens,
                                       solo[r["rid"]].tokens,
@@ -147,15 +147,14 @@ def test_paged_mesh_rejects_dp():
     model = build(cfg)
     params = model.init(jax.random.PRNGKey(0))
     with pytest.raises(NotImplementedError, match="tensor-parallel only"):
-        ServeEngine(model, params, n_slots=2, max_len=24, paged=True,
+        ServeEngine(model, params, n_slots=2, max_len=24,
                     mesh=make_mesh((2, 2), ("data", "model")))
 
 
 def test_paged_kernel_engine_agrees_with_golden():
     """paged_kernel=True streams int8 pages through the Pallas kernel —
     rtol-level numerics, so assert high token agreement, not equality."""
-    got, _ = drain_paged("int8_kv", paged=True, page_size=8,
-                         paged_kernel=True)
+    got, _ = drain_paged("int8_kv", page_size=8, paged_kernel=True)
     want = golden_tokens("int8_kv")
     agree = np.mean([np.mean(np.asarray(got[rid]) == np.asarray(want[rid]))
                      for rid in want])
@@ -168,18 +167,12 @@ def test_paged_engine_validation():
     cfg, model, params = built("int8_kv")
     make = lambda **kw: ServeEngine(model, params, n_slots=2, max_len=24,
                                     **kw)  # noqa: E731
-    with pytest.raises(ValueError, match="multiple of"):
-        make(paged=True, page_size=8, prefill_chunk=12)
     with pytest.raises(ValueError, match="page_size"):
-        make(paged=True, page_size=0)
+        make(page_size=0)
     with pytest.raises(ValueError, match="prefill_chunk"):
-        make(paged=True, page_size=8, prefill_chunk=-8)
-    with pytest.raises(ValueError, match="paged=True"):
-        make(prefill_chunk=8)
-    with pytest.raises(ValueError, match="paged=True"):
-        make(paged_kernel=True)
+        make(page_size=8, prefill_chunk=-8)
     # a request that could never fit the (shrunken) pool fails at submit
-    eng = make(paged=True, page_size=8, n_pages=2)
+    eng = make(page_size=8, n_pages=2)
     with pytest.raises(ValueError, match="pages"):
         eng.submit(np.arange(1, 10), 8)
 
@@ -193,8 +186,8 @@ def test_paged_pool_exhaustion_waits_not_corrupts():
                             seed=regenerate.SEED)
     # 3 allocatable pages of 8: budgets (prompt+gen) need 2 pages each,
     # so at most one request's reservation fits at a time
-    eng = ServeEngine(model, params, n_slots=2, max_len=24, paged=True,
-                      page_size=8, n_pages=4)
+    eng = ServeEngine(model, params, n_slots=2, max_len=24, page_size=8,
+                      n_pages=4)
     results = eng.run(reqs)
     assert len(results) == 4
     admits = [e for e in eng.events if e[0] == "admit"]

@@ -1,10 +1,10 @@
 """Pipelined (depth-1 asynchronous) unified serving loop.
 
-The pipelined loop (``ServeEngine(pipeline=True)``, the unified-mode
-default) packs and dispatches step N+1 while step N executes on device,
+The pipelined loop (``ServeEngine(pipeline=True)``, the default)
+packs and dispatches step N+1 while step N executes on device,
 sampling greedily inside the jitted step so only (n_logits,) int32
 tokens ever cross D2H. It must be **bitwise token-identical** to the
-synchronous loop — and therefore to the legacy golden fixtures — across
+synchronous loop — and therefore to the golden fixtures — across
 every serving configuration it composes with:
 
   - plain unified (tight and loose budgets, prefill chunking)
@@ -31,6 +31,7 @@ import pytest
 from _hypothesis_shim import given, settings, st
 
 from golden import regenerate
+from test_scheduler_properties import _stub
 
 from repro.data import request_workload
 from repro.launch.engine import ServeEngine
@@ -65,7 +66,7 @@ def _assert_pool_drained(eng):
     dict(max_batch_tokens=8, prefill_chunk=4),    # chunk cap on top
 ], ids=["budget6", "budget8chunk4"])
 def test_pipelined_matches_golden_bitwise(case, kw):
-    got = regenerate.run_case(case, schedule="unified", page_size=8,
+    got = regenerate.run_case(case, page_size=8,
                               pipeline=True, **kw)
     golden = _golden(case)
     for rid, want in golden.items():
@@ -85,7 +86,7 @@ def test_pipelined_summary_and_timing_spans():
                             lengths=regenerate.LENGTHS,
                             seed=regenerate.SEED)
     eng = ServeEngine(model, params, n_slots=regenerate.N_SLOTS,
-                      max_len=regenerate.MAX_LEN, schedule="unified",
+                      max_len=regenerate.MAX_LEN,
                       page_size=8)
     assert eng.pipeline
     for r in reqs:
@@ -110,31 +111,25 @@ def test_pipelined_summary_and_timing_spans():
 def test_pipelined_equals_sync_loop():
     """pipeline=True vs pipeline=False on the same config: identical
     tokens, and the sync run reports pipeline=False with zero overlap."""
-    a = regenerate.run_case("fp", schedule="unified", page_size=8,
+    a = regenerate.run_case("fp", page_size=8,
                             max_batch_tokens=6, pipeline=True)
-    b = regenerate.run_case("fp", schedule="unified", page_size=8,
+    b = regenerate.run_case("fp", page_size=8,
                             max_batch_tokens=6, pipeline=False)
     assert a == b
 
 
 def test_sync_env_var_forces_synchronous(monkeypatch):
-    """REPRO_SYNC_STEP=1 flips the unified default to the synchronous
+    """REPRO_SYNC_STEP=1 flips the default to the synchronous
     loop (profiling mode: honest blocked per-step spans)."""
     monkeypatch.setenv("REPRO_SYNC_STEP", "1")
     cfg, model, params = regenerate.build_case("fp")
     eng = ServeEngine(model, params, n_slots=2, max_len=24,
-                      schedule="unified", page_size=8)
+                      page_size=8)
     assert eng.pipeline is False
     # an explicit pipeline=True still wins over the env default
     eng2 = ServeEngine(model, params, n_slots=2, max_len=24,
-                       schedule="unified", page_size=8, pipeline=True)
+                       page_size=8, pipeline=True)
     assert eng2.pipeline is True
-
-
-def test_pipeline_needs_unified_schedule():
-    cfg, model, params = regenerate.build_case("fp")
-    with pytest.raises(ValueError, match="pipeline"):
-        ServeEngine(model, params, n_slots=2, max_len=24, pipeline=True)
 
 
 # -------------------------------------------------- prefix cache compose
@@ -149,7 +144,7 @@ def test_pipelined_prefix_cache_matches_golden():
                             seed=regenerate.SEED)
     golden = _golden("int8_kv")
     eng = ServeEngine(model, params, n_slots=regenerate.N_SLOTS,
-                      max_len=regenerate.MAX_LEN, schedule="unified",
+                      max_len=regenerate.MAX_LEN,
                       page_size=8, prefix_cache=True)
     for label in ("cold", "warm"):
         res = eng.run(reqs)
@@ -173,7 +168,7 @@ def test_pipelined_speculative_matches_golden(k, spec_draft):
     injected from the in-flight target step's device vector; partial
     accepts roll the optimistic next step back): bitwise equal to the
     target-only golden fixture."""
-    got = regenerate.run_case("fp", schedule="unified", page_size=8,
+    got = regenerate.run_case("fp", page_size=8,
                               max_batch_tokens=12, speculative_k=k,
                               draft=spec_draft, pipeline=True)
     golden = _golden("fp")
@@ -188,14 +183,14 @@ def test_pipelined_speculative_eos_rollback(spec_draft):
     cfg, model, params = regenerate.build_case("fp")
     reqs = request_workload(cfg, 4, gen=6, lengths=(6, 10), seed=11)
     base = ServeEngine(model, params, n_slots=2, max_len=24,
-                       schedule="unified", page_size=8, speculative_k=2,
+                       page_size=8, speculative_k=2,
                        draft=spec_draft, pipeline=False).run(reqs)
     # an eos seen mid-stream in the no-eos run forces early retirement
     eos = int(base[0].tokens[base[0].prompt_len])
     runs = []
     for pipeline in (False, True):
         eng = ServeEngine(model, params, n_slots=2, max_len=24,
-                          schedule="unified", page_size=8, speculative_k=2,
+                          page_size=8, speculative_k=2,
                           draft=spec_draft, eos_id=eos, pipeline=pipeline)
         runs.append(eng.run(reqs))
         _assert_pool_drained(eng)
@@ -221,11 +216,11 @@ def test_pipelined_tp4_mha_matches_sync_solo():
     params = model.init(jax.random.PRNGKey(0))
     reqs = request_workload(cfg, 6, gen=5, lengths=(6, 10, 14), seed=3)
     solo = ServeEngine(model, params, n_slots=3, max_len=32,
-                       schedule="unified", page_size=8,
+                       page_size=8,
                        pipeline=False).run(reqs)
     mesh = make_mesh((1, 4), ("data", "model"))
     eng = ServeEngine(model, params, n_slots=3, max_len=32, mesh=mesh,
-                      schedule="unified", page_size=8, pipeline=True)
+                      page_size=8, pipeline=True)
     meshed = eng.run(reqs)
     for r in reqs:
         np.testing.assert_array_equal(
@@ -245,7 +240,7 @@ def test_eos_mispredict_rolls_back_to_sync_trajectory():
     cfg, model, params = regenerate.build_case("fp")
     reqs = request_workload(cfg, 4, gen=6, lengths=(6, 10), seed=11)
     base = ServeEngine(model, params, n_slots=2, max_len=24,
-                       schedule="unified", page_size=8,
+                       page_size=8,
                        pipeline=False).run(reqs)
     # the 2nd generated token of request 0 retires it 4 tokens early —
     # its slot is mid-decode, so the next step always has it packed
@@ -253,7 +248,7 @@ def test_eos_mispredict_rolls_back_to_sync_trajectory():
     runs, engines = [], []
     for pipeline in (False, True):
         eng = ServeEngine(model, params, n_slots=2, max_len=24,
-                          schedule="unified", page_size=8, eos_id=eos,
+                          page_size=8, eos_id=eos,
                           pipeline=pipeline)
         runs.append(eng.run(reqs))
         engines.append(eng)
@@ -288,8 +283,6 @@ def test_forced_retirement_equals_sync_seeded(seed, which, depth):
 
 
 def _forced_retirement_case(seed, which, depth):
-    from test_scheduler_properties import _stub
-
     rng = np.random.default_rng(seed)
     reqs = [{"rid": i,
              "tokens": rng.integers(0, 64, int(p)).astype(np.int32),
@@ -297,7 +290,7 @@ def _forced_retirement_case(seed, which, depth):
             for i, (p, g) in enumerate(zip(rng.integers(1, 12, 4),
                                            rng.integers(2, 7, 4)))]
     base = ServeEngine(_stub(), {}, n_slots=2, max_len=24,
-                       schedule="unified", page_size=4,
+                       page_size=4,
                        pipeline=False).run(reqs)
     rid = int(which) % len(reqs)
     gen = base[rid].tokens[base[rid].prompt_len:]
@@ -305,7 +298,7 @@ def _forced_retirement_case(seed, which, depth):
     runs, engines = [], []
     for pipeline in (False, True):
         eng = ServeEngine(_stub(), {}, n_slots=2, max_len=24,
-                          schedule="unified", page_size=4, eos_id=eos,
+                          page_size=4, eos_id=eos,
                           pipeline=pipeline)
         runs.append(eng.run(reqs))
         engines.append(eng)
@@ -335,7 +328,7 @@ def test_reset_mid_flight_refused_then_clean_after_drain():
                             lengths=regenerate.LENGTHS,
                             seed=regenerate.SEED)
     eng = ServeEngine(model, params, n_slots=regenerate.N_SLOTS,
-                      max_len=regenerate.MAX_LEN, schedule="unified",
+                      max_len=regenerate.MAX_LEN,
                       page_size=8)
     for r in reqs:
         eng.submit(r["tokens"], r["max_new_tokens"], rid=r.get("rid"))
@@ -356,31 +349,3 @@ def test_reset_mid_flight_refused_then_clean_after_drain():
     for r in reqs:
         np.testing.assert_array_equal(res[r["rid"]].tokens,
                                       first[r["rid"]])
-
-
-# ------------------------------------------- legacy executor regression
-
-def test_legacy_decode_device_argmax_and_d2h_attribution():
-    """LegacyExecutor.decode samples on device — the returned array is
-    the (n_slots,) int32 token vector, and the (tiny) D2H copy is
-    attributed to d2h_s / d2h_ms_mean instead of inflating the engine's
-    compute span. Output stays pinned to the golden fixture."""
-    cfg, model, params = regenerate.build_case("fp")
-    reqs = request_workload(cfg, regenerate.N_REQUESTS, gen=regenerate.GEN,
-                            lengths=regenerate.LENGTHS,
-                            seed=regenerate.SEED)
-    eng = ServeEngine(model, params, n_slots=regenerate.N_SLOTS,
-                      max_len=regenerate.MAX_LEN)
-    toks = np.zeros((regenerate.N_SLOTS, 1), np.int32)
-    pos = np.zeros((regenerate.N_SLOTS,), np.int32)
-    out = eng.exec.decode(toks, pos)
-    assert out.shape == (regenerate.N_SLOTS,) and out.dtype == np.int32
-    assert eng.exec.d2h_s > 0.0
-    res = eng.run(reqs)
-    golden = _golden("fp")
-    for r in reqs:
-        assert (np.asarray(res[r["rid"]].tokens).tolist()
-                == golden[str(r["rid"])]), r["rid"]
-    s = eng.summary()
-    assert "d2h_ms_mean" in s and s["d2h_ms_mean"] > 0.0
-    assert s["device_ms_mean"] > 0.0

@@ -5,8 +5,8 @@ it: attention always reads the *stored* (post-quantization) page
 content, and identical tokens at identical positions produce identical
 codes/scales, so prefix-cache-on decoded tokens must match the
 checked-in fixtures **bit for bit** — for all three serving configs
-(fp, int8 KV, int4-packed weights), under the legacy paged schedule and
-the unified token-budget schedule, single-device and on a tp=4 mesh.
+(fp, int8 KV, int4-packed weights), under three token budgets and
+chunk caps, single-device and on a tp=4 mesh.
 
 The differential tests then force the sharing machinery to actually
 fire: a shared-system-prompt workload (real hits, cache-on vs cache-off
@@ -24,12 +24,12 @@ from golden import regenerate
 from repro.data import request_workload
 from repro.launch.engine import ServeEngine
 
-# every serving schedule the prefix cache rides on; page_size 8 keeps
-# the fixture prompts (6/10 tokens) spanning a full + partial page
+# step geometries the prefix cache rides on; page_size 8 keeps the
+# fixture prompts (6/10 tokens) spanning a full + partial page
 SCHEDULES = [
-    ("legacy", dict(paged=True, page_size=8, prefill_chunk=8)),
-    ("legacy_nochunk", dict(paged=True, page_size=8)),
-    ("unified", dict(schedule="unified", page_size=8, max_batch_tokens=8)),
+    ("chunk8", dict(page_size=8, prefill_chunk=8)),
+    ("budget64", dict(page_size=8, max_batch_tokens=64)),
+    ("budget8", dict(page_size=8, max_batch_tokens=8)),
 ]
 
 
@@ -42,8 +42,8 @@ def _golden(case):
 @pytest.mark.parametrize("sched_kw", SCHEDULES,
                          ids=[n for n, _ in SCHEDULES])
 def test_prefix_cache_on_matches_golden_bitwise(case, sched_kw):
-    """Cache-on output equals the (cache-off, legacy slot-engine) golden
-    fixture exactly, for every schedule the cache rides on."""
+    """Cache-on output equals the (cache-off) golden fixture exactly,
+    for every step geometry the cache rides on."""
     _, kw = sched_kw
     got = regenerate.run_case(case, prefix_cache=True, **kw)
     for rid, want in _golden(case).items():
@@ -132,7 +132,7 @@ def test_prefix_cache_matches_golden_at_tp4():
     solo = ServeEngine(model, params, n_slots=2, max_len=40).run(reqs)
     mesh = make_mesh((1, 4), ("data", "model"))
     on_eng = ServeEngine(model, params, n_slots=2, max_len=40, mesh=mesh,
-                         schedule="unified", max_batch_tokens=8,
+                         max_batch_tokens=8,
                          page_size=8, prefix_cache=True)
     on = on_eng.run(reqs)
     for r in reqs:
@@ -140,10 +140,3 @@ def test_prefix_cache_matches_golden_at_tp4():
                                       solo[r["rid"]].tokens,
                                       err_msg=f"rid={r['rid']}")
     assert on_eng.summary()["prefix_hits"] > 0
-
-
-def test_prefix_cache_requires_paged():
-    cfg, model, params = regenerate.build_case("fp")
-    with pytest.raises(ValueError, match="paged"):
-        ServeEngine(model, params, n_slots=2, max_len=24,
-                    prefix_cache=True)

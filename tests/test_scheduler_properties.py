@@ -18,8 +18,8 @@
      exactly once; drained pools return every page
 
 2. **Engine integration** — a ragged-contract stub model through
-   ``ServeEngine(schedule="unified")``, asserting the engine reproduces
-   the legacy (prefill-on-admit) engine's output exactly.
+   ``ServeEngine``, asserting the engine reproduces the per-request
+   simulation exactly.
 
 Runs via tests/_hypothesis_shim: property cases when hypothesis is
 installed, the seeded deterministic ports always."""
@@ -245,47 +245,24 @@ def test_long_prompt_interleaves_with_decode():
 # ------------------------------------------------- engine integration stub
 
 class _RaggedStubModel:
-    """Dense-family stand-in honoring BOTH engine contracts: the legacy
-    prefill/decode pair and the unified ragged step (logits at packed
-    ``logit_rows``, next token a pure function of the fed token and its
-    position). Carries a paged-cache shape so the unified engine's pool
-    bookkeeping runs for real."""
+    """Dense-family stand-in honoring the engine's model contract: a
+    paged cache (so the engine's pool bookkeeping runs for real) and the
+    ragged step (the greedy token at packed ``logit_rows``, a pure
+    function of the fed token and its position)."""
 
     def __init__(self, cfg):
         self.cfg = cfg
 
-    def init_cache(self, batch, max_len):
-        return {"k": jnp.zeros((1, batch, max_len, 1, 1), jnp.float32),
-                "v": jnp.zeros((1, batch, max_len, 1, 1), jnp.float32),
-                "pos": jnp.int32(0)}
-
     def init_paged_cache(self, n_pages, page_size):
         return {"k": jnp.zeros((1, n_pages, page_size, 1, 1), jnp.float32),
                 "v": jnp.zeros((1, n_pages, page_size, 1, 1), jnp.float32)}
-
-    def prefill(self, params, tokens, cache, logits_at=None):
-        if logits_at is None:
-            logits_at = jnp.int32(tokens.shape[1] - 1)
-        import jax
-        tok = jax.lax.dynamic_slice_in_dim(tokens, logits_at, 1, axis=1)
-        pos = cache["pos"] + logits_at
-        nxt = (tok[:, 0] * 7 + pos * 13 + 1) % _V
-        import jax.nn
-        logits = jax.nn.one_hot(nxt, _V)[:, None, :]
-        return logits, dict(cache, pos=pos + 1)
-
-    def decode(self, params, token, cache):
-        import jax.nn
-        nxt = (token[:, 0] * 7 + cache["pos"] * 13 + 1) % _V
-        return (jax.nn.one_hot(nxt, _V)[:, None, :],
-                dict(cache, pos=cache["pos"] + 1))
 
     def ragged_step(self, params, tokens, cache, logit_rows, greedy=False,
                     **kw):
         import jax.nn
         fed = jnp.take(tokens[:, 0], logit_rows)
         pos = jnp.take(cache["pos"], logit_rows)
-        nxt = (fed * 7 + pos * 13 + 1) % _V
+        nxt = _next_token(fed, pos)
         if greedy:      # device-resident sampling (models.dense contract)
             return nxt.astype(jnp.int32), dict(cache)
         return (jax.nn.one_hot(nxt, _V)[:, None, :],
@@ -305,20 +282,20 @@ def _stub():
 
 @pytest.mark.parametrize("budget,chunk", [(3, 0), (8, 0), (5, 4)])
 def test_unified_engine_matches_legacy_on_stub(budget, chunk):
+    """The engine over the stub reproduces the per-request simulation
+    under budgets that reshuffle packing and a chunk cap."""
     rng = np.random.default_rng(7)
     reqs = [{"rid": i, "tokens": rng.integers(0, _V, p).astype(np.int32),
              "max_new_tokens": g}
             for i, (p, g) in enumerate([(5, 3), (11, 2), (1, 4), (8, 1),
                                         (13, 5)])]
-    legacy = ServeEngine(_stub(), {}, n_slots=3, max_len=24)
-    lres = legacy.run(reqs)
     uni = ServeEngine(_stub(), {}, n_slots=3, max_len=24,
-                      schedule="unified", max_batch_tokens=budget,
-                      prefill_chunk=chunk, page_size=4)
+                      max_batch_tokens=budget, prefill_chunk=chunk,
+                      page_size=4)
     ures = uni.run(reqs)
     for r in reqs:
-        assert (lres[r["rid"]].tokens == ures[r["rid"]].tokens).all(), \
-            r["rid"]
+        want = _simulate(r["tokens"], r["max_new_tokens"], None)
+        assert ures[r["rid"]].tokens.tolist() == want, r["rid"]
     # engine-level mirrors of the scheduler invariants
     assert max(t for t, *_ in uni.sched.plan_log) <= budget
     admits = [e[1] for e in uni.events if e[0] == "admit"]

@@ -17,7 +17,7 @@ MAX_LEN = 14 + GEN + 8          # longest workload prompt + gen + slack
 
 @pytest.fixture(scope="module")
 def served(tiny_cfg):
-    """Tiny model with the serving-default int8 slot KV cache."""
+    """Tiny model with the serving-default int8 KV cache."""
     from repro.models import build
     cfg = tiny_cfg.scaled(kv_quant_bits=8)
     model = build(cfg)
@@ -125,13 +125,17 @@ def test_metrics_and_backpressure(drained):
 # ------------------------------------------------------------------- edges
 
 def test_single_token_request_retires_from_prefill(served):
+    """max_new_tokens=1: the prefill step's token is the whole output —
+    one device dispatch, no decode step."""
     _, model, params = served
     engine = ServeEngine(model, params, n_slots=2, max_len=MAX_LEN)
     rid = engine.submit(np.arange(1, 9, dtype=np.int32), 1)
-    engine.step()
-    assert rid in engine.results and engine.idle
+    while not engine.idle:
+        engine.step()
+    assert rid in engine.results
     assert len(engine.results[rid].tokens) == 9
     assert engine.metrics["decode_steps"] == 0
+    assert engine.exec.n_dispatch == 1
 
 
 def test_submit_rejects_overflow_empty_dup_and_zero_budget(served):
@@ -149,8 +153,9 @@ def test_submit_rejects_overflow_empty_dup_and_zero_budget(served):
 
 
 def test_unsupported_family_rejected_up_front():
-    """Per-slot position vectors are a dense-family contract; ssm/hybrid
-    models must fail loudly at construction, not decode garbage."""
+    """Per-token positions in a paged pool are a dense-family contract;
+    ssm/hybrid models must fail loudly at construction, not decode
+    garbage."""
     from repro.configs import get_config
     from repro.models import build
     model = build(get_config("rwkv6_7b").smoke())

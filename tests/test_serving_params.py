@@ -14,7 +14,7 @@ MAX_LEN = 14 + 5 + 8
 
 def _engine(model, params):
     return ServeEngine(model, params, n_slots=3, max_len=MAX_LEN,
-                       schedule="unified", max_batch_tokens=16)
+                       max_batch_tokens=16)
 
 
 def test_jitted_serving_gives_the_tokens_of_pre_served_params(

@@ -77,10 +77,6 @@ def test_tp_cache_specs_head_axis_and_pos(tiny_cfg, tp4_mesh):
     specs2 = tp_cache_specs(_cache_shapes(tiny_cfg.scaled(kv_quant_bits=8),
                                           4, 32), tp4_mesh)
     assert specs2["k"] == P(None, None, None, None, None)
-    # dp axis shards the slot axis when it divides
-    specs3 = tp_cache_specs(cache, abstract_mesh((2, 2), ("data", "model")),
-                            dp_axis="data")
-    assert specs3["k"][1] == "data"
 
 
 # ------------------------------------------------------------- tp params

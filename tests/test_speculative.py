@@ -51,7 +51,7 @@ def test_speculative_matches_golden_bitwise(case, k):
     """Accepted+corrected output == the target-only golden fixture for
     every quant config and draft depth (identity is structural — the
     draft only changes how many verify rows get accepted per cycle)."""
-    got = regenerate.run_case(case, schedule="unified", page_size=8,
+    got = regenerate.run_case(case, page_size=8,
                               max_batch_tokens=12, speculative_k=k,
                               draft=_draft())
     golden = _golden(case)
@@ -66,7 +66,7 @@ def test_adaptive_spec_matches_golden_bitwise():
     cycle — acceptance still appends target-argmax rows only, so the
     output must stay bitwise equal to the golden fixture."""
     case = sorted(regenerate.CASES)[0]
-    got = regenerate.run_case(case, schedule="unified", page_size=8,
+    got = regenerate.run_case(case, page_size=8,
                               max_batch_tokens=12, speculative_k=4,
                               draft=_draft(), adaptive_spec=True)
     golden = _golden(case)
@@ -87,7 +87,7 @@ def test_speculative_shared_prefix_identity(prefix_cache):
     cfg, model, params = regenerate.build_case("int8_kv")
     reqs = request_workload(cfg, 6, gen=5, lengths=(6, 10), seed=11,
                             shared_prefix=6)
-    kw = dict(n_slots=2, max_len=24, schedule="unified",
+    kw = dict(n_slots=2, max_len=24,
               max_batch_tokens=12, page_size=8, prefix_cache=prefix_cache)
     base_eng = ServeEngine(model, params, **kw)
     base = base_eng.run(reqs)
@@ -114,7 +114,7 @@ def test_speculative_shared_prefix_identity(prefix_cache):
 def test_speculative_tp4_token_identical():
     """tp=4 mesh on the MHA override (same convention as the unified
     mesh test): the draft always runs plain single-device jit, only the
-    target verify is shard_mapped — output must equal the solo legacy
+    target verify is shard_mapped — output must equal the solo
     engine's."""
     import jax
 
@@ -133,7 +133,7 @@ def test_speculative_tp4_token_identical():
     solo = ServeEngine(model, params, n_slots=2, max_len=24).run(reqs)
     mesh = make_mesh((1, 4), ("data", "model"))
     spec = ServeEngine(model, params, n_slots=2, max_len=24, mesh=mesh,
-                       schedule="unified", max_batch_tokens=12,
+                       max_batch_tokens=12,
                        page_size=8, speculative_k=2,
                        draft=_draft(key="mha4", n_kv_heads=4)).run(reqs)
     for r in reqs:
@@ -161,7 +161,7 @@ def test_speculative_kv_rewind_invariant():
     # between steps (its drained-pool equality lives in
     # test_pipelined_engine.py), so pin the synchronous loop here.
     eng = ServeEngine(model, params, n_slots=2, max_len=24,
-                      schedule="unified", max_batch_tokens=12, page_size=8,
+                      max_batch_tokens=12, page_size=8,
                       speculative_k=2, draft=_draft(key="seed1", seed=1),
                       pipeline=False)
     for r in reqs:
@@ -189,18 +189,18 @@ def test_speculative_kv_rewind_invariant():
 
 def test_speculative_engine_validation():
     cfg, model, params = regenerate.build_case("fp")
-    with pytest.raises(ValueError, match="schedule"):
+    with pytest.raises(ValueError, match="speculative_k"):
         ServeEngine(model, params, n_slots=2, max_len=24,
-                    speculative_k=2, draft=_draft())
+                    speculative_k=-1, draft=_draft())
     with pytest.raises(ValueError, match="draft"):
         ServeEngine(model, params, n_slots=2, max_len=24,
-                    schedule="unified", max_batch_tokens=12,
+                    max_batch_tokens=12,
                     speculative_k=2)
     # every running slot packs k+1 verify rows, so the budget floor
     # scales with spec_k
     with pytest.raises(ValueError, match="max_batch_tokens"):
         ServeEngine(model, params, n_slots=2, max_len=24,
-                    schedule="unified", max_batch_tokens=4,
+                    max_batch_tokens=4,
                     speculative_k=2, draft=_draft())
 
 
@@ -246,7 +246,7 @@ def test_device_time_within_step_time():
     step_s span."""
     cfg, model, params = regenerate.build_case("fp")
     reqs = request_workload(cfg, 4, gen=4, lengths=(6, 10), seed=2)
-    for kw in (dict(schedule="unified", max_batch_tokens=12, page_size=8),
+    for kw in (dict(max_batch_tokens=12, page_size=8),
                dict()):
         eng = ServeEngine(model, params, n_slots=2, max_len=24, **kw)
         eng.run(reqs)
@@ -264,7 +264,7 @@ def test_ttft_non_negative_and_asserted():
     cfg, model, params = regenerate.build_case("fp")
     reqs = request_workload(cfg, 3, gen=2, lengths=(6,), seed=0)
     eng = ServeEngine(model, params, n_slots=2, max_len=16,
-                      schedule="unified", max_batch_tokens=8, page_size=8)
+                      max_batch_tokens=8, page_size=8)
     res = eng.run(reqs)
     assert all(r.ttft_s >= 0 for r in res.values())
     assert eng.summary()["ttft_s_mean"] >= 0

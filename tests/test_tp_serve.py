@@ -1,5 +1,5 @@
-"""Tensor-parallel serving: ServeEngine on a ("data", "model") device
-mesh — sharded int4-packed weights, sharded (quantized) KV cache — must
+"""Tensor-parallel serving: ServeEngine on a (1, tp) ("data", "model")
+device mesh — sharded int4-packed weights, sharded (quantized) KV pool — must
 drain the seeded mixed-prompt workload with **token-identical** output to
 the single-device engine for fp, int8-KV, and int4-packed configs (which
 is itself token-identical to solo greedy_generate, PR 2's contract).
@@ -138,22 +138,6 @@ def test_mesh_engine_untied_embeddings_token_identical(mesh):
     params = build(cfg).init(jax.random.PRNGKey(2))
     reqs, solo, meshed, _ = _drain_both(cfg, params, mesh, n_requests=4)
     _assert_identical(reqs, solo, meshed)
-
-
-@needs_mesh
-def test_mesh_engine_dp_tp_token_identical(mha_params):
-    """(2, 2) mesh: the decode batch (slot axis) and the per-slot pos
-    vector shard over 'data' while heads shard over 'model' — still
-    token-identical to single device."""
-    from repro.configs import get_config
-    from repro.distributed.compat import make_mesh
-    cfg = get_config("catlm_60m").smoke().scaled(n_kv_heads=4,
-                                                 kv_quant_bits=8)
-    mesh22 = make_mesh((2, 2), ("data", "model"))
-    reqs, solo, meshed, eng = _drain_both(cfg, mha_params, mesh22,
-                                          n_requests=4, n_slots=2)
-    _assert_identical(reqs, solo, meshed)
-    assert eng.summary()["mesh"] == {"data": 2, "model": 2}
 
 
 # ------------------------------------------------------------- validation

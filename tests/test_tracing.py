@@ -31,7 +31,7 @@ def _engine(pipeline: bool):
                             lengths=regenerate.LENGTHS,
                             seed=regenerate.SEED)
     eng = ServeEngine(model, params, n_slots=regenerate.N_SLOTS,
-                      max_len=regenerate.MAX_LEN, schedule="unified",
+                      max_len=regenerate.MAX_LEN,
                       max_batch_tokens=8, paged_kernel=True, page_size=8,
                       pipeline=pipeline)
     assert eng.exec.supports_decode_step

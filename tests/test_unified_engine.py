@@ -2,12 +2,13 @@
 
 The unified step packs decode tokens and prefill chunks into ONE ragged
 model invocation (``launch/scheduler.py`` + ``launch/executor.py`` +
-``models.dense.ragged_step``). Per-row numerics are unchanged from the
-legacy dispatches, so decoded tokens must be **bitwise identical** to the
+``models.dense.ragged_step``). Per-row numerics do not depend on what
+else a step packs, so decoded tokens must be **bitwise identical** to the
 checked-in golden fixtures — across budgets (which reshuffle step packing
 arbitrarily), with and without a prefill-chunk cap, and at tensor
-parallelism. The ragged paged-attention kernel path is rtol-level (like
-legacy ``paged_kernel``) and is pinned at >= 0.9 token agreement.
+parallelism — and to ``greedy_generate``'s solo static decode. The
+paged-attention kernel path is rtol-level and is pinned at >= 0.9 token
+agreement.
 """
 import json
 
@@ -32,12 +33,12 @@ def _golden(case):
     dict(max_batch_tokens=8, prefill_chunk=4),    # chunk cap on top
 ], ids=["budget6", "budget64", "budget8chunk4"])
 def test_unified_matches_golden_bitwise(case, kw):
-    got = regenerate.run_case(case, schedule="unified", page_size=8, **kw)
+    got = regenerate.run_case(case, page_size=8, **kw)
     golden = _golden(case)
     for rid, want in golden.items():
         assert got[rid] == want, (
             f"{case} {kw}: unified tokens for rid={rid} diverged from the "
-            f"legacy golden fixture")
+            f"golden fixture")
 
 
 def test_unified_matches_golden_at_tp2():
@@ -58,7 +59,7 @@ def test_unified_matches_golden_at_tp2():
                             lengths=regenerate.LENGTHS,
                             seed=regenerate.SEED)
     eng = ServeEngine(model, params, n_slots=regenerate.N_SLOTS,
-                      max_len=regenerate.MAX_LEN, schedule="unified",
+                      max_len=regenerate.MAX_LEN,
                       max_batch_tokens=6, mesh=mesh)
     res = eng.run(reqs)
     golden = _golden("fp")
@@ -69,8 +70,8 @@ def test_unified_matches_golden_at_tp2():
 
 def test_unified_mesh_tp4_token_identical():
     """tp=4 on an MHA override (same convention as test_paged_cache):
-    the unified mesh engine must be token-identical to the solo legacy
-    engine. Also pins that unified rejects dp meshes loudly."""
+    the unified mesh engine must be token-identical to the solo engine
+    at another budget. Also pins that dp meshes are rejected loudly."""
     import jax
 
     if len(jax.devices()) < 4:
@@ -88,8 +89,7 @@ def test_unified_mesh_tp4_token_identical():
     solo = ServeEngine(model, params, n_slots=2, max_len=24).run(reqs)
     mesh = make_mesh((1, 4), ("data", "model"))
     uni = ServeEngine(model, params, n_slots=2, max_len=24, mesh=mesh,
-                      schedule="unified", max_batch_tokens=6,
-                      page_size=8).run(reqs)
+                      max_batch_tokens=6, page_size=8).run(reqs)
     for r in reqs:
         np.testing.assert_array_equal(uni[r["rid"]].tokens,
                                       solo[r["rid"]].tokens,
@@ -97,7 +97,7 @@ def test_unified_mesh_tp4_token_identical():
     with pytest.raises(NotImplementedError, match="tensor-parallel only"):
         ServeEngine(model, params, n_slots=2, max_len=24,
                     mesh=make_mesh((2, 2), ("data", "model")),
-                    schedule="unified", max_batch_tokens=6)
+                    max_batch_tokens=6)
 
 
 @pytest.mark.parametrize("kw", [
@@ -115,7 +115,7 @@ def test_unified_ragged_kernel_token_agreement(kw):
                             lengths=regenerate.LENGTHS,
                             seed=regenerate.SEED)
     eng = ServeEngine(model, params, n_slots=regenerate.N_SLOTS,
-                      max_len=regenerate.MAX_LEN, schedule="unified",
+                      max_len=regenerate.MAX_LEN,
                       paged_kernel=True, **kw)
     res = eng.run(reqs)
     golden = _golden("int8_kv")
@@ -136,7 +136,7 @@ def test_pure_decode_fast_path_engages_and_agrees():
                             lengths=regenerate.LENGTHS,
                             seed=regenerate.SEED)
     eng = ServeEngine(model, params, n_slots=regenerate.N_SLOTS,
-                      max_len=regenerate.MAX_LEN, schedule="unified",
+                      max_len=regenerate.MAX_LEN,
                       max_batch_tokens=8, paged_kernel=True, page_size=8)
     assert eng.exec.supports_decode_step
     calls = {"n": 0}
@@ -161,23 +161,33 @@ def test_pure_decode_fast_path_engages_and_agrees():
 
 
 def test_unified_eos_and_single_token_budgets():
-    """eos retirement and max_new=1 requests behave identically to
-    legacy under a budget that forces multi-step prefill."""
+    """eos retirement and max_new=1 requests decode exactly the solo
+    ``greedy_generate`` trajectory, cut after its first eos, under a
+    budget that forces multi-step prefill."""
+    import jax.numpy as jnp
+
+    from repro.launch.serve import greedy_generate
+
     cfg, model, params = regenerate.build_case("fp")
     reqs = request_workload(cfg, 5, gen=3, lengths=(6, 10), seed=3)
     reqs[1]["max_new_tokens"] = 1
-    legacy = ServeEngine(model, params, n_slots=2, max_len=24)
-    lres = legacy.run(reqs)
-    eos = int(lres[0].tokens[lres[0].prompt_len])   # first generated token
+    solo = {r["rid"]: np.asarray(greedy_generate(
+        model, params, jnp.asarray(r["tokens"])[None], r["max_new_tokens"],
+        24))[0].tolist() for r in reqs}
+    eos = solo[0][len(reqs[0]["tokens"])]           # first generated token
+
+    def want(r):
+        p = len(r["tokens"])
+        gen = solo[r["rid"]][p:]
+        return solo[r["rid"]][:p] + (gen[:gen.index(eos) + 1]
+                                     if eos in gen else gen)
+
     for n_slots, budget in ((2, 4), (3, 16)):
-        l2 = ServeEngine(model, params, n_slots=n_slots, max_len=24,
-                         eos_id=eos)
         u2 = ServeEngine(model, params, n_slots=n_slots, max_len=24,
-                         eos_id=eos, schedule="unified",
-                         max_batch_tokens=budget, page_size=8)
-        lr, ur = l2.run(reqs), u2.run(reqs)
+                         eos_id=eos, max_batch_tokens=budget, page_size=8)
+        ur = u2.run(reqs)
         for r in reqs:
-            assert (lr[r["rid"]].tokens == ur[r["rid"]].tokens).all(), (
+            assert ur[r["rid"]].tokens.tolist() == want(r), (
                 n_slots, budget, r["rid"])
     assert u2.pool.in_use == 0, "drained unified engine must free all pages"
 
@@ -186,26 +196,16 @@ def test_unified_summary_and_validation():
     cfg, model, params = regenerate.build_case("fp")
     reqs = request_workload(cfg, 3, gen=2, lengths=(6,), seed=0)
     eng = ServeEngine(model, params, n_slots=2, max_len=16,
-                      schedule="unified", max_batch_tokens=8, page_size=8)
+                      max_batch_tokens=8, page_size=8)
     eng.run(reqs)
     s = eng.summary()
-    assert s["schedule"] == "unified"
     assert s["max_batch_tokens"] == 8
     assert s["packed_tokens_max"] <= 8
     assert s["itl_p95_s"] >= s["itl_p50_s"] > 0
-    assert s["resident_kv_bytes_peak"] > 0
-    # legacy (slot) engines report the resident footprint too
-    leg = ServeEngine(model, params, n_slots=2, max_len=16)
-    leg.run(reqs)
-    ls = leg.summary()
-    assert ls["resident_kv_bytes_mean"] == ls["kv_capacity_bytes"]
-    assert ls["itl_p95_s"] > 0 and ls["schedule"] == "legacy"
+    assert 0 < s["resident_kv_bytes_peak"] <= s["kv_capacity_bytes"]
     with pytest.raises(ValueError, match="max_batch_tokens"):
         ServeEngine(model, params, n_slots=4, max_len=16,
-                    schedule="unified", max_batch_tokens=2)
+                    max_batch_tokens=2)
     with pytest.raises(ValueError, match="schedule"):
         ServeEngine(model, params, n_slots=2, max_len=16,
                     schedule="sjf")
-    with pytest.raises(ValueError, match="max_batch_tokens"):
-        ServeEngine(model, params, n_slots=2, max_len=16,
-                    max_batch_tokens=8)   # needs schedule="unified"
