@@ -21,12 +21,13 @@ Quantized serving adds two wrinkles this module owns:
 - quantized KV caches are a (codes int8, per-token scale f32) pair per
   K/V; both must shard the head axis congruently or a decode step would
   dequantize codes against the wrong slice of scales.
-- *paged* KV pools (``models.dense.init_paged_cache``) keep the same
-  5-dim leaf rank but mean (L, n_pages, page_size, KV, hd): the head
-  axis (3) still shards on ``model`` — codes and scales congruently —
-  while the page axis NEVER shards (every device holds its head slice
-  of every physical page; the host-side page table indexes pages
-  globally) and the ``page_table`` leaf replicates like ``pos``.
+- *paged* KV pools (``models.dense.init_paged_cache``) are (L, n_pages,
+  page_size, KV·hd) codes, whole heads side by side on the lane axis 3,
+  and (L, n_pages, KV, lanes) scales, heads on axis 2: both shard their
+  heads on ``model`` — congruently, whole heads per shard — while the
+  page axis NEVER shards (every device holds its head slice of every
+  physical page; the host-side page table indexes pages globally) and
+  the ``page_table`` leaf replicates like ``pos``.
 
 ``tp_param_specs``/``tp_cache_specs`` emit plain PartitionSpec trees for
 ``shard_map`` (the serve engine's tensor-parallel mode); the
@@ -163,6 +164,33 @@ _KV_KEYS = {"k", "v"}
 _KV_SCALE_KEYS = {"k_scale", "v_scale"}
 
 
+def _head_axes(cache):
+    """-> ``axis(key, shape)``: a KV cache leaf's (head axis, head
+    count), or (None, None) for any other leaf.
+
+    Contiguous caches (L, B, T, KV, hd|1) hold heads on axis 3. A paged
+    pool (``models.dense.init_paged_cache``) holds them on the lane axis
+    3 of its (L, n_pages, G, KV·hd) codes and on axis 2 of its (L,
+    n_pages, KV, lanes) scales; the scales give the head count. An fp
+    pool has no scales: its lanes stand in, and mesh serving admits only
+    whole heads per shard (``launch.executor._validate_tp``)."""
+    scale = cache.get("k_scale") if isinstance(cache, dict) else None
+    paged_heads = (scale.shape[2] if scale is not None and scale.ndim == 4
+                   else None)
+
+    def axis(key, shape):
+        nd = len(shape)
+        if nd == 5 and key not in ("pos", "page_table"):
+            return 3, shape[3]
+        if nd == 4 and key in _KV_SCALE_KEYS:
+            return 2, shape[2]
+        if nd == 4 and key in _KV_KEYS:
+            return 3, paged_heads or shape[3]
+        return None, None
+
+    return axis
+
+
 def cache_sharding(cache, mesh, cfg=None, shard_seq: bool = False):
     """KV caches (L, B, T, KV, hd): batch on dp, heads on model when
     divisible; long-context (B not divisible) shards T on data instead.
@@ -173,13 +201,16 @@ def cache_sharding(cache, mesh, cfg=None, shard_seq: bool = False):
     axis so scales shard exactly like their codes — the shape heuristic
     alone would misread a scale (or a short-T cache) as an SSM state.
 
-    Paged caches (a ``page_table`` leaf next to (L, n_pages, page_size,
-    KV, hd) pools) shard heads on model only: the page axis stays whole
-    on every device (page ids are global) and the table replicates."""
+    Paged caches (a ``page_table`` leaf next to the pools of
+    ``models.dense.init_paged_cache``) shard heads on model only: the
+    page axis stays whole on every device (page ids are global) and the
+    table replicates."""
     dp = dp_axes(mesh)
     ms = _model_size(mesh)
     dp_size = int(np.prod([mesh.shape[a] for a in dp])) if dp else 1
     paged = isinstance(cache, dict) and "page_table" in cache
+
+    head_axis = _head_axes(cache)
 
     def spec(path, leaf):
         shape = leaf.shape
@@ -187,9 +218,11 @@ def cache_sharding(cache, mesh, cfg=None, shard_seq: bool = False):
         key = _last_key(path)
         if nd == 0 or key in ("pos", "page_table"):
             return NamedSharding(mesh, P(*([None] * nd)))
-        if paged and nd == 5:
-            hspec = "model" if shape[3] % ms == 0 else None
-            return NamedSharding(mesh, P(None, None, None, hspec, None))
+        ax, heads = head_axis(key, shape)
+        if paged and ax is not None:
+            sp = [None] * nd
+            sp[ax] = "model" if heads % ms == 0 else None
+            return NamedSharding(mesh, P(*sp))
         if nd == 5:  # (L, B, T, KV, hd) kv-cache or (L, B, H, dk, dv) state
             batch_ok = dp and shape[1] % dp_size == 0
             if key in _KV_KEYS or key in _KV_SCALE_KEYS:
@@ -340,20 +373,21 @@ def tp_cache_specs(cache, mesh, axis: str = "model"):
     AND their per-token scales shard the head axis congruently when the
     head count divides; ``pos`` and anything non-divisible replicate.
 
-    Axis 3 is the head axis for both the contiguous layout (L, B, T, KV,
-    hd) and the paged pool (L, n_pages, page_size, KV, hd), so
-    codes/scales shard congruently either way; the page axis stays whole
-    and the ``page_table`` replicates alongside ``pos``."""
+    Axis 3 is the head axis of the contiguous layout (L, B, T, KV, hd);
+    a paged pool holds whole heads on the lane axis 3 of its (L, n_pages,
+    page_size, KV·hd) codes and on axis 2 of its (L, n_pages, KV, lanes)
+    scales (``_head_axes``), so codes/scales shard congruently either
+    way; the page axis stays whole and the ``page_table`` replicates
+    alongside ``pos``."""
     tp = mesh.shape[axis]
+    head_axis = _head_axes(cache)
 
     def walk(path, leaf):
-        nd = len(leaf.shape)
-        key = _last_key(path)
-        if key in ("pos", "page_table") or nd < 5:
-            return P(*([None] * nd))
-        heads = leaf.shape[3]
-        hspec = axis if heads % tp == 0 else None
-        return P(None, None, None, hspec, None)
+        sp = [None] * len(leaf.shape)
+        ax, heads = head_axis(_last_key(path), leaf.shape)
+        if ax is not None and heads % tp == 0:
+            sp[ax] = axis
+        return P(*sp)
 
     return jax.tree_util.tree_map_with_path(
         walk, cache,

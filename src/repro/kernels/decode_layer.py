@@ -25,15 +25,23 @@ block index is unchanged):
         acc -> split q|k|v columns -> RoPE(q, k) with per-row positions
         -> q out; quantize_kv(k), quantize_kv(v) -> code/scale scratch
     last (j, kk), every r:                     # the paged scatter
-        row r's (KVH, hd) codes + (KVH, 1) scales -> pool out blocks
-        whose index maps target (page_ids[r], row_ids[r])
+        row r's (KVH·hd) codes -> sublane row_ids[r] of page
+        page_ids[r]'s (G, KVH·hd) code block; its (KVH,) scales -> lane
+        row_ids[r] of the page's (KVH, lanes) scale block
 
-The four pool leaves ride through ``input_output_aliases`` so every page
-row the grid does not target keeps its prior content; before the final
-(j, kk) sweep the pool out-spec index maps park on the reserved null
-page (0, 0) — inert by the pool contract, exactly like the composed
-path's padded ``_write_kv_paged`` rows. Padded batch rows (M < 8) pass
-``page_ids == row_ids == 0`` and land there too.
+The four pool leaves come whole, every layer stacked, in the layout
+``kernels/paged_attention.py`` reads (codes ``(L, n_pages, G, KVH·hd)``,
+scales ``(L, n_pages, KVH, lanes)``), with the layer index as a
+scalar-prefetch operand, and ride through ``input_output_aliases``, so
+no layer is sliced out. A row is no block of its own in either leaf, so
+each leaf is read a page at a time at the index it is written and only
+the row's sublane (codes) or lane (scales) replaced: every row the grid
+does not target keeps its prior content. Before the final (j, kk) sweep
+the pool index maps park on the reserved null page (0, 0) — inert by
+the pool contract, exactly like the composed path's padded
+``paged_cache_update_quantized`` rows. Padded batch rows (M < 8) pass
+``page_ids == row_ids == 0`` and land there too. Real rows target pages
+of distinct slots, so no two of them read or write one page.
 
 Numerics: the RoPE and KV-quant stages mirror ``models.layers.rope`` /
 ``quantize_kv`` op for op in f32 and the contraction is exact int32, but
@@ -53,6 +61,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .fused_cat_matmul import _prep_operands, _transform_quant
+from .paged_attention import _check_pool, _layer_operand
 from .quant_matmul_w4 import _GEMV_M, _unpack_block, int8_contract
 
 
@@ -78,8 +87,8 @@ def _quantize_kv_rows(t, bits: int):
     """``models.layers.quantize_kv`` op for op: symmetric codes + f32
     scales over the last axis of one head's (M, hd) rows. The codes stay
     f32 (integral values) until the scatter casts them to the pool's
-    int8: Mosaic stores a head's rows into the (M, KVH, hd) scratch only
-    for 32-bit values."""
+    int8: Mosaic stores a head's rows into its lanes of the (M, KVH·hd)
+    scratch only for 32-bit values."""
     qmax = 2.0 ** (bits - 1) - 1
     amax = jnp.max(jnp.abs(t), axis=-1, keepdims=True)
     scale = jnp.maximum(amax, 1e-12) / qmax
@@ -93,7 +102,7 @@ def _make_prologue_kernel(*, act_bits: int, packed: bool, has_blocks: bool,
     kvh = n_kv // head_dim
 
     def kernel(*refs):
-        (pid_ref, rid_ref), refs = refs[:2], refs[2:]
+        (_layer_ref, _pid_ref, rid_ref), refs = refs[:3], refs[3:]
         if has_blocks:
             (x_ref, sign_ref, ha_ref, hb_ref, blocks_ref, w_ref, sw_ref,
              pos_ref), refs = refs[:8], refs[8:]
@@ -101,7 +110,7 @@ def _make_prologue_kernel(*, act_bits: int, packed: bool, has_blocks: bool,
             (x_ref, sign_ref, ha_ref, hb_ref, w_ref, sw_ref,
              pos_ref), refs = refs[:7], refs[7:]
             blocks_ref = None
-        (_kin, _ksin, _vin, _vsin,                    # aliased, unread
+        (ki_ref, ksi_ref, vi_ref, vsi_ref,            # aliased pool pages
          qo_ref, ko_ref, kso_ref, vo_ref, vso_ref,
          qx_ref, sx_ref, zx_ref, acc_ref,
          kq_ref, ks_ref, vq_ref, vs_ref) = refs
@@ -139,17 +148,29 @@ def _make_prologue_kernel(*, act_bits: int, packed: bool, has_blocks: bool,
             v = y[:, n_q + n_kv:n_q + 2 * n_kv]
             for h in range(kvh):
                 cols = slice(h * head_dim, (h + 1) * head_dim)
-                kq_ref[:, h, :], ks_ref[:, h, :] = _quantize_kv_rows(
+                kq_ref[:, cols], ks_ref[:, h, :] = _quantize_kv_rows(
                     k[:, cols], kv_bits)
-                vq_ref[:, h, :], vs_ref[:, h, :] = _quantize_kv_rows(
+                vq_ref[:, cols], vs_ref[:, h, :] = _quantize_kv_rows(
                     v[:, cols], kv_bits)
 
         @pl.when(last_jk)
         def _scatter():
-            ko_ref[...] = kq_ref[r].astype(ko_ref.dtype)[None, None]
-            kso_ref[...] = ks_ref[r][None, None]    # (1, 1, KVH, 1)
-            vo_ref[...] = vq_ref[r].astype(vo_ref.dtype)[None, None]
-            vso_ref[...] = vs_ref[r][None, None]
+            rid = rid_ref[r]
+            # the row's codes into its sublane of the page's code block
+            # (selected in f32: the int8 values round-trip exactly)
+            sub = jax.lax.broadcasted_iota(jnp.int32, ko_ref.shape, 1)
+            for row_ref, page_ref, out_ref in ((kq_ref, ki_ref, ko_ref),
+                                               (vq_ref, vi_ref, vo_ref)):
+                new = row_ref[pl.ds(r, 1), :][None]        # (1, 1, KVH·hd)
+                old = page_ref[...].astype(jnp.float32)
+                out_ref[...] = jnp.where(sub == rid, new, old).astype(
+                    out_ref.dtype)
+            # the row's (KVH, 1) scales into its lane of the scale block
+            lane = jax.lax.broadcasted_iota(jnp.int32, kso_ref.shape, 2)
+            kso_ref[...] = jnp.where(lane == rid, ks_ref[r][None],
+                                     ksi_ref[...])
+            vso_ref[...] = jnp.where(lane == rid, vs_ref[r][None],
+                                     vsi_ref[...])
 
     return kernel
 
@@ -158,7 +179,7 @@ def _make_prologue_kernel(*, act_bits: int, packed: bool, has_blocks: bool,
     "n_q", "head_dim", "rope_theta", "kv_bits", "act_bits", "packed",
     "block_n", "block_k", "interpret"))
 def decode_qkv_prologue(x, blocks, ha, hb, sign, qw, sw,
-                        k_pool, k_scale, v_pool, v_scale,
+                        k_pool, k_scale, v_pool, v_scale, layer,
                         page_ids, row_ids, positions, *,
                         n_q: int, head_dim: int, rope_theta: float,
                         kv_bits: int = 8, act_bits: int = 8,
@@ -172,8 +193,10 @@ def decode_qkv_prologue(x, blocks, ha, hb, sign, qw, sw,
     qw          (ceil(D/2), N) packed int4 — or (D, N) int8 — QKV weight,
                 N = n_q + 2·n_kv columns laid out [q | k | v]
     sw          (1, N) f32 weight scales
-    k/v_pool    (n_pages, page_size, KVH, hd) int8 pool leaves
-    k/v_scale   (n_pages, page_size, KVH, 1) f32 pool leaves
+    k/v_pool    (L, n_pages, page_size, KVH·hd) int8 pool leaves
+    k/v_scale   (L, n_pages, KVH, scale_lanes(page_size)) f32 pool
+                leaves (``paged_attention.scale_lanes``)
+    layer       int32 scalar: the layer whose pools take the rows
     page_ids    (B,) int32 physical page per row (0 = null page for
                 padded/invalid rows — the write is inert)
     row_ids     (B,) int32 row within the page
@@ -190,8 +213,9 @@ def decode_qkv_prologue(x, blocks, ha, hb, sign, qw, sw,
     assert n_q + 2 * n_kv == n, (n_q, n)
     assert n_q % head_dim == 0 and n_kv % head_dim == 0, (n_q, n_kv,
                                                           head_dim)
-    n_pages, page_size, kvh, hd = k_pool.shape
-    assert hd == head_dim and kvh == n_kv // head_dim, (k_pool.shape, n_kv)
+    kvh = n_kv // head_dim
+    _check_pool(k_pool, k_scale, kvh, head_dim)
+    page_size, lanes = k_pool.shape[2], k_scale.shape[3]
     tk = min(block_k, d + d % 2)
     tk += tk % 2
     tn = min(block_n, n)
@@ -216,61 +240,61 @@ def decode_qkv_prologue(x, blocks, ha, hb, sign, qw, sw,
         tn=tn, k_pad=k_pad, gn=gn, gk=gk, n_q=n_q, n_kv=n_kv,
         head_dim=head_dim, rope_theta=rope_theta, kv_bits=kv_bits)
 
-    def _pool_idx(j, kk, r, pid, rid):
+    def _page_idx(j, kk, r, lyr, pid, rid):
         # park on the inert null page until the final (j, kk) sweep — the
         # only flushes that reach real rows carry the finished epilogue
         last = (j == gn - 1) & (kk == gk - 1)
-        return (jnp.where(last, pid[r], 0), jnp.where(last, rid[r], 0),
-                0, 0)
+        return lyr[0], jnp.where(last, pid[r], 0), 0, 0
 
-    def _null_idx(j, kk, r, pid, rid):
-        return (0, 0, 0, 0)
+    def const(*idx):
+        return lambda j, kk, r, lyr, pid, rid: idx
 
+    code_block = (pl.squeezed, 1, page_size, n_kv)
+    scale_block = (pl.squeezed, 1, kvh, lanes)
     in_specs = [
-        pl.BlockSpec((_GEMV_M, d), lambda j, kk, r, pid, rid: (0, 0)),
-        pl.BlockSpec((1, d), lambda j, kk, r, pid, rid: (0, 0)),
-        pl.BlockSpec(ha.shape, lambda j, kk, r, pid, rid: (0, 0)),
-        pl.BlockSpec(hb.shape, lambda j, kk, r, pid, rid: (0, 0)),
+        pl.BlockSpec((_GEMV_M, d), const(0, 0)),
+        pl.BlockSpec((1, d), const(0, 0)),
+        pl.BlockSpec(ha.shape, const(0, 0)),
+        pl.BlockSpec(hb.shape, const(0, 0)),
     ]
     operands = [x, sign, ha, hb]
     if has_blocks:
-        in_specs.append(pl.BlockSpec(blocks.shape,
-                                     lambda j, kk, r, pid, rid: (0, 0, 0)))
+        in_specs.append(pl.BlockSpec(blocks.shape, const(0, 0, 0)))
         operands.append(blocks)
     in_specs += [
         pl.BlockSpec((tk // 2 if packed else tk, tn),
-                     lambda j, kk, r, pid, rid: (kk, j)),
-        pl.BlockSpec((1, tn), lambda j, kk, r, pid, rid: (0, j)),
-        pl.BlockSpec((_GEMV_M, 1), lambda j, kk, r, pid, rid: (0, 0)),
-        # aliased pool leaves: blocked on the null page, never read
-        pl.BlockSpec((1, 1, kvh, hd), _null_idx),
-        pl.BlockSpec((1, 1, kvh, 1), _null_idx),
-        pl.BlockSpec((1, 1, kvh, hd), _null_idx),
-        pl.BlockSpec((1, 1, kvh, 1), _null_idx),
+                     lambda j, kk, r, lyr, pid, rid: (kk, j)),
+        pl.BlockSpec((1, tn), lambda j, kk, r, lyr, pid, rid: (0, j)),
+        pl.BlockSpec((_GEMV_M, 1), const(0, 0)),
+        # aliased pool leaves, read a page at a time where written
+        pl.BlockSpec(code_block, _page_idx),
+        pl.BlockSpec(scale_block, _page_idx),
+        pl.BlockSpec(code_block, _page_idx),
+        pl.BlockSpec(scale_block, _page_idx),
     ]
     operands += [qw, sw, pos8, k_pool, k_scale, v_pool, v_scale]
     # alias indices count ALL pallas_call operands, scalar prefetch first:
-    # pid=0, rid=1, then `operands` — pools are the last four
-    pool0 = 2 + len(operands) - 4
+    # layer=0, pid=1, rid=2, then `operands` — pools are the last four
+    pool0 = 3 + len(operands) - 4
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,              # page_ids, row_ids
+        num_scalar_prefetch=3,              # layer, page_ids, row_ids
         grid=(gn, gk, _GEMV_M),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((_GEMV_M, n_q), lambda j, kk, r, pid, rid: (0, 0)),
-            pl.BlockSpec((1, 1, kvh, hd), _pool_idx),
-            pl.BlockSpec((1, 1, kvh, 1), _pool_idx),
-            pl.BlockSpec((1, 1, kvh, hd), _pool_idx),
-            pl.BlockSpec((1, 1, kvh, 1), _pool_idx),
+            pl.BlockSpec((_GEMV_M, n_q), const(0, 0)),
+            pl.BlockSpec(code_block, _page_idx),
+            pl.BlockSpec(scale_block, _page_idx),
+            pl.BlockSpec(code_block, _page_idx),
+            pl.BlockSpec(scale_block, _page_idx),
         ],
         scratch_shapes=[
             pltpu.VMEM((_GEMV_M, k_pad), jnp.int8),      # act codes
             pltpu.VMEM((_GEMV_M, 1), jnp.float32),       # act scale
             pltpu.VMEM((_GEMV_M, 1), jnp.float32),       # act zero point
             pltpu.VMEM((_GEMV_M, n_pad), jnp.float32),   # qkv accumulator
-            pltpu.VMEM((_GEMV_M, kvh, hd), jnp.float32),  # k codes
+            pltpu.VMEM((_GEMV_M, n_kv), jnp.float32),    # k codes
             pltpu.VMEM((_GEMV_M, kvh, 1), jnp.float32),  # k scales
-            pltpu.VMEM((_GEMV_M, kvh, hd), jnp.float32),  # v codes
+            pltpu.VMEM((_GEMV_M, n_kv), jnp.float32),    # v codes
             pltpu.VMEM((_GEMV_M, kvh, 1), jnp.float32),  # v scales
         ],
     )
@@ -288,5 +312,5 @@ def decode_qkv_prologue(x, blocks, ha, hb, sign, qw, sw,
                               pool0 + 3: 4},
         name="decode_qkv_prologue",
         interpret=interpret,
-    )(page_ids, row_ids, *operands)
+    )(_layer_operand(layer), page_ids, row_ids, *operands)
     return q8[:m], kp, ksc, vp, vsc

@@ -240,7 +240,7 @@ def fused_cat_matmul(x, blocks, ha, hb, sign, qw, sw, act_bits: int = 8,
 
 
 def decode_qkv_prologue(x, blocks, ha, hb, sign, qw, sw,
-                        k_pool, k_scale, v_pool, v_scale,
+                        k_pool, k_scale, v_pool, v_scale, layer,
                         page_ids, row_ids, positions, *,
                         n_q: int, head_dim: int, rope_theta: float,
                         kv_bits: int = 8, act_bits: int = 8,
@@ -250,7 +250,8 @@ def decode_qkv_prologue(x, blocks, ha, hb, sign, qw, sw,
     paged-pool scatter. Together with the paged-attention kernel this
     makes a decode layer's attention block exactly two launches.
 
-    Returns (q (B, n_q) f32 rope'd, k_pool', k_scale', v_pool',
+    Takes the stacked pool leaves and the layer that takes the rows;
+    returns (q (B, n_q) f32 rope'd, k_pool', k_scale', v_pool',
     v_scale') with the pool leaves donated through
     ``input_output_aliases``. Block sizes come from
     ``autotune.prologue_blocks`` unless passed explicitly.
@@ -266,14 +267,15 @@ def decode_qkv_prologue(x, blocks, ha, hb, sign, qw, sw,
         kw.setdefault("block_k", tk)
     return _decode_qkv_prologue(
         x, blocks, ha, hb, sign, qw, sw, k_pool, k_scale, v_pool, v_scale,
-        page_ids, row_ids, positions, n_q=n_q, head_dim=head_dim,
+        layer, page_ids, row_ids, positions, n_q=n_q, head_dim=head_dim,
         rope_theta=rope_theta, kv_bits=kv_bits, act_bits=act_bits,
         packed=packed, **kw)
 
 
-def paged_attention(q, k_pages, k_scale, v_pages, v_scale, page_table,
-                    lengths, **kw):
-    """Paged decode attention from the quantized KV page pool.
+def paged_attention(q, k_pages, k_scale, v_pages, v_scale, layer,
+                    page_table, lengths, **kw):
+    """Paged decode attention from layer ``layer`` of the stacked
+    quantized KV page pool.
 
     Routes int8 pools to the Pallas kernel (page table + lengths ride as
     scalar-prefetch operands driving the per-page DMA; dequant + online
@@ -282,13 +284,13 @@ def paged_attention(q, k_pages, k_scale, v_pages, v_scale, page_table,
     """
     if k_scale is None or v_scale is None:
         return paged_attention_fallback(q, k_pages, k_scale, v_pages,
-                                        v_scale, page_table, lengths)
+                                        v_scale, layer, page_table, lengths)
     kw.setdefault("interpret", default_interpret())
     return paged_attention_decode(q, k_pages, k_scale, v_pages, v_scale,
-                                  page_table, lengths, **kw)
+                                  layer, page_table, lengths, **kw)
 
 
-def ragged_paged_attention(q, k_pages, k_scale, v_pages, v_scale,
+def ragged_paged_attention(q, k_pages, k_scale, v_pages, v_scale, layer,
                            page_table, lengths, q_pos, **kw):
     """Mixed-q_len paged attention for the unified token-budget step:
     per-work-item query blocks against the page pool, with the
@@ -298,11 +300,11 @@ def ragged_paged_attention(q, k_pages, k_scale, v_pages, v_scale,
     """
     if k_scale is None or v_scale is None:
         return paged_attention_ragged_fallback(q, k_pages, k_scale,
-                                               v_pages, v_scale,
+                                               v_pages, v_scale, layer,
                                                page_table, lengths, q_pos)
     kw.setdefault("interpret", default_interpret())
     return paged_attention_ragged(q, k_pages, k_scale, v_pages, v_scale,
-                                  page_table, lengths, q_pos, **kw)
+                                  layer, page_table, lengths, q_pos, **kw)
 
 
 # ------------------------------------------------- tensor-parallel wrappers

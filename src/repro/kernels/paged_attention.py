@@ -3,18 +3,29 @@
 The serve engine's paged KV cache stores int8 codes + per-(token, head)
 f32 scales in fixed-size *pages* of a global pool (``repro.launch.paged``);
 a request's logical sequence is the concatenation of the pages its page
-table names. Decode attention is then a gather problem: for slot ``b``,
-stream pages ``page_table[b, i]`` from HBM, dequantize in VMEM, and fold
-each page into an online-softmax accumulator — the bf16 logical cache is
-never materialized and the int8 pages are the only HBM stream (half the
-bytes of an fp16 cache per decoded token, the memory-bound regime where
-KV quantization pays).
+table names. The kernels take each pool leaf whole, every layer stacked
+on its leading axis, as the model's layer loop carries it, and a layer
+index that selects the layer in their index maps, so no layer's pool is
+ever sliced out or relaid. The leaves are stored in the layout the
+kernels read (``scale_lanes``): codes ``(L, n_pages, G, KVH·hd)``, a
+page's rows with their heads side by side on lanes, and scales ``(L,
+n_pages, KVH, lanes)``, a page's rows on the first ``G`` of 128 lanes.
+With a minor dim a multiple of 128 the chip stores either as it is; a
+minor ``hd`` or ``G`` under 128 would make it store the page axis minor
+and relayout every call. A page's scales are one ``(KVH, lanes)`` block,
+which the kernels multiply into the scores (K) and the softmax weights
+(V) of the page's rows rather than into the codes. Decode attention is
+then a gather problem: for slot ``b``, stream pages ``page_table[b, i]``
+from HBM, scale in VMEM, and fold each page into an online-softmax
+accumulator — the bf16 logical cache is never materialized and the int8
+pages are the only HBM stream (half the bytes of an fp16 cache per
+decoded token, the memory-bound regime where KV quantization pays).
 
-The page table and per-slot lengths ride in as **scalar-prefetch**
-operands (``pltpu.PrefetchScalarGridSpec``): they are resident before the
-kernel body runs, so the k/v BlockSpec index maps can address the
-*physical* page ``pt[b, t]`` of a *logical* table entry ``t`` — the
-indirection is free, folded into the DMA descriptor.
+The layer index, page table and per-slot lengths ride in as
+**scalar-prefetch** operands (``pltpu.PrefetchScalarGridSpec``): they
+are resident before the kernel body runs, so the k/v BlockSpec index
+maps can address the *physical* page ``pt[b, t]`` of a *logical* table
+entry ``t`` — the indirection is free, folded into the DMA descriptor.
 
 Grid: one step per **live block** of ``ppb`` table pages, rows one after
 another, so the grid's length is known only from the lengths (a dynamic
@@ -30,9 +41,10 @@ flash-attention running (m, l, acc) across a row's blocks (init at its
 first block, final ``acc / l`` write-out at its last). The ragged last
 page and a tail block's repeated pages are masked by ``kv_pos <
 length[b]`` — garbage rows get ``exp(-1e30 - m) == 0`` weight exactly.
-(Copying the pages by hand from a ``pl.ANY`` pool would need 128-lane
-minor dims: Mosaic refuses the DMA of a ``(G, KVH, hd=64)`` page and of
-any ``(G, KVH, 1)`` scale page.)
+(The pool layout's 128-lane minor dims would also let a page be copied
+by hand from a ``pl.ANY`` leaf: Mosaic compiles that DMA for the ``(G,
+KVH·hd)`` code and ``(KVH, lanes)`` scale pages; it refuses a page whose
+minor dim is not a multiple of 128.)
 
 ``repro.kernels.ref.paged_attention_decode`` is the jnp oracle;
 ``paged_attention_fallback`` is a gather-based jnp path for fp pools and
@@ -72,14 +84,27 @@ _BLOCK_ROWS = 128
 _BLOCK_VMEM_BYTES = 4 << 20
 
 
+def scale_lanes(page_size: int) -> int:
+    """Lanes of a scale leaf's page row: ``page_size`` rounded up to a
+    whole number of 128-lane vregs."""
+    return -(-page_size // 128) * 128
+
+
+def _page_vmem_bytes(page_size: int, kvh: int, hd: int, code_bytes: int,
+                     scale_bytes: int) -> int:
+    """VMEM of one page's code and scale blocks, in padded tiles."""
+    return (tile_bytes(page_size, kvh * hd, code_bytes)
+            + tile_bytes(kvh, scale_lanes(page_size), scale_bytes))
+
+
 def _pages_per_block(page_size: int, kvh: int, hd: int, n_ptab: int,
                      code_bytes: int = 1, scale_bytes: int = 4) -> int:
     """Table pages one decode grid step fetches and computes: about
     ``_BLOCK_ROWS`` KV rows, as many as keep both buffers of K and V
     (codes + scales) within ``_BLOCK_VMEM_BYTES``, never more than the
     table holds."""
-    per_page = 2 * 2 * page_size * (tile_bytes(kvh, hd, code_bytes)
-                                    + tile_bytes(kvh, 1, scale_bytes))
+    per_page = 2 * 2 * _page_vmem_bytes(page_size, kvh, hd, code_bytes,
+                                        scale_bytes)
     return max(1, min(_BLOCK_ROWS // page_size,
                       _BLOCK_VMEM_BYTES // per_page, n_ptab))
 
@@ -102,21 +127,39 @@ def _live_blocks(lengths, page_size: int, n_ptab: int, ppb: int):
     return pages, jnp.maximum((pages + ppb - 1) // ppb, 1)
 
 
-def _page_index(j: int, ppb: int, page_size: int, n_ptab: int, where):
-    """Index map of the ``j``-th page operand: the physical page of table
-    entry ``i * ppb + j`` of the step's table row while that entry is
-    live; past the live context, the last live entry this operand held,
-    so the pipeline sees an unchanged block and fetches nothing. An
-    operand with no live entry in the row holds the row's last live
-    page. ``where(s, *prefetch)`` gives step ``s``'s (page table, table
-    row, live KV rows, block)."""
-    def index(s, *prefetch):
+def _page_specs(j: int, ppb: int, page_size: int, kvh: int, hd: int,
+                lanes: int, n_ptab: int, where) -> tuple:
+    """(codes, scales) BlockSpecs of the ``j``-th page operand: one page
+    of the layer the first scalar-prefetch operand names, the physical
+    page of table entry ``i * ppb + j`` of the step's table row while
+    that entry is live; past the live context, the last live entry this
+    operand held, so the pipeline sees an unchanged block and fetches
+    nothing. An operand with no live entry in the row holds the row's
+    last live page. ``where(s, *prefetch)`` gives step ``s``'s (page
+    table, table row, live KV rows, block)."""
+    def page(s, layer, *prefetch):
         pt, bb, length, i = where(s, *prefetch)
         last = _live_blocks(length, page_size, n_ptab, ppb)[0] - 1
         held = jnp.where(j <= last, j + (last - j) // ppb * ppb,
                          jnp.maximum(last, 0))
-        return (pt[bb, jnp.minimum(i * ppb + j, held)], 0, 0, 0)
-    return index
+        return layer[0], pt[bb, jnp.minimum(i * ppb + j, held)]
+    return (pl.BlockSpec((pl.squeezed, 1, page_size, kvh * hd),
+                         lambda *a: (*page(*a), 0, 0)),
+            pl.BlockSpec((pl.squeezed, 1, kvh, lanes),
+                         lambda *a: (*page(*a), 0, 0)))
+
+
+def _check_pool(k_pages, k_scale, kvh: int, hd: int) -> None:
+    """The pool leaves are in the layout ``scale_lanes`` describes."""
+    lyr, n_pages, page_size, width = k_pages.shape
+    assert width == kvh * hd, (k_pages.shape, kvh, hd)
+    assert k_scale.shape == (lyr, n_pages, kvh, scale_lanes(page_size)), (
+        k_scale.shape, k_pages.shape)
+
+
+def _layer_operand(layer):
+    """The layer index as the (1,) int32 scalar-prefetch operand."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 def _decode_step(s, ln, pt, row, blk):
@@ -124,15 +167,23 @@ def _decode_step(s, ln, pt, row, blk):
     return pt, row[s], ln[row[s]], blk[s]
 
 
-def _dequant(codes, scales):
-    """A block's pages, dequantized, kv head first: (KVH, rows, hd)."""
+def _codes(refs, kvh: int):
+    """A block's pages' codes as f32, kv head first: (KVH, rows, hd) from
+    each head's lanes of the pages' (G, KVH·hd) rows."""
+    rows = jnp.concatenate([c[0].astype(jnp.float32) for c in refs], axis=0)
+    hd = rows.shape[1] // kvh
+    return jnp.stack([rows[:, h * hd:(h + 1) * hd] for h in range(kvh)])
+
+
+def _scales(refs, page_size: int):
+    """A block's pages' scales, kv head first, rows on lanes: (KVH, 1,
+    rows) — a row's K (V) scale multiplies its scores (weights)."""
     return jnp.swapaxes(jnp.concatenate(
-        [c[0].astype(jnp.float32) * sc[0] for c, sc in zip(codes, scales)],
-        axis=0), 0, 1)
+        [sc[..., :page_size] for sc in refs], axis=-1), 0, 1)
 
 
-def _paged_attn_kernel(len_ref, pt_ref, row_ref, blk_ref, q_ref, *refs,
-                       page_size: int, ppb: int):
+def _paged_attn_kernel(layer_ref, len_ref, pt_ref, row_ref, blk_ref, q_ref,
+                       *refs, page_size: int, ppb: int):
     k_refs, ks_refs, v_refs, vs_refs = (refs[n * ppb:(n + 1) * ppb]
                                         for n in range(4))
     o_ref, m_ref, l_ref, acc_ref = refs[4 * ppb:]
@@ -151,14 +202,15 @@ def _paged_attn_kernel(len_ref, pt_ref, row_ref, blk_ref, q_ref, *refs,
     @pl.when(i * rows < length)
     def _block():
         q = q_ref[0].astype(jnp.float32)              # (KVH, g, hd), pre-scaled
-        k = _dequant(k_refs, ks_refs)
-        v = _dequant(v_refs, vs_refs)
+        kvh = q.shape[0]
+        k, v = _codes(k_refs, kvh), _codes(v_refs, kvh)   # (KVH, rows, hd)
 
         # scores for this block, batched over kv heads: (KVH, g, rows);
         # entries past the live context hold a repeated live page and get
         # exactly zero weight
         sc = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                  preferred_element_type=jnp.float32)
+        sc = sc * _scales(ks_refs, page_size)
         kv_pos = i * rows + jax.lax.iota(jnp.int32, rows)
         sc = jnp.where((kv_pos < length)[None, None, :], sc, _NEG_INF)
 
@@ -170,7 +222,8 @@ def _paged_attn_kernel(len_ref, pt_ref, row_ref, blk_ref, q_ref, *refs,
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
         acc_ref[...] = (acc_ref[...] * corr[..., None]
                         + jax.lax.dot_general(
-                            p, v, (((2,), (1,)), ((0,), (0,))),
+                            p * _scales(vs_refs, page_size), v,
+                            (((2,), (1,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32))
 
     @pl.when(i == n_blocks - 1)
@@ -182,14 +235,16 @@ def _paged_attn_kernel(len_ref, pt_ref, row_ref, blk_ref, q_ref, *refs,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
                            k_scale: jnp.ndarray, v_pages: jnp.ndarray,
-                           v_scale: jnp.ndarray, page_table: jnp.ndarray,
-                           lengths: jnp.ndarray,
+                           v_scale: jnp.ndarray, layer,
+                           page_table: jnp.ndarray, lengths: jnp.ndarray,
                            interpret: bool = True) -> jnp.ndarray:
     """Single-token paged decode attention from a quantized page pool.
 
     q           (B, KVH, g, hd)  query heads grouped GQA-style (g = H/KVH)
-    k/v_pages   (n_pages, G, KVH, hd) int8 codes
-    k/v_scale   (n_pages, G, KVH, 1) f32 per-(token, head) scales
+    k/v_pages   (L, n_pages, G, KVH·hd) int8 codes
+    k/v_scale   (L, n_pages, KVH, scale_lanes(G)) f32 per-(token, head)
+                scales, row r of a page on lane r
+    layer       int32 scalar: the layer of the pools to attend over
     page_table  (B, n_ptab) int32 physical page ids (0 = null page for
                 entries beyond the sequence — never fetched)
     lengths     (B,) int32 valid kv rows per slot (the decode token's row
@@ -197,9 +252,9 @@ def paged_attention_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
     -> (B, KVH, g, hd) in q's dtype.
     """
     b, kvh, g, hd = q.shape
-    n_pages, page_size, kvh_p, _ = k_pages.shape
+    page_size, lanes = k_pages.shape[2], k_scale.shape[3]
     n_ptab = page_table.shape[1]
-    assert kvh_p == kvh, (q.shape, k_pages.shape)
+    _check_pool(k_pages, k_scale, kvh, hd)
     assert page_table.shape[0] == b and lengths.shape == (b,)
     ppb = _pages_per_block(page_size, kvh, hd, n_ptab,
                            k_pages.dtype.itemsize, k_scale.dtype.itemsize)
@@ -214,16 +269,15 @@ def paged_attention_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
     blk = steps - (ends - n_blocks)[row]
 
     qs = (q.astype(jnp.float32) * hd ** -0.5).astype(q.dtype)
-    maps = [_page_index(j, ppb, page_size, n_ptab, _decode_step)
-            for j in range(ppb)]
-    codes = [pl.BlockSpec((1, page_size, kvh, hd), m) for m in maps]
-    scales = [pl.BlockSpec((1, page_size, kvh, 1), m) for m in maps]
-    per_row = pl.BlockSpec((1, kvh, g, hd),
-                           lambda s, ln, pt, row, blk: (row[s], 0, 0, 0))
+    codes, scales = zip(*(_page_specs(j, ppb, page_size, kvh, hd, lanes,
+                                      n_ptab, _decode_step)
+                          for j in range(ppb)))
+    per_row = pl.BlockSpec(
+        (1, kvh, g, hd), lambda s, lyr, ln, pt, row, blk: (row[s], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,      # lengths, page_table, row, blk
+        num_scalar_prefetch=5,      # layer, lengths, page_table, row, blk
         grid=(ends[-1],),
-        in_specs=[per_row] + codes + scales + codes + scales,
+        in_specs=[per_row, *codes, *scales, *codes, *scales],
         out_specs=per_row,
         scratch_shapes=[
             pltpu.VMEM((kvh, g), jnp.float32),       # running max
@@ -237,8 +291,9 @@ def paged_attention_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
         name="paged_attention_decode",
         interpret=interpret,
-    )(lengths, page_table, row, blk, qs, *[k_pages] * ppb,
-      *[k_scale] * ppb, *[v_pages] * ppb, *[v_scale] * ppb)
+    )(_layer_operand(layer), lengths, page_table, row, blk, qs,
+      *[k_pages] * ppb, *[k_scale] * ppb, *[v_pages] * ppb,
+      *[v_scale] * ppb)
 
 
 # ------------------------------------------------- ragged (mixed q_len)
@@ -262,8 +317,8 @@ def _ragged_vmem_bytes(kvh: int, tile: int, hd: int, page_size: int,
                 + 4 * tile_bytes(tile, hd, 4)            # q, acc, update
                 + 2 * tile_bytes(tile, rows, 4)          # scores, weights
                 + 2 * tile_bytes(rows, hd, 4))           # K, V
-    pages = 2 * 2 * rows * (tile_bytes(kvh, hd, code_bytes)
-                            + tile_bytes(kvh, 1, scale_bytes))
+    pages = 2 * 2 * ppb * _page_vmem_bytes(page_size, kvh, hd, code_bytes,
+                                           scale_bytes)
     return kvh * per_head + 2 * tile_bytes(tile, 1, 4) + pages
 
 
@@ -352,7 +407,7 @@ def _ragged_grid(rows, lengths, tile: int, page_size: int, n_ptab: int,
     return ends, work, blk, jnp.maximum(stops[-1], 1)
 
 
-def _ragged_attn_kernel(pt_ref, end_ref, work_ref, blk_ref, q_ref,
+def _ragged_attn_kernel(layer_ref, pt_ref, end_ref, work_ref, blk_ref, q_ref,
                         qpos_ref, *refs, page_size: int, ppb: int):
     k_refs, ks_refs, v_refs, vs_refs = (refs[n * ppb:(n + 1) * ppb]
                                         for n in range(4))
@@ -372,12 +427,13 @@ def _ragged_attn_kernel(pt_ref, end_ref, work_ref, blk_ref, q_ref,
     def _block():
         q = q_ref[0].astype(jnp.float32)          # (KVH, tile, hd)
         qpos = qpos_ref[0]                        # (tile, 1) positions
-        k = _dequant(k_refs, ks_refs)             # (KVH, rows, hd)
-        v = _dequant(v_refs, vs_refs)
+        kvh = q.shape[0]
+        k, v = _codes(k_refs, kvh), _codes(v_refs, kvh)   # (KVH, rows, hd)
 
         # scores for this block, batched over kv heads: (KVH, tile, rows)
         sc = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                  preferred_element_type=jnp.float32)
+        sc = sc * _scales(ks_refs, page_size)
         kv_pos = i * rows + jax.lax.broadcasted_iota(
             jnp.int32, (1, rows), 1)
         # per-(query, kv) causal mask: a prefill row at position p sees
@@ -396,7 +452,8 @@ def _ragged_attn_kernel(pt_ref, end_ref, work_ref, blk_ref, q_ref,
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[...] = (acc_ref[...] * corr
                         + jax.lax.dot_general(
-                            p, v, (((2,), (1,)), ((0,), (0,))),
+                            p * _scales(vs_refs, page_size), v,
+                            (((2,), (1,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32))
 
     @pl.when(i == n_blocks - 1)
@@ -413,8 +470,9 @@ def _ragged_step(s, pt, ends, work, blk, *, n_tiles: int):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
                            k_scale: jnp.ndarray, v_pages: jnp.ndarray,
-                           v_scale: jnp.ndarray, page_table: jnp.ndarray,
-                           lengths: jnp.ndarray, q_pos: jnp.ndarray,
+                           v_scale: jnp.ndarray, layer,
+                           page_table: jnp.ndarray, lengths: jnp.ndarray,
+                           q_pos: jnp.ndarray,
                            interpret: bool = True) -> jnp.ndarray:
     """Mixed-q_len paged attention: the q_len=1 decode kernel generalized
     to a *block of queries per sequence*, so one launch serves a unified
@@ -422,8 +480,10 @@ def paged_attention_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
     decode token (or a speculative verify block).
 
     q           (B, Q, KVH, g, hd)  per-item query blocks (right-padded)
-    k/v_pages   (n_pages, G, KVH, hd) int8 codes
-    k/v_scale   (n_pages, G, KVH, 1) f32 per-(token, head) scales
+    k/v_pages   (L, n_pages, G, KVH·hd) int8 codes
+    k/v_scale   (L, n_pages, KVH, scale_lanes(G)) f32 per-(token, head)
+                scales, row r of a page on lane r
+    layer       int32 scalar: the layer of the pools to attend over
     page_table  (B, n_ptab) int32 physical page ids (0 = null page)
     lengths     (B,) int32 valid kv rows per item (last query's pos + 1)
     q_pos       (B, Q) int32 absolute position per query row; -1 marks
@@ -442,9 +502,9 @@ def paged_attention_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
     garbage, like padded rows.
     """
     b, nq, kvh, g, hd = q.shape
-    n_pages, page_size, kvh_p, _ = k_pages.shape
+    page_size, lanes = k_pages.shape[2], k_scale.shape[3]
     n_ptab = page_table.shape[1]
-    assert kvh_p == kvh, (q.shape, k_pages.shape)
+    _check_pool(k_pages, k_scale, kvh, hd)
     assert page_table.shape[0] == b and lengths.shape == (b,)
     assert q_pos.shape == (b, nq), (q_pos.shape, q.shape)
 
@@ -461,20 +521,18 @@ def paged_attention_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
                                           n_ptab, ppb)
 
     where = functools.partial(_ragged_step, n_tiles=n_tiles)
-    maps = [_page_index(j, ppb, page_size, n_ptab, where)
-            for j in range(ppb)]
-    codes = [pl.BlockSpec((1, page_size, kvh, hd), m) for m in maps]
-    scales = [pl.BlockSpec((1, page_size, kvh, 1), m) for m in maps]
+    codes, scales = zip(*(_page_specs(j, ppb, page_size, kvh, hd, lanes,
+                                      n_ptab, where) for j in range(ppb)))
     per_tile = pl.BlockSpec(
         (1, kvh, tile, hd),
-        lambda s, pt, e, w, bk: (w[s] // n_tiles, 0, w[s] % n_tiles, 0))
+        lambda s, lyr, pt, e, w, bk: (w[s] // n_tiles, 0, w[s] % n_tiles, 0))
     pos_tile = pl.BlockSpec(
         (1, tile, 1),
-        lambda s, pt, e, w, bk: (w[s] // n_tiles, w[s] % n_tiles, 0))
+        lambda s, lyr, pt, e, w, bk: (w[s] // n_tiles, w[s] % n_tiles, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,      # page_table, ends, work, blk
+        num_scalar_prefetch=5,      # layer, page_table, ends, work, blk
         grid=(steps,),
-        in_specs=[per_tile, pos_tile] + codes + scales + codes + scales,
+        in_specs=[per_tile, pos_tile, *codes, *scales, *codes, *scales],
         out_specs=per_tile,
         scratch_shapes=[
             pltpu.VMEM((kvh, tile, 1), jnp.float32),     # running max
@@ -493,14 +551,27 @@ def paged_attention_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
             kvh, tile, hd, page_size, ppb, q.dtype.itemsize,
             k_pages.dtype.itemsize, k_scale.dtype.itemsize)),
         interpret=interpret,
-    )(page_table, ends, work, blk, qs, rows[:, :, None], *[k_pages] * ppb,
-      *[k_scale] * ppb, *[v_pages] * ppb, *[v_scale] * ppb)
+    )(_layer_operand(layer), page_table, ends, work, blk, qs,
+      rows[:, :, None], *[k_pages] * ppb, *[k_scale] * ppb,
+      *[v_pages] * ppb, *[v_scale] * ppb)
     out = out[:, :, :qg].reshape(b, kvh, nq, g, hd)
     return out.transpose(0, 2, 1, 3, 4)
 
 
+def _logical(pages, scale, layer, page_table, hd: int):
+    """One layer's logical (B, n_ptab·G, KVH, hd) f32 view of the pool
+    leaves through ``page_table`` (``models.layers.gather_pages``): codes
+    times their per-row scales, or an fp pool as it is where ``scale``
+    is None."""
+    from repro.models.layers import gather_pages, gather_scales
+    view = gather_pages(pages, page_table, layer, hd).astype(jnp.float32)
+    if scale is None:
+        return view
+    return view * gather_scales(scale, page_table, layer, pages.shape[2])
+
+
 def paged_attention_ragged_fallback(q: jnp.ndarray, k_pages, k_scale,
-                                    v_pages, v_scale,
+                                    v_pages, v_scale, layer,
                                     page_table: jnp.ndarray,
                                     lengths: jnp.ndarray,
                                     q_pos: jnp.ndarray) -> jnp.ndarray:
@@ -510,19 +581,10 @@ def paged_attention_ragged_fallback(q: jnp.ndarray, k_pages, k_scale,
     masked softmax in f32. Also serves fp pools: pass ``k_scale``/
     ``v_scale`` as ``None`` and fp ``*_pages``.
     """
-    b, nq, kvh, g, hd = q.shape
-    page_size = k_pages.shape[1]
-
-    def logical(pages, scale):
-        view = pages[page_table].reshape(b, -1, kvh, hd)  # (B, S, KVH, hd)
-        if scale is None:
-            return view.astype(jnp.float32)
-        sc = scale[page_table].reshape(b, -1, kvh, 1)
-        return view.astype(jnp.float32) * sc
-
-    k = logical(k_pages, k_scale)
-    v = logical(v_pages, v_scale)
-    skv = page_table.shape[1] * page_size
+    hd = q.shape[-1]
+    k = _logical(k_pages, k_scale, layer, page_table, hd)
+    v = _logical(v_pages, v_scale, layer, page_table, hd)
+    skv = k.shape[1]
     qf = q.astype(jnp.float32) * hd ** -0.5
     s = jnp.einsum("bqkgd,bskd->bqkgs", qf, k)
     kv = jnp.arange(skv, dtype=jnp.int32)
@@ -536,26 +598,17 @@ def paged_attention_ragged_fallback(q: jnp.ndarray, k_pages, k_scale,
 
 
 def paged_attention_fallback(q: jnp.ndarray, k_pages, k_scale, v_pages,
-                             v_scale, page_table: jnp.ndarray,
+                             v_scale, layer, page_table: jnp.ndarray,
                              lengths: jnp.ndarray) -> jnp.ndarray:
     """Pure-jnp paged decode attention (same contract as the kernel).
 
     Gathers the logical view and runs a masked softmax in f32. Also serves
     fp pools: pass ``k_scale``/``v_scale`` as ``None`` and fp ``*_pages``.
     """
-    b, kvh, g, hd = q.shape
-    page_size = k_pages.shape[1]
-
-    def logical(pages, scale):
-        view = pages[page_table].reshape(b, -1, kvh, hd)  # (B, S, KVH, hd)
-        if scale is None:
-            return view.astype(jnp.float32)
-        sc = scale[page_table].reshape(b, -1, kvh, 1)
-        return view.astype(jnp.float32) * sc
-
-    k = logical(k_pages, k_scale)
-    v = logical(v_pages, v_scale)
-    skv = page_table.shape[1] * page_size
+    hd = q.shape[-1]
+    k = _logical(k_pages, k_scale, layer, page_table, hd)
+    v = _logical(v_pages, v_scale, layer, page_table, hd)
+    skv = k.shape[1]
     qf = q.astype(jnp.float32) * hd ** -0.5
     s = jnp.einsum("bkgd,bskd->bkgs", qf, k)
     mask = jnp.arange(skv, dtype=jnp.int32)[None, :] < lengths[:, None]

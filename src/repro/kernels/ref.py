@@ -101,14 +101,16 @@ def quant_gemv_w4(qx: jnp.ndarray, sx: jnp.ndarray, zpx: jnp.ndarray,
 
 
 def paged_attention_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
-                           k_scale, v_pages: jnp.ndarray, v_scale,
+                           k_scale, v_pages: jnp.ndarray, v_scale, layer,
                            page_table: jnp.ndarray,
                            lengths: jnp.ndarray) -> jnp.ndarray:
     """Paged decode-attention oracle (mirrors kernels.paged_attention).
 
-    q (B, KVH, g, hd); k/v_pages (n_pages, G, KVH, hd) int8 codes (or fp
-    when the matching scale is None); k/v_scale (n_pages, G, KVH, 1) f32;
-    page_table (B, n_ptab) int32; lengths (B,) valid kv rows per slot.
+    q (B, KVH, g, hd); k/v_pages (L, n_pages, G, KVH·hd) int8 codes (or
+    fp when the matching scale is None); k/v_scale (L, n_pages, KVH,
+    lanes) f32 (``kernels.paged_attention.scale_lanes``); layer the
+    int32 layer index; page_table (B, n_ptab) int32;
+    lengths (B,) valid kv rows per slot.
     Gathers each slot's logical sequence, dequantizes, and runs a masked
     f32 softmax — positions >= lengths[b] (ragged last pages, null-page
     entries) get exactly zero weight.
@@ -119,11 +121,11 @@ def paged_attention_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
     this."""
     from repro.kernels.paged_attention import paged_attention_fallback
     return paged_attention_fallback(q, k_pages, k_scale, v_pages, v_scale,
-                                    page_table, lengths)
+                                    layer, page_table, lengths)
 
 
 def paged_attention_ragged(q: jnp.ndarray, k_pages, k_scale,
-                           v_pages: jnp.ndarray, v_scale,
+                           v_pages: jnp.ndarray, v_scale, layer,
                            page_table: jnp.ndarray, lengths: jnp.ndarray,
                            q_pos: jnp.ndarray) -> jnp.ndarray:
     """Ragged (mixed q_len) paged-attention oracle.
@@ -139,8 +141,8 @@ def paged_attention_ragged(q: jnp.ndarray, k_pages, k_scale,
     online-softmax reformulation is what gets validated against this."""
     from repro.kernels.paged_attention import paged_attention_ragged_fallback
     return paged_attention_ragged_fallback(q, k_pages, k_scale, v_pages,
-                                           v_scale, page_table, lengths,
-                                           q_pos)
+                                           v_scale, layer, page_table,
+                                           lengths, q_pos)
 
 
 def block_diag_matmul(x: jnp.ndarray, blocks: jnp.ndarray) -> jnp.ndarray:
@@ -184,7 +186,7 @@ def kernel_transform_quant(x, blocks, ha, hb, sign, *, act_bits: int = 8):
 
 
 def decode_qkv_prologue(x, blocks, ha, hb, sign, qw, sw,
-                        k_pool, k_scale, v_pool, v_scale,
+                        k_pool, k_scale, v_pool, v_scale, layer,
                         page_ids, row_ids, positions, *,
                         n_q: int, head_dim: int, rope_theta: float,
                         kv_bits: int = 8, act_bits: int = 8,
@@ -195,15 +197,17 @@ def decode_qkv_prologue(x, blocks, ha, hb, sign, qw, sw,
 
     Composes ``kernel_transform_quant`` (kernel op order) + the exact
     int32 ``quant_matmul`` + ``models.layers.rope`` + ``quantize_kv`` +
-    the ``_write_kv_paged`` scatter. Agreement with the kernel is rtol
-    ~1e-6 on the f32 outputs (XLA FMA-contracts the fused mul/sub chains
-    inside the jitted launch; this eager composition keeps them
-    separate) while the scattered int8 KV codes round identically and
-    match bitwise. The kernel additionally parks padded batch rows and
-    intermediate flushes on the null page — page 0 is outside the
-    contract and excluded from comparison.
+    the paged scatter (``_put_code_rows``, ``_put_scale_rows``) into
+    layer ``layer`` of the stacked pool leaves. Agreement with the
+    kernel is rtol ~1e-6 on the f32 outputs (XLA FMA-contracts the
+    fused mul/sub chains inside the jitted launch; this eager
+    composition keeps them separate) while the scattered int8 KV codes
+    round identically and match bitwise. The kernel additionally parks
+    padded batch rows and intermediate flushes on the null page — page 0
+    is outside the contract and excluded from comparison.
     """
-    from repro.models.layers import quantize_kv, rope
+    from repro.models.layers import (_put_code_rows, _put_scale_rows,
+                                     quantize_kv, rope)
 
     m, _ = x.shape
     n = qw.shape[1]
@@ -221,13 +225,11 @@ def decode_qkv_prologue(x, blocks, ha, hb, sign, qw, sw,
     v = y[:, n_q + n_kv:].reshape(m, kvh, head_dim)
     kq, ks = quantize_kv(k, bits=kv_bits)
     vq, vs = quantize_kv(v, bits=kv_bits)
-    pids = page_ids.astype(jnp.int32)
-    rows = row_ids.astype(jnp.int32)
-    k_pool = k_pool.at[pids, rows].set(kq, mode="drop")
-    k_scale = k_scale.at[pids, rows].set(ks, mode="drop")
-    v_pool = v_pool.at[pids, rows].set(vq, mode="drop")
-    v_scale = v_scale.at[pids, rows].set(vs, mode="drop")
-    return q, k_pool, k_scale, v_pool, v_scale
+    at = (layer, page_ids.astype(jnp.int32), row_ids.astype(jnp.int32))
+    return (q, _put_code_rows(k_pool, kq, *at),
+            _put_scale_rows(k_scale, ks, *at),
+            _put_code_rows(v_pool, vq, *at),
+            _put_scale_rows(v_scale, vs, *at))
 
 
 def fused_cat_matmul_w4(x, blocks, ha, hb, sign, qw, sw, *,

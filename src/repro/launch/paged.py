@@ -7,8 +7,9 @@ Layout contract (shared with ``models.layers`` and
 ``kernels.paged_attention``):
 
 - The pool holds ``n_pages`` pages of ``page_size`` token rows each,
-  per layer: leaves are (L, n_pages, page_size, KV, hd) codes plus
-  congruent per-token scale leaves when the cache is quantized.
+  per layer: leaves are (L, n_pages, page_size, KV·hd) codes plus (L,
+  n_pages, KV, lanes) per-token scale leaves when the cache is
+  quantized, row r of a page on lane r (``init_paged_cache``).
 - **Page 0 is the null page** — never allocated. Page-table entries
   default to 0, so dummy writes (free decode slots, padded prefill rows
   past a slot's table) land there inertly, and dummy reads are causally

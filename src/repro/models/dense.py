@@ -89,7 +89,7 @@ def init(cfg, rng) -> dict:
 
 # ----------------------------------------------------------------- forward
 
-def _fused_decode_operands(cfg, lp, cache_sl, s, b, tp_axis, ragged_desc,
+def _fused_decode_operands(cfg, lp, cache, s, b, tp_axis, ragged_desc,
                            page_table, paged_kernel):
     """Two-launch decode gate: returns (wqkv QLinear, (blocks, ha, hb,
     sign)) when this layer's attention block can run the one-launch QKV
@@ -106,7 +106,7 @@ def _fused_decode_operands(cfg, lp, cache_sl, s, b, tp_axis, ragged_desc,
     """
     if not (paged_kernel and s == 1 and ragged_desc is None
             and tp_axis is None and page_table is not None
-            and cache_sl is not None and "k_scale" in cache_sl
+            and cache is not None and "k_scale" in cache
             and bool(cfg.kv_quant_bits) and b <= 8
             and not cfg.window and not cfg.attn_softcap
             and not cfg.qk_norm):
@@ -123,54 +123,55 @@ def _fused_decode_operands(cfg, lp, cache_sl, s, b, tp_axis, ragged_desc,
     return p, dec
 
 
-def _fused_decode_attn(cfg, fd, h, cache_sl, page_table, pos, b):
+def _fused_decode_attn(cfg, fd, h, cache, layer, page_table, pos, b):
     """The two-launch decode attention block: ONE prologue launch (CAT ->
-    quant -> W4A8 QKV GEMV -> RoPE -> int8 KV quant -> paged scatter)
-    feeding ONE paged-attention launch. Returns (o (B, 1, Hq·hd),
-    new_cache_sl)."""
+    quant -> W4A8 QKV GEMV -> RoPE -> int8 KV quant -> paged scatter into
+    layer ``layer`` of the stacked pools) feeding ONE paged-attention
+    launch over that layer. Returns (o (B, 1, Hq·hd), new_cache)."""
     from repro.kernels import ops
     from repro.models.layers import _paged_indices
 
     p, (blocks, ha, hb, sign) = fd
     cd = h.dtype
-    page_size = cache_sl["k"].shape[1]
+    page_size = cache["k"].shape[2]
     pos_vec = (pos if getattr(pos, "ndim", 0)
                else jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,)))
     pids, rows = _paged_indices(page_table, pos_vec, b, 1, page_size)
     q, ck, cks, cv, cvs = ops.decode_qkv_prologue(
         h.reshape(b, -1), blocks, ha, hb, sign, p.qweight, p.scale,
-        cache_sl["k"], cache_sl["k_scale"], cache_sl["v"],
-        cache_sl["v_scale"], pids, rows, pos_vec,
+        cache["k"], cache["k_scale"], cache["v"], cache["v_scale"], layer,
+        pids, rows, pos_vec,
         n_q=cfg.q_dim, head_dim=cfg.head_dim,
         rope_theta=float(cfg.rope_theta), kv_bits=cfg.kv_quant_bits,
         act_bits=p.act_bits, packed=p.packed)
-    new_cache_sl = {"k": ck, "k_scale": cks, "v": cv, "v_scale": cvs}
-    kvh = ck.shape[2]
+    new_cache = {"k": ck, "k_scale": cks, "v": cv, "v_scale": cvs}
+    kvh = ck.shape[3] // cfg.head_dim
     g = cfg.q_dim // cfg.head_dim // kvh
     qk = q.astype(cd).reshape(b, kvh, g, cfg.head_dim)
     lengths = pos_vec + 1
-    o = ops.paged_attention(qk, ck, cks, cv, cvs, page_table,
+    o = ops.paged_attention(qk, ck, cks, cv, cvs, layer, page_table,
                             lengths.astype(jnp.int32))
-    return o.reshape(b, 1, -1).astype(cd), new_cache_sl
+    return o.reshape(b, 1, -1).astype(cd), new_cache
 
 
-def _layer_body(cfg, x, lp, cache_sl, is_global, pos, positions,
+def _layer_body(cfg, x, lp, cache, is_global, pos, positions,
                 taps=None, layer_idx=None, tp_axis=None,
                 tp_mode: str = "gather", tp_kernels=False,
                 page_table=None, paged_kernel: bool = False,
                 ragged_desc=None):
-    """cache_sl: per-layer cache slices dict ({"k","v"[,"k_scale","v_scale"]})
-    or None. Returns (x, new_cache_sl, aux).
+    """cache: the whole cache dict ({"k","v"[,"k_scale","v_scale"]}), every
+    layer stacked on the leading axis, or None; the layer reads and
+    writes its own layer, ``layer_idx`` (a traced int32 in the layer
+    scan). Returns (x, new_cache, aux).
 
-    With ``page_table`` (B, n_ptab) the cache slices are *page pools*
-    ((n_pages, page_size, KV, hd) per layer, plus congruent per-token
-    scale pools when quantized): k/v writes scatter to the physical rows
-    the table maps [pos, pos+S) to, and attention reads the gathered
-    logical view — identical content and shape to the contiguous slot
-    cache, so decoded tokens stay bitwise the same. ``paged_kernel``
-    additionally routes single-token (decode) attention on quantized
-    pools through the Pallas paged-attention kernel (streams int8 pages,
-    dequantizes in VMEM — rtol-level, not bitwise).
+    With ``page_table`` (B, n_ptab) the cache leaves are *page pools*
+    (``init_paged_cache``): k/v writes scatter to the physical rows the
+    table maps [pos, pos+S) to in layer ``layer_idx``, and attention
+    reads the gathered logical view — identical content and shape to the
+    contiguous slot cache, so decoded tokens stay bitwise the same.
+    ``paged_kernel`` additionally routes single-token (decode) attention
+    on quantized pools through the Pallas paged-attention kernel
+    (streams int8 pages, scales in VMEM — rtol-level, not bitwise).
 
     With ``tp_axis`` the body runs INSIDE shard_map on a tensor-parallel
     mesh axis: wq/wk/wv/wg/wu arrive column-sharded (whole local heads /
@@ -196,7 +197,7 @@ def _layer_body(cfg, x, lp, cache_sl, is_global, pos, positions,
 
     h = rms_norm(x, lp["ln1"])
     _tap(taps, layer_idx, "attn_in", h)
-    fd = _fused_decode_operands(cfg, lp, cache_sl, s, b, tp_axis,
+    fd = _fused_decode_operands(cfg, lp, cache, s, b, tp_axis,
                                 ragged_desc, page_table, paged_kernel)
     if fd is not None:
         # two-launch decode: the QKV prologue kernel replaces the dense
@@ -207,8 +208,8 @@ def _layer_body(cfg, x, lp, cache_sl, is_global, pos, positions,
         # documented route-2/route-3 gap; gating defaults off outside
         # TPU (REPRO_DECODE_FUSED overrides) so stock CPU runs keep the
         # composed path bitwise.
-        o, new_cache_sl = _fused_decode_attn(cfg, fd, h, cache_sl,
-                                             page_table, pos, b)
+        o, new_cache = _fused_decode_attn(cfg, fd, h, cache, layer_idx,
+                                          page_table, pos, b)
     else:
         if "wqkv" in lp:
             # fused serving params (make_serving_params): one concatenated
@@ -238,8 +239,8 @@ def _layer_body(cfg, x, lp, cache_sl, is_global, pos, positions,
             window = jnp.where(is_global, jnp.int32(2**30),
                                jnp.int32(cfg.window))
 
-        quant_cache = bool(cfg.kv_quant_bits) and cache_sl is not None \
-            and "k_scale" in cache_sl
+        quant_cache = bool(cfg.kv_quant_bits) and cache is not None \
+            and "k_scale" in cache
         if cfg.kv_quant_bits and not quant_cache:
             # no cache (training fwd): simulate KV quantization numerics
             from repro.core.quantizers import QuantSpec, fake_quant
@@ -248,19 +249,19 @@ def _layer_body(cfg, x, lp, cache_sl, is_global, pos, positions,
             k = fake_quant(k, kv_spec)
             v = fake_quant(v, kv_spec)
 
-        new_cache_sl = None
+        new_cache = None
         o = None
-        if cache_sl is not None and page_table is not None:
-            from repro.models.layers import (gather_pages,
+        if cache is not None and page_table is not None:
+            from repro.models.layers import (gather_pages, gather_scales,
                                              paged_cache_update,
                                              paged_cache_update_quantized)
             if quant_cache:
                 ck, cks, cv, cvs = paged_cache_update_quantized(
-                    cache_sl["k"], cache_sl["k_scale"], cache_sl["v"],
-                    cache_sl["v_scale"], k, v, page_table, pos,
+                    cache["k"], cache["k_scale"], cache["v"],
+                    cache["v_scale"], k, v, page_table, pos, layer_idx,
                     cfg.kv_quant_bits)
-                new_cache_sl = {"k": ck, "k_scale": cks, "v": cv,
-                                "v_scale": cvs}
+                new_cache = {"k": ck, "k_scale": cks, "v": cv,
+                             "v_scale": cvs}
                 use_kernel = (paged_kernel and s == 1 and window is None
                               and not cfg.attn_softcap)
                 if use_kernel and ragged_desc is not None:
@@ -269,11 +270,11 @@ def _layer_body(cfg, x, lp, cache_sl, is_global, pos, positions,
                     # pages stream ONCE for all its prefill-chunk +
                     # decode queries (one launch for the mixed batch)
                     from repro.kernels import ops
-                    kvh = ck.shape[2]
+                    kvh = ck.shape[3] // cfg.head_dim
                     qf = q.reshape(b, kvh, q.shape[2] // kvh, cfg.head_dim)
                     qb = qf[ragged_desc["qidx"]]     # (R, Q, KVH, g, hd)
                     ob = ops.ragged_paged_attention(
-                        qb, ck, cks, cv, cvs, ragged_desc["table"],
+                        qb, ck, cks, cv, cvs, layer_idx, ragged_desc["table"],
                         ragged_desc["lengths"].astype(jnp.int32),
                         ragged_desc["qpos"].astype(jnp.int32))
                     o = ob[ragged_desc["inv_seq"], ragged_desc["inv_qi"]]
@@ -282,37 +283,44 @@ def _layer_body(cfg, x, lp, cache_sl, is_global, pos, positions,
                     # decode fast path: stream int8 pages, dequant in
                     # VMEM (rtol-level vs the gathered view, not bitwise)
                     from repro.kernels import ops
-                    kvh = ck.shape[2]
+                    kvh = ck.shape[3] // cfg.head_dim
                     qk = q.reshape(b, kvh, q.shape[2] // kvh, cfg.head_dim)
                     lengths = (pos if getattr(pos, "ndim", 0)
                                else jnp.broadcast_to(pos, (b,))) + 1
-                    o = ops.paged_attention(qk, ck, cks, cv, cvs,
+                    o = ops.paged_attention(qk, ck, cks, cv, cvs, layer_idx,
                                             page_table,
                                             lengths.astype(jnp.int32))
                     o = o.reshape(b, 1, -1)
                 else:
-                    k_att = (gather_pages(ck, page_table),
-                             gather_pages(cks, page_table))
-                    v_att = (gather_pages(cv, page_table),
-                             gather_pages(cvs, page_table))
+                    at = (page_table, layer_idx)
+                    page = ck.shape[2]
+                    k_att = (gather_pages(ck, *at, cfg.head_dim),
+                             gather_scales(cks, *at, page))
+                    v_att = (gather_pages(cv, *at, cfg.head_dim),
+                             gather_scales(cvs, *at, page))
             else:
-                ck, cv = paged_cache_update(cache_sl["k"], cache_sl["v"],
-                                            k, v, page_table, pos)
-                new_cache_sl = {"k": ck, "v": cv}
-                k_att = gather_pages(ck, page_table).astype(cd)
-                v_att = gather_pages(cv, page_table).astype(cd)
-        elif cache_sl is not None and quant_cache:
-            from repro.models.layers import cache_update_quantized
-            ck, cks, cv, cvs = cache_update_quantized(
-                cache_sl["k"], cache_sl["k_scale"], cache_sl["v"],
-                cache_sl["v_scale"], k, v, pos, cfg.kv_quant_bits)
-            new_cache_sl = {"k": ck, "k_scale": cks, "v": cv,
-                            "v_scale": cvs}
-            k_att, v_att = (ck, cks), (cv, cvs)
-        elif cache_sl is not None:
-            ck, cv = cache_update(cache_sl["k"], cache_sl["v"], k, v, pos)
-            new_cache_sl = {"k": ck, "v": cv}
-            k_att, v_att = ck.astype(cd), cv.astype(cd)
+                ck, cv = paged_cache_update(cache["k"], cache["v"], k, v,
+                                            page_table, pos, layer_idx)
+                new_cache = {"k": ck, "v": cv}
+                at = (page_table, layer_idx, cfg.head_dim)
+                k_att = gather_pages(ck, *at).astype(cd)
+                v_att = gather_pages(cv, *at).astype(cd)
+        elif cache is not None:
+            # contiguous slot cache: update this layer's slice, put it back
+            sl = {n: a[layer_idx] for n, a in cache.items()}
+            if quant_cache:
+                from repro.models.layers import cache_update_quantized
+                ck, cks, cv, cvs = cache_update_quantized(
+                    sl["k"], sl["k_scale"], sl["v"], sl["v_scale"], k, v,
+                    pos, cfg.kv_quant_bits)
+                sl = {"k": ck, "k_scale": cks, "v": cv, "v_scale": cvs}
+                k_att, v_att = (ck, cks), (cv, cvs)
+            else:
+                ck, cv = cache_update(sl["k"], sl["v"], k, v, pos)
+                sl = {"k": ck, "v": cv}
+                k_att, v_att = ck.astype(cd), cv.astype(cd)
+            new_cache = {n: cache[n].at[layer_idx].set(a)
+                         for n, a in sl.items()}
         else:
             k_att, v_att = k, v
 
@@ -353,7 +361,7 @@ def _layer_body(cfg, x, lp, cache_sl, is_global, pos, positions,
     if cfg.act_shard == "seq":
         from repro.distributed.act_sharding import constrain_seq
         x = constrain_seq(x)
-    return x, new_cache_sl, aux
+    return x, new_cache, aux
 
 
 def _tap(taps, layer_idx, name, x):
@@ -369,8 +377,8 @@ def forward(cfg, params, tokens, *, extra_embed=None, cache=None,
     ``extra_embed`` (B, P, D) is prepended (vlm prefix); with ``cache`` the
     attention runs against the cache and writes k/v at cache['pos'].
 
-    A cache carrying a ``page_table`` leaf is *paged*: its k/v leaves are
-    page pools (L, n_pages, page_size, KV, hd) shared across slots, and
+    A cache carrying a ``page_table`` leaf is *paged*: its leaves are
+    the page pools of ``init_paged_cache``, shared across slots, and
     the table ((B, n_ptab) int32) maps each row's logical positions to
     physical pages (see ``init_paged_cache`` / ``models.layers``).
     ``paged_kernel`` opts decode steps into the Pallas paged-attention
@@ -410,56 +418,42 @@ def forward(cfg, params, tokens, *, extra_embed=None, cache=None,
         cache_layers = {k: v for k, v in cache.items()
                         if k not in ("pos", "page_table")}
 
-    aux0 = jnp.zeros((), jnp.float32)
+    # The cache rides the layer loop whole, as one buffer per leaf: each
+    # layer reads and writes its own layer of it in place (the paged
+    # kernels take the stacked pools and the layer index), so no layer's
+    # pool is sliced out, relaid or stacked back.
+    body_kw = dict(tp_axis=tp_axis, tp_mode=tp_mode, tp_kernels=tp_kernels,
+                   page_table=page_table, paged_kernel=paged_kernel,
+                   ragged_desc=ragged_desc)
+    aux = jnp.zeros((), jnp.float32)
     if unroll:
-        new_sl = []
-        aux = aux0
         for i in range(cfg.n_layers):
             lp = jax.tree.map(lambda a: a[i], params["layers"])
-            csl = (jax.tree.map(lambda a: a[i], cache_layers)
-                   if cache_layers is not None else None)
-            x, csl, a = _layer_body(cfg, x, lp, csl, flags[i], pos,
-                                    positions, taps=taps, layer_idx=i,
-                                    tp_axis=tp_axis, tp_mode=tp_mode,
-                                    tp_kernels=tp_kernels,
-                                    page_table=page_table,
-                                    paged_kernel=paged_kernel,
-                                    ragged_desc=ragged_desc)
+            x, cache_layers, a = _layer_body(cfg, x, lp, cache_layers,
+                                             flags[i], pos, positions,
+                                             taps=taps, layer_idx=i,
+                                             **body_kw)
             aux = aux + a
-            if csl is not None:
-                new_sl.append(csl)
-        new_cache = None
-        if cache is not None:
-            stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *new_sl)
-            new_cache = dict(stacked, pos=pos + s)
     else:
         def body(carry, xs):
-            x, aux = carry
-            if cache_layers is not None:
-                lp, csl, fl = xs
-            else:
-                (lp, fl), csl = xs, None
-            x, csl, a = _layer_body(cfg, x, lp, csl, fl, pos, positions,
-                                    tp_axis=tp_axis, tp_mode=tp_mode,
-                                    tp_kernels=tp_kernels,
-                                    page_table=page_table,
-                                    paged_kernel=paged_kernel,
-                                    ragged_desc=ragged_desc)
-            return (x, aux + a), csl
+            x, aux, cache_layers = carry
+            lp, fl, i = xs
+            x, cache_layers, a = _layer_body(cfg, x, lp, cache_layers, fl,
+                                             pos, positions, layer_idx=i,
+                                             **body_kw)
+            return (x, aux + a, cache_layers), None
 
         if cfg.remat:
             body = jax.checkpoint(body)
-        if cache_layers is not None:
-            xs = (params["layers"], cache_layers, flags)
-        else:
-            xs = (params["layers"], flags)
         from repro.models.flags import scan as _scan
-        (x, aux), ys = _scan(body, (x, aux0), xs)
-        new_cache = None
-        if cache is not None:
-            new_cache = dict(ys, pos=pos + s)
-    if new_cache is not None and page_table is not None:
-        new_cache["page_table"] = page_table
+        xs = (params["layers"], flags,
+              jnp.arange(cfg.n_layers, dtype=jnp.int32))
+        (x, aux, cache_layers), _ = _scan(body, (x, aux, cache_layers), xs)
+    new_cache = None
+    if cache is not None:
+        new_cache = dict(cache_layers, pos=pos + s)
+        if page_table is not None:
+            new_cache["page_table"] = page_table
 
     x = rms_norm(x, params["final_norm"])
     return x, aux, new_cache
@@ -550,15 +544,21 @@ def init_cache(cfg, batch_size: int, max_len: int) -> dict:
 
 
 def init_paged_cache(cfg, n_pages: int, page_size: int) -> dict:
-    """Global paged KV pool: (L, n_pages, page_size, KV, hd) codes (+
-    congruent per-token scale pools when quantized). No ``pos`` — page
-    tables and per-slot lengths are the caller's (engine's) bookkeeping;
-    page 0 is conventionally the never-owned null page (see
+    """Global paged KV pool, stored in the layout the Pallas kernels read
+    (``kernels/paged_attention.py``): (L, n_pages, page_size, KV·hd)
+    codes, a row's heads side by side on lanes, and when quantized (L,
+    n_pages, KV, scale_lanes(page_size)) f32 per-(token, head) scales,
+    row r of a page on lane r. No ``pos`` — page tables and per-slot
+    lengths are the caller's (engine's) bookkeeping; page 0 is
+    conventionally the never-owned null page (see
     ``repro.launch.paged.PagePool``)."""
-    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+    from repro.kernels.paged_attention import scale_lanes
+
+    shape = (cfg.n_layers, n_pages, page_size, cfg.kv_dim)
     cd = _compute_dtype(cfg)
     if cfg.kv_quant_bits:
-        sshape = shape[:-1] + (1,)
+        sshape = (cfg.n_layers, n_pages, cfg.n_kv_heads,
+                  scale_lanes(page_size))
         return {"k": jnp.zeros(shape, jnp.int8),
                 "k_scale": jnp.zeros(sshape, jnp.float32),
                 "v": jnp.zeros(shape, jnp.int8),

@@ -237,55 +237,78 @@ def _paged_indices(page_table, pos, b, s, page_size):
     return pids.reshape(-1), rows.reshape(-1)
 
 
-def _write_kv_paged(pool, x, page_table, pos):
-    """Scatter x (B, S, KV, d) into pool (n_pages, G, KV, d) at the pages
-    ``page_table`` (B, n_ptab) names for logical rows [pos, pos+S).
+def _put_code_rows(pool, vals, layer, pids, rows):
+    """Write (..., KV, d) rows, one per flat (N,) (page, row) target,
+    into layer ``layer`` of a stacked code (or fp) pool leaf (L,
+    n_pages, G, KV·d); targets past the pool drop."""
+    vals = vals.reshape(pids.shape[0], -1).astype(pool.dtype)
+    return pool.at[layer, pids, rows].set(vals, mode="drop")
+
+
+def _put_scale_rows(pool, vals, layer, pids, rows):
+    """Write (..., KV, 1) per-row scales, one per flat (N,) (page, row)
+    target, into layer ``layer`` of a stacked scale leaf (L, n_pages, KV,
+    lanes), row r of a page on lane r; targets past the pool drop."""
+    vals = vals.reshape(pids.shape[0], -1).astype(pool.dtype)
+    return pool.at[layer, pids, :, rows].set(vals, mode="drop")
+
+
+def _page_targets(page_table, pos, x, pool):
+    """Flat (page, row) targets of x's (B, S) tokens in a code leaf."""
+    return _paged_indices(page_table, pos, x.shape[0], x.shape[1],
+                          pool.shape[2])
+
+
+def paged_cache_update(ck, cv, k, v, page_table, pos, layer):
+    """fp paged write: k, v (B, S, KV, hd) into layer ``layer`` of the
+    stacked (L, n_pages, G, KV·hd) pools at the rows the page table
+    maps [pos, pos+S) to.
 
     Distinct slots own distinct pages, so real writes never collide; the
     only duplicate targets are inert null-page rows (see _paged_indices).
     """
-    b, s = x.shape[:2]
-    pids, rows = _paged_indices(page_table, pos, b, s, pool.shape[1])
-    vals = x.reshape((b * s,) + x.shape[2:]).astype(pool.dtype)
-    return pool.at[pids, rows].set(vals, mode="drop")
-
-
-def paged_cache_update(ck, cv, k, v, page_table, pos):
-    """fp paged write: k, v (B, S, KV, hd) into (n_pages, G, KV, hd)
-    pools at the rows the page table maps [pos, pos+S) to."""
-    return (_write_kv_paged(ck, k, page_table, pos),
-            _write_kv_paged(cv, v, page_table, pos))
+    at = (layer, *_page_targets(page_table, pos, k, ck))
+    return _put_code_rows(ck, k, *at), _put_code_rows(cv, v, *at)
 
 
 def paged_cache_update_quantized(ck, cks, cv, cvs, k, v, page_table, pos,
-                                 bits: int):
+                                 layer, bits: int):
     """int8 paged write: same quantizer as the contiguous cache
-    (``quantize_kv``), codes + per-token scales scattered page-wise —
-    the stored values are bitwise identical to the slot cache's."""
+    (``quantize_kv``), codes + per-token scales scattered page-wise into
+    layer ``layer`` of the stacked pools — the stored values are bitwise
+    identical to the slot cache's."""
     kq, ks = quantize_kv(k, bits)
     vq, vs = quantize_kv(v, bits)
-    return (_write_kv_paged(ck, kq, page_table, pos),
-            _write_kv_paged(cks, ks, page_table, pos),
-            _write_kv_paged(cv, vq, page_table, pos),
-            _write_kv_paged(cvs, vs, page_table, pos))
+    at = (layer, *_page_targets(page_table, pos, k, ck))
+    return (_put_code_rows(ck, kq, *at), _put_scale_rows(cks, ks, *at),
+            _put_code_rows(cv, vq, *at), _put_scale_rows(cvs, vs, *at))
 
 
 def copy_pool_pages(pool, src, dst):
     """Copy whole pages ``src`` -> ``dst`` along a pool leaf's page axis
-    (axis 1: leaves are (L, n_pages, page_size, ...)). The prefix cache's
-    copy-on-write split: duplicate a shared page's rows into a private
-    replacement before the new owner writes its divergent rows. ``src``/
-    ``dst`` are (C,) int32; padding pairs are (0, 0) — a null-page
-    self-copy is a no-op write — so the copy keeps one compile shape."""
+    (axis 1 of every leaf: codes (L, n_pages, G, KV·hd) and scales (L,
+    n_pages, KV, lanes) alike). The prefix cache's copy-on-write split:
+    duplicate a shared page's rows into a private replacement before the
+    new owner writes its divergent rows. ``src``/``dst`` are (C,) int32;
+    padding pairs are (0, 0) — a null-page self-copy is a no-op write —
+    so the copy keeps one compile shape."""
     return pool.at[:, dst].set(pool[:, src])
 
 
-def gather_pages(pool, page_table):
-    """(n_pages, G, KV, d) pool + (B, n_ptab) table -> the logical
-    (B, n_ptab*G, KV, d) view — identical (content and shape) to the
-    contiguous slot cache over written rows, so downstream attention is
-    bitwise the same; unwritten/null rows are finite garbage masked by
-    the causal test."""
-    b, n_ptab = page_table.shape
-    g = pool.shape[1]
-    return pool[page_table].reshape((b, n_ptab * g) + pool.shape[2:])
+def gather_pages(pool, page_table, layer, head_dim: int):
+    """Layer ``layer`` of a stacked code (or fp) pool leaf (L, n_pages,
+    G, KV·hd) + (B, n_ptab) table -> the logical (B, n_ptab*G, KV, hd)
+    view — identical (content and shape) to the contiguous slot cache
+    over written rows, so downstream attention is bitwise the same;
+    unwritten/null rows are finite garbage masked by the causal test."""
+    view = pool[layer, page_table]
+    return view.reshape(page_table.shape[0], -1, view.shape[-1] // head_dim,
+                        head_dim)
+
+
+def gather_scales(pool, page_table, layer, page_size: int):
+    """Layer ``layer`` of a stacked scale leaf (L, n_pages, KV, lanes) +
+    (B, n_ptab) table -> the logical (B, n_ptab*G, KV, 1) per-row scales,
+    the contiguous slot cache's scale view (see ``gather_pages``)."""
+    view = jnp.swapaxes(pool[layer, page_table][..., :page_size], 2, 3)
+    return view.reshape(page_table.shape[0], -1, view.shape[-1], 1)

@@ -35,12 +35,15 @@ import pytest
 from repro.core.quantizers import pack_int4
 from repro.kernels import ops, ref
 from repro.kernels.decode_layer import decode_qkv_prologue
+from repro.kernels.paged_attention import scale_lanes
 
 HD = 8          # head_dim
 N_Q = 32        # 4 q heads
 N_KV = 16       # 2 kv heads
 PAGE = 4        # page_size
 PAGES = 10      # pool pages (page 0 = null)
+LAYERS = 2      # stacked pool layers; the prologue writes LAYER
+LAYER = 1
 
 
 def _factor(d):
@@ -68,13 +71,12 @@ def _operands(b, d, seed, n_blocks=0):
     qw = jnp.asarray(r.integers(-8, 8, (d, n)), jnp.int8)
     sw = jnp.asarray(r.uniform(0.01, 0.1, (1, n)), jnp.float32)
     kvh = N_KV // HD
-    shape = (PAGES, PAGE, kvh, HD)
-    pools = (jnp.asarray(r.integers(-128, 128, shape), jnp.int8),
-             jnp.asarray(r.uniform(0.01, 1.0, shape[:-1] + (1,)),
-                         jnp.float32),
-             jnp.asarray(r.integers(-128, 128, shape), jnp.int8),
-             jnp.asarray(r.uniform(0.01, 1.0, shape[:-1] + (1,)),
-                         jnp.float32))
+    codes = (LAYERS, PAGES, PAGE, N_KV)             # init_paged_cache's
+    scales = (LAYERS, PAGES, kvh, scale_lanes(PAGE))
+    pools = (jnp.asarray(r.integers(-128, 128, codes), jnp.int8),
+             jnp.asarray(r.uniform(0.01, 1.0, scales), jnp.float32),
+             jnp.asarray(r.integers(-128, 128, codes), jnp.int8),
+             jnp.asarray(r.uniform(0.01, 1.0, scales), jnp.float32))
     return x, blocks, ha, hb, sign, qw, sw, pools
 
 
@@ -94,22 +96,24 @@ def _run_both(b, d, seed, n_blocks=0, packed=True, pids=None, rows=None,
     kw = dict(n_q=N_Q, head_dim=HD, rope_theta=1e4, kv_bits=8, act_bits=8,
               packed=packed)
     got = decode_qkv_prologue(x, blocks, ha, hb, sign, jnp.asarray(qw_store),
-                              sw, *pools, pids, rows, positions,
+                              sw, *pools, LAYER, pids, rows, positions,
                               interpret=True, **kw, **kernel_kw)
     want = ref.decode_qkv_prologue(x, blocks, ha, hb, sign,
-                                   jnp.asarray(qw_store), sw, *pools,
+                                   jnp.asarray(qw_store), sw, *pools, LAYER,
                                    pids, rows, positions, **kw)
     return got, want, pools, (pids, rows)
 
 
 def _assert_pools_match(got, want):
-    """Pools equal outside the null page: codes bitwise, scales rtol."""
+    """Pools equal outside the null page, every layer: codes bitwise,
+    scales rtol."""
     for g, w, name in ((got[1], want[1], "k"), (got[3], want[3], "v")):
-        np.testing.assert_array_equal(np.asarray(g)[1:], np.asarray(w)[1:],
+        np.testing.assert_array_equal(np.asarray(g)[:, 1:],
+                                      np.asarray(w)[:, 1:],
                                       err_msg=f"{name} codes")
     for g, w, name in ((got[2], want[2], "k_scale"),
                        (got[4], want[4], "v_scale")):
-        np.testing.assert_allclose(np.asarray(g)[1:], np.asarray(w)[1:],
+        np.testing.assert_allclose(np.asarray(g)[:, 1:], np.asarray(w)[:, 1:],
                                    rtol=1e-5, atol=1e-8, err_msg=name)
 
 
@@ -155,7 +159,9 @@ def test_ragged_last_page():
     kp0 = np.asarray(pools[0])
     kp1 = np.asarray(got[1])
     for p, r in zip(pids, rows):
-        assert not np.array_equal(kp1[p, r], kp0[p, r])
+        assert not np.array_equal(kp1[LAYER, p, r], kp0[LAYER, p, r])
+    # the other layer is left as it was
+    np.testing.assert_array_equal(kp1[1 - LAYER], kp0[1 - LAYER])
 
 
 def test_untouched_pages_and_null_target():
@@ -167,8 +173,8 @@ def test_untouched_pages_and_null_target():
                                  rows=np.zeros(3, np.int32))
     for g, orig in ((got[1], pools[0]), (got[2], pools[1]),
                     (got[3], pools[2]), (got[4], pools[3])):
-        np.testing.assert_array_equal(np.asarray(g)[1:],
-                                      np.asarray(orig)[1:])
+        np.testing.assert_array_equal(np.asarray(g)[:, 1:],
+                                      np.asarray(orig)[:, 1:])
 
 
 def test_oracle_matches_layers_composition():
@@ -189,7 +195,7 @@ def test_oracle_matches_layers_composition():
     pids, rows = _paged_indices(table, pos, b, 1, PAGE)
     qw_p = jnp.asarray(pack_int4(np.asarray(qw), axis=0))
     q, kp, ks, vp, vs = ref.decode_qkv_prologue(
-        x, blocks, ha, hb, sign, qw_p, sw, *pools, pids, rows, pos,
+        x, blocks, ha, hb, sign, qw_p, sw, *pools, LAYER, pids, rows, pos,
         n_q=N_Q, head_dim=HD, rope_theta=1e4)
 
     # the same y rows through the layers composition
@@ -200,7 +206,7 @@ def test_oracle_matches_layers_composition():
              theta=1e4)
     v = y[:, N_Q + N_KV:].reshape(b, 1, kvh, HD)
     kp2, ks2, vp2, vs2 = paged_cache_update_quantized(
-        *pools, k, v, table, pos, 8)
+        *pools, k, v, table, pos, LAYER, 8)
     np.testing.assert_array_equal(kp, kp2)
     np.testing.assert_array_equal(vp, vp2)
     np.testing.assert_array_equal(ks, ks2)

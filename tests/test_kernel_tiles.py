@@ -8,6 +8,7 @@ runs) through the serving dispatch with ``interpret=False``, and its
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental import pallas as pl
 
 from repro.core.hadamard import hadamard_factors
 from repro.kernels import autotune, ops
@@ -81,12 +82,14 @@ def test_block_heuristics_at_catlm_widths():
 
 
 def test_ragged_attention_keeps_its_blocks(calls):
-    pool = [_s((N_PAGES, PAGE, KVH, HD), jnp.int8),
-            _s((N_PAGES, PAGE, KVH, 1), jnp.float32)] * 2
+    lanes = pa.scale_lanes(PAGE)
+    pool = [_s((8, N_PAGES, PAGE, KVH * HD), jnp.int8),
+            _s((8, N_PAGES, KVH, lanes), jnp.float32)] * 2
     jax.eval_shape(
         lambda q, *a: pa.paged_attention_ragged(q, *a, interpret=False),
         _s((SLOTS, BUDGET, KVH, N_Q // N_KV, HD), jnp.bfloat16), *pool,
-        _s((SLOTS, N_PTAB), jnp.int32), _s((SLOTS,), jnp.int32),
+        _s((), jnp.int32), _s((SLOTS, N_PTAB), jnp.int32),
+        _s((SLOTS,), jnp.int32),
         _s((SLOTS, BUDGET), jnp.int32))
     (name, grid, blocks, params), = calls
     assert name == "paged_attention_ragged"
@@ -97,8 +100,8 @@ def test_ragged_attention_keeps_its_blocks(calls):
         tile, ppb)
     assert len(grid) == 1
     assert blocks == ([(1, KVH, tile, HD), (1, tile, 1)]
-                      + ([(1, PAGE, KVH, HD)] * ppb
-                         + [(1, PAGE, KVH, 1)] * ppb) * 2)
+                      + ([(pl.squeezed, 1, PAGE, KVH * HD)] * ppb
+                         + [(pl.squeezed, 1, KVH, lanes)] * ppb) * 2)
     # at most every slot's 8 tiles over 40 blocks of its table
     _, work, blk, _ = pa._ragged_grid(
         jnp.full((SLOTS, 2 * BUDGET), -1), jnp.zeros((SLOTS,), jnp.int32),

@@ -28,6 +28,7 @@ import numpy as np
 import pytest
 
 from _hypothesis_shim import HAVE_HYPOTHESIS, given, settings, st
+from _pools import stacked, stacked_codes
 from golden import regenerate
 from repro.data import request_workload
 from repro.launch.engine import ServeEngine
@@ -274,10 +275,12 @@ def test_slot_tables_lifecycle():
 # ------------------------------------------------- kernel vs oracle
 
 def _rand_paged(seed, b=3, kvh=2, g=2, hd=16, page=8, n_ptab=3,
-                quantized_normal=False):
-    """Random pool, table and lengths. Codes and scales are uniform, or
-    with ``quantized_normal`` the int8 quantization (scale = amax / 127
-    per token and head) of unit-normal K and V, as the pool holds them."""
+                quantized_normal=False, fp=False):
+    """Random one-layer stacked pool (layer 0), table and lengths. Codes
+    and scales are uniform, or with ``quantized_normal`` the int8
+    quantization (scale = amax / 127 per token and head) of unit-normal
+    K and V, as the pool holds them. ``fp`` also returns the pool's fp
+    twin: codes times scales in f32, in the same layout."""
     from repro.kernels import ref
     rng = np.random.default_rng(seed)
     n_pages = 1 + b * n_ptab
@@ -304,8 +307,13 @@ def _rand_paged(seed, b=3, kvh=2, g=2, hd=16, page=8, n_ptab=3,
         lengths[i] = int(rng.integers(1, n_ptab * page + 1))
         n_owned = -(-int(lengths[i]) // page)
         table[i, :n_owned] = 1 + i * n_ptab + np.arange(n_owned)
-    args = (q, kp, ks, vp, vs, jnp.asarray(table), jnp.asarray(lengths))
-    return args, ref.paged_attention_decode(*args)
+    args = (q, *stacked(kp, ks, vp, vs), 0, jnp.asarray(table),
+            jnp.asarray(lengths))
+    out = (args, ref.paged_attention_decode(*args))
+    if fp:
+        out += (stacked_codes(kp.astype(jnp.float32) * ks),
+                stacked_codes(vp.astype(jnp.float32) * vs))
+    return out
 
 
 @pytest.mark.parametrize("seed", range(3))
@@ -348,7 +356,7 @@ def _block_lengths(kvh=4, g=2, hd=64, page=16, seed=5):
     n_ptab = 2 * ppb + ppb // 2 + 1
     rows = ppb * page
     lengths = [1, rows - 1, rows, rows + 1, n_ptab * page]
-    (q, kp, ks, vp, vs, _, _), _ = _rand_paged(
+    (q, kp, ks, vp, vs, _, _, _), _ = _rand_paged(
         seed, b=len(lengths), kvh=kvh, g=g, hd=hd, page=page, n_ptab=n_ptab,
         quantized_normal=True)
     table = np.zeros((len(lengths), n_ptab), np.int32)
@@ -361,7 +369,7 @@ def _block_lengths(kvh=4, g=2, hd=64, page=16, seed=5):
 def test_paged_attention_block_edge_lengths_vs_oracle():
     from repro.kernels import ops, ref
     q, kp, ks, vp, vs, table, lengths = _block_lengths()
-    args = (q, kp, ks, vp, vs, jnp.asarray(table), lengths)
+    args = (q, kp, ks, vp, vs, 0, jnp.asarray(table), lengths)
     np.testing.assert_allclose(np.asarray(ops.paged_attention(*args)),
                                np.asarray(ref.paged_attention_decode(*args)),
                                rtol=1e-5, atol=1e-5)
@@ -373,14 +381,14 @@ def test_paged_attention_dead_blocks_are_never_read():
     dead page is fetched or weighted."""
     from repro.kernels import ops
     q, kp, ks, vp, vs, table, lengths = _block_lengths()
-    base = ops.paged_attention(q, kp, ks, vp, vs, jnp.asarray(table),
+    base = ops.paged_attention(q, kp, ks, vp, vs, 0, jnp.asarray(table),
                                lengths)
-    poison = kp.shape[0]                 # one page past the pool
+    poison = kp.shape[1]                 # one page past the pool
     pad = lambda x, val: jnp.concatenate(  # noqa: E731
-        [x, jnp.full((1,) + x.shape[1:], val, x.dtype)])
+        [x, jnp.full((1, 1) + x.shape[2:], val, x.dtype)], axis=1)
     poisoned = np.where(table == 0, poison, table)
     got = ops.paged_attention(q, pad(kp, 127), pad(ks, jnp.nan),
-                              pad(vp, -127), pad(vs, jnp.nan),
+                              pad(vp, -127), pad(vs, jnp.nan), 0,
                               jnp.asarray(poisoned), lengths)
     assert (poisoned == poison).sum() > table.shape[1]
     np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
@@ -390,18 +398,19 @@ def test_paged_attention_ragged_last_page_exact_zero_weight():
     """A position past lengths[b] must contribute *exactly* nothing:
     poisoning masked rows with huge codes cannot move the output."""
     from repro.kernels import ops
-    (q, kp, ks, vp, vs, table, lengths), _ = _rand_paged(7)
-    base = ops.paged_attention(q, kp, ks, vp, vs, table, lengths)
-    page = kp.shape[1]
+    (q, kp, ks, vp, vs, layer, table, lengths), _ = _rand_paged(7)
+    base = ops.paged_attention(q, kp, ks, vp, vs, layer, table, lengths)
+    page = kp.shape[2]
     poisoned_k, poisoned_v = np.array(kp), np.array(vp)
     for b in range(q.shape[0]):
         n = int(lengths[b])
         idx, row = n // page, n % page    # first masked position
         if idx < table.shape[1] and int(table[b, idx]) > 0:
-            poisoned_k[int(table[b, idx]), row:] = 127
-            poisoned_v[int(table[b, idx]), row:] = -127
+            poisoned_k[layer, int(table[b, idx]), row:] = 127
+            poisoned_v[layer, int(table[b, idx]), row:] = -127
     got = ops.paged_attention(q, jnp.asarray(poisoned_k), ks,
-                              jnp.asarray(poisoned_v), vs, table, lengths)
+                              jnp.asarray(poisoned_v), vs, layer, table,
+                              lengths)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
 
 
@@ -410,11 +419,70 @@ def test_paged_attention_fp_pool_routes_to_fallback():
     quantized oracle on equivalent inputs: dequantizing the pool outside
     (codes·scale in f32) is the exact same op the oracle runs inside."""
     from repro.kernels import ops
-    (q, kp, ks, vp, vs, table, lengths), want = _rand_paged(13)
-    kf = kp.astype(jnp.float32) * ks
-    vf = vp.astype(jnp.float32) * vs
-    got = ops.paged_attention(q, kf, None, vf, None, table, lengths)
+    (q, _, _, _, _, layer, table, lengths), want, kf, vf = _rand_paged(
+        13, fp=True)
+    got = ops.paged_attention(q, kf, None, vf, None, layer, table, lengths)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ------------------------------------------------------- pool layout parity
+
+def test_pool_layout_keeps_the_logical_view():
+    """The stored layout moves no value: on a two-layer pool, the paged
+    quantized write into one layer, the COW page copy and the gathers of
+    every layer give bitwise what the per-head layout gave — (n_pages,
+    G, KV, hd) codes and (n_pages, G, KV, 1) scales a layer, written at
+    ``[layer, page, row]`` and gathered through the table — and the
+    stored leaves are that layout's values, laid out as
+    ``init_paged_cache`` stores them."""
+    from _pools import stacked_codes, stacked_scales
+    from repro.models.layers import (_paged_indices, copy_pool_pages,
+                                     gather_pages, gather_scales,
+                                     paged_cache_update_quantized,
+                                     quantize_kv)
+
+    rng = np.random.default_rng(0)
+    L, n_pages, G, KV, hd, b, s, layer = 2, 9, 4, 2, 8, 2, 3, 1
+    table = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
+    pos = jnp.asarray([2, 5], jnp.int32)            # rows cross pages
+    codes = lambda: rng.integers(  # noqa: E731
+        -127, 128, (L, n_pages, G, KV, hd)).astype(np.int8)
+    scales = lambda: rng.uniform(  # noqa: E731
+        0.01, 1.0, (L, n_pages, G, KV, 1)).astype(np.float32)
+    before = [jnp.asarray(a) for a in (codes(), scales(), codes(),
+                                       scales())]
+
+    def laid_out(leaves):
+        return [jnp.concatenate([(stacked_scales if i % 2 else
+                                  stacked_codes)(a[lyr])
+                                 for lyr in range(L)])
+                for i, a in enumerate(leaves)]
+
+    pools = laid_out(before)
+    k, v = (jnp.asarray(rng.normal(size=(b, s, KV, hd)), jnp.float32)
+            for _ in range(2))
+    pools = paged_cache_update_quantized(*pools, k, v, table, pos, layer, 8)
+    kq, ks = quantize_kv(k, 8)
+    vq, vs = quantize_kv(v, 8)
+    pids, rows = _paged_indices(table, pos, b, s, G)
+    before = [a.at[layer, pids, rows].set(x.reshape((b * s,) + x.shape[2:]))
+              for a, x in zip(before, (kq, ks, vq, vs))]
+    src = jnp.asarray([2, 5, 0, 0], jnp.int32)      # COW splits + padding
+    dst = jnp.asarray([7, 8, 0, 0], jnp.int32)
+    pools = [copy_pool_pages(a, src, dst) for a in pools]
+    before = [a.at[:, dst].set(a[:, src]) for a in before]
+    for got, want in zip(pools, laid_out(before)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    split = jnp.asarray([[7, 8, 3], [4, 8, 6]], jnp.int32)
+    for lyr in range(L):
+        for t in (table, split):
+            for i, leaf in enumerate(pools):
+                got = (gather_scales(leaf, t, lyr, G) if i % 2
+                       else gather_pages(leaf, t, lyr, hd))
+                want = before[i][lyr][t].reshape(
+                    (b, -1) + before[i].shape[3:])
+                np.testing.assert_array_equal(np.asarray(got),
+                                              np.asarray(want))
 
 
 # --------------------------------------------------- sharding spec checks
@@ -429,25 +497,26 @@ def test_tp_cache_specs_paged_pool():
 
     mesh = abstract_mesh((1, 2), ("data", "model"))
     L, n_pages, G, KV, hd = 2, 9, 8, 4, 16
+    codes = jax.ShapeDtypeStruct((L, n_pages, G, KV * hd), jnp.int8)
+    scales = jax.ShapeDtypeStruct((L, n_pages, KV, 128), jnp.float32)
     cache = {
-        "k": jax.ShapeDtypeStruct((L, n_pages, G, KV, hd), jnp.int8),
-        "k_scale": jax.ShapeDtypeStruct((L, n_pages, G, KV, 1),
-                                        jnp.float32),
-        "v": jax.ShapeDtypeStruct((L, n_pages, G, KV, hd), jnp.int8),
-        "v_scale": jax.ShapeDtypeStruct((L, n_pages, G, KV, 1),
-                                        jnp.float32),
+        "k": codes, "k_scale": scales, "v": codes, "v_scale": scales,
         "page_table": jax.ShapeDtypeStruct((3, 3), jnp.int32),
         "pos": jax.ShapeDtypeStruct((3,), jnp.int32),
     }
     specs = shlib.tp_cache_specs(cache, mesh, axis="model")
-    for key in ("k", "k_scale", "v", "v_scale"):
-        assert specs[key] == P(None, None, None, "model", None), key
+    for key in ("k", "v"):              # heads side by side on lanes
+        assert specs[key] == P(None, None, None, "model"), key
+    for key in ("k_scale", "v_scale"):  # heads on axis 2
+        assert specs[key] == P(None, None, "model", None), key
     assert specs["page_table"] == P(None, None)
     assert specs["pos"] == P(None)
-    # MQA-ish: heads don't divide -> whole tree replicates (congruent)
-    cache["k"] = jax.ShapeDtypeStruct((L, n_pages, G, 3, hd), jnp.int8)
-    cache["k_scale"] = jax.ShapeDtypeStruct((L, n_pages, G, 3, 1),
-                                            jnp.float32)
+    # MQA-ish: heads don't divide -> whole tree replicates (congruent),
+    # although the codes' 48 lanes would split
+    cache["k"] = cache["v"] = jax.ShapeDtypeStruct((L, n_pages, G, 3 * hd),
+                                                   jnp.int8)
+    cache["k_scale"] = cache["v_scale"] = jax.ShapeDtypeStruct(
+        (L, n_pages, 3, 128), jnp.float32)
     specs = shlib.tp_cache_specs(cache, mesh, axis="model")
-    assert specs["k"] == P(None, None, None, None, None)
-    assert specs["k_scale"] == P(None, None, None, None, None)
+    for key in ("k", "k_scale", "v", "v_scale"):
+        assert specs[key] == P(None, None, None, None), key

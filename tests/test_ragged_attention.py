@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _pools import stacked
 from repro.kernels import ops, ref
 from repro.kernels import paged_attention as pa
 from repro.kernels.paged_attention import (paged_attention_decode,
@@ -38,12 +39,13 @@ def _pool(key, n_pages, page_size, kvh, hd):
                                  jnp.float32, 0.01, 0.1)
     v_scale = jax.random.uniform(kvs, (n_pages, page_size, kvh, 1),
                                  jnp.float32, 0.01, 0.1)
-    return k_pages, k_scale, v_pages, v_scale
+    return stacked(k_pages, k_scale, v_pages, v_scale)
 
 
 def _case(seed, b, nq, kvh, g, hd, page_size, n_ptab, q_lens, lengths):
     """Build a ragged batch: row i holds q_lens[i] real query rows ending
-    at position lengths[i]-1, with distinct pages per row."""
+    at position lengths[i]-1, with distinct pages per row, over a
+    one-layer stacked pool (layer 0)."""
     key = jax.random.PRNGKey(seed)
     n_pages = 1 + b * n_ptab
     kq, kp = jax.random.split(key)
@@ -59,7 +61,7 @@ def _case(seed, b, nq, kvh, g, hd, page_size, n_ptab, q_lens, lengths):
     qpos = np.full((b, nq), -1, np.int32)
     for i, (ql, ln) in enumerate(zip(q_lens, lengths)):
         qpos[i, :ql] = ln - ql + np.arange(ql)
-    return (q, *pools, jnp.asarray(table),
+    return (q, *pools, 0, jnp.asarray(table),
             jnp.asarray(np.asarray(lengths, np.int32)),
             jnp.asarray(qpos))
 
@@ -184,15 +186,15 @@ def test_padded_query_rows_do_not_contaminate():
     a_small = _case(3, 2, 2, 2, 2, 8, 4, 2, q_lens, lengths)
     out_small = paged_attention_ragged(*a_small, interpret=True)
     # same case embedded in a wider query block
-    q, kp, ks, vp, vs, table, ln, qpos = a_small
+    q, kp, ks, vp, vs, layer, table, ln, qpos = a_small
     pad = 3
     q_wide = jnp.concatenate(
         [q, jax.random.normal(jax.random.PRNGKey(9), (2, pad, 2, 2, 8))],
         axis=1)
     qpos_wide = jnp.concatenate(
         [qpos, jnp.full((2, pad), -1, jnp.int32)], axis=1)
-    out_wide = paged_attention_ragged(q_wide, kp, ks, vp, vs, table, ln,
-                                      qpos_wide, interpret=True)
+    out_wide = paged_attention_ragged(q_wide, kp, ks, vp, vs, layer, table,
+                                      ln, qpos_wide, interpret=True)
     valid = np.asarray(qpos) >= 0
     np.testing.assert_array_equal(np.asarray(out_small)[valid],
                                   np.asarray(out_wide)[:, :2][valid])
@@ -205,13 +207,14 @@ def test_causal_horizon_masking_is_exact():
     weight."""
     q_lens, lengths = (3, 1), (5, 9)
     args = _case(5, 2, 3, 2, 2, 8, 4, 3, q_lens, lengths)
-    q, kp, ks, vp, vs, table, ln, qpos = args
+    q, kp, ks, vp, vs, layer, table, ln, qpos = args
     out = paged_attention_ragged(*args, interpret=True)
     # poison: every (page, row) whose logical position exceeds the MAX
-    # qpos of its sequence, plus the whole null page
+    # qpos of its sequence, plus the whole null page (row r of a page:
+    # codes [0, page, r], scales [0, page, :, r])
     kp2, vp2 = np.asarray(kp).copy(), np.asarray(vp).copy()
     ks2, vs2 = np.asarray(ks).copy(), np.asarray(vs).copy()
-    page_size = kp2.shape[1]
+    page_size = kp2.shape[2]
     tbl = np.asarray(table)
     for i in range(2):
         horizon = int(np.max(np.asarray(qpos)[i]))
@@ -220,14 +223,14 @@ def test_causal_horizon_masking_is_exact():
             for r in range(page_size):
                 if page == 0 or j * page_size + r > horizon:
                     if page:
-                        kp2[page, r] = 127
-                        vp2[page, r] = -127
-                        ks2[page, r] = 1e8
-                        vs2[page, r] = 1e8
-    kp2[0], vp2[0], ks2[0], vs2[0] = 127, -127, 1e8, 1e8
+                        kp2[0, page, r] = 127
+                        vp2[0, page, r] = -127
+                        ks2[0, page, :, r] = 1e8
+                        vs2[0, page, :, r] = 1e8
+    kp2[0, 0], vp2[0, 0], ks2[0, 0], vs2[0, 0] = 127, -127, 1e8, 1e8
     out2 = paged_attention_ragged(q, jnp.asarray(kp2), jnp.asarray(ks2),
                                   jnp.asarray(vp2), jnp.asarray(vs2),
-                                  table, ln, qpos, interpret=True)
+                                  layer, table, ln, qpos, interpret=True)
     valid = np.asarray(qpos) >= 0
     np.testing.assert_array_equal(np.asarray(out)[valid],
                                   np.asarray(out2)[valid])
@@ -240,27 +243,36 @@ def test_qlen1_reduces_to_decode_kernel():
     b, kvh, g, hd, page_size, n_ptab = 3, 2, 2, 8, 4, 3
     args = _case(7, b, 1, kvh, g, hd, page_size, n_ptab,
                  q_lens=(1, 1, 1), lengths=(5, 12, 1))
-    q, kp, ks, vp, vs, table, ln, qpos = args
+    q, kp, ks, vp, vs, layer, table, ln, qpos = args
     ragged = paged_attention_ragged(*args, interpret=True)
-    decode = paged_attention_decode(q[:, 0], kp, ks, vp, vs, table, ln,
-                                    interpret=True)
+    decode = paged_attention_decode(q[:, 0], kp, ks, vp, vs, layer, table,
+                                    ln, interpret=True)
     np.testing.assert_allclose(np.asarray(ragged[:, 0]),
                                np.asarray(decode), rtol=RTOL, atol=1e-6)
+
+
+def _dequantized(codes, scales):
+    """A stacked int8 pool leaf times its scales: the fp pool of the same
+    values, in the same (1, n_pages, G, KVH·hd) layout."""
+    page = codes.shape[2]
+    sc = jnp.swapaxes(scales[..., :page], 2, 3)[..., None]  # (1, n, G, KVH, 1)
+    rows = codes.reshape(sc.shape[:4] + (-1,)).astype(jnp.float32) * sc
+    return rows.reshape(codes.shape)
 
 
 def test_ops_dispatch_fp_pool_falls_back():
     """fp pools (scales None) must route to the jnp fallback and agree
     with a quantized pool dequantized up front."""
     args = _case(11, 2, 3, 2, 2, 8, 4, 2, (3, 2), (7, 4))
-    q, kp, ks, vp, vs, table, ln, qpos = args
-    k_fp = kp.astype(jnp.float32) * ks
-    v_fp = vp.astype(jnp.float32) * vs
-    via_ops = ops.ragged_paged_attention(q, k_fp, None, v_fp, None, table,
-                                         ln, qpos)
+    q, kp, ks, vp, vs, layer, table, ln, qpos = args
+    k_fp, v_fp = _dequantized(kp, ks), _dequantized(vp, vs)
+    via_ops = ops.ragged_paged_attention(q, k_fp, None, v_fp, None, layer,
+                                         table, ln, qpos)
     direct = paged_attention_ragged_fallback(q, k_fp, None, v_fp, None,
-                                             table, ln, qpos)
+                                             layer, table, ln, qpos)
     np.testing.assert_array_equal(np.asarray(via_ops), np.asarray(direct))
-    quant = ops.ragged_paged_attention(q, kp, ks, vp, vs, table, ln, qpos)
+    quant = ops.ragged_paged_attention(q, kp, ks, vp, vs, layer, table, ln,
+                                       qpos)
     valid = np.asarray(qpos) >= 0
     np.testing.assert_allclose(np.asarray(via_ops)[valid],
                                np.asarray(quant)[valid],

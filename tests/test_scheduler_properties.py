@@ -254,8 +254,8 @@ class _RaggedStubModel:
         self.cfg = cfg
 
     def init_paged_cache(self, n_pages, page_size):
-        return {"k": jnp.zeros((1, n_pages, page_size, 1, 1), jnp.float32),
-                "v": jnp.zeros((1, n_pages, page_size, 1, 1), jnp.float32)}
+        return {"k": jnp.zeros((1, n_pages, page_size, 1), jnp.float32),
+                "v": jnp.zeros((1, n_pages, page_size, 1), jnp.float32)}
 
     def ragged_step(self, params, tokens, cache, logit_rows, greedy=False,
                     **kw):

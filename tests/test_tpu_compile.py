@@ -9,13 +9,22 @@ attached) ``v5e:2x2`` topology at ``catlm_60m``'s published widths
 the compiled text must hold the Mosaic kernel (``tpu_custom_call``).
 Nothing executes, so this says nothing about results or speed.
 
+The two serving step programs are compiled whole as well, at both
+models' KV shapes, and must copy no part of the KV pool: the pool is
+stored in the layout the kernels read and rides the layer loop in place
+(``models.dense.init_paged_cache``).
+
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU compiler library, and test
 workers import every test module.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
+
+from repro.kernels.paged_attention import scale_lanes
 
 D, D_FF, N_Q, N_KV, HD = 512, 1536, 512, 256, 64
 KVH, G = N_KV // HD, N_Q // N_KV
@@ -123,21 +132,27 @@ def test_quant_matmul_compiles(one_chip, no_cache, name):
              one_chip)
 
 
+def _pool(n_pages, kvh, hd, layers=1):
+    """Pool leaves (k, k_scale, v, v_scale) as ``init_paged_cache``
+    stores them, followed by the layer index."""
+    return [((layers, n_pages, PAGE, kvh * hd), jnp.int8),
+            ((layers, n_pages, kvh, scale_lanes(PAGE)), jnp.float32)] * 2 \
+        + [((), jnp.int32)]
+
+
 def _pool_shapes():
-    return [((N_PAGES, PAGE, KVH, HD), jnp.int8),
-            ((N_PAGES, PAGE, KVH, 1), jnp.float32),
-            ((N_PAGES, PAGE, KVH, HD), jnp.int8),
-            ((N_PAGES, PAGE, KVH, 1), jnp.float32)]
+    return _pool(N_PAGES, KVH, HD)
 
 
 def test_decode_qkv_prologue_compiles(one_chip, no_cache):
     from repro.kernels.ops import decode_qkv_prologue
     n = N_Q + 2 * N_KV
 
-    def f(x, blocks, ha, hb, sign, qw, sw, kp, ks, vp, vs, pid, rid, pos):
+    def f(x, blocks, ha, hb, sign, qw, sw, kp, ks, vp, vs, lyr, pid, rid,
+          pos):
         return decode_qkv_prologue(
-            x, blocks, ha, hb, sign, qw, sw, kp, ks, vp, vs, pid, rid, pos,
-            n_q=N_Q, head_dim=HD, rope_theta=1e4, interpret=False)
+            x, blocks, ha, hb, sign, qw, sw, kp, ks, vp, vs, lyr, pid, rid,
+            pos, n_q=N_Q, head_dim=HD, rope_theta=1e4, interpret=False)
 
     _compile(f, _cat_shapes(8, D) + [((D // 2, n), jnp.int8),
                                      ((1, n), jnp.float32)]
@@ -157,9 +172,7 @@ def test_paged_attention_decode_compiles_nemo_widths(one_chip, no_cache):
     sized from the shapes, fits the default scoped VMEM."""
     from repro.kernels.paged_attention import paged_attention_decode
     kvh, hd, n_ptab = 8, 128, 320
-    n_pages = 1 + 8 * n_ptab
-    pool = [((n_pages, PAGE, kvh, hd), jnp.int8),
-            ((n_pages, PAGE, kvh, 1), jnp.float32)] * 2
+    pool = _pool(1 + 8 * n_ptab, kvh, hd)
     _compile(lambda q, *a: paged_attention_decode(q, *a, interpret=False),
              [((8, kvh, 4, hd), jnp.bfloat16)] + pool
              + [((8, n_ptab), jnp.int32), ((8,), jnp.int32)], one_chip)
@@ -198,9 +211,7 @@ def _nemo_cat_shapes(m, d):
 
 
 def _nemo_pool(n_ptab=320):
-    n_pages = 1 + 8 * n_ptab
-    return [((n_pages, PAGE, NEMO_KVH, NEMO_HD), jnp.int8),
-            ((n_pages, PAGE, NEMO_KVH, 1), jnp.float32)] * 2
+    return _pool(1 + 8 * n_ptab, NEMO_KVH, NEMO_HD)
 
 
 @pytest.mark.parametrize("m", [8, 512], ids=["gemv", "matmul"])
@@ -227,10 +238,12 @@ def test_decode_qkv_prologue_compiles_nemo_widths(one_chip, no_cache):
     from repro.kernels.ops import decode_qkv_prologue
     n = NEMO_Q + 2 * NEMO_KV
 
-    def f(x, blocks, ha, hb, sign, qw, sw, kp, ks, vp, vs, pid, rid, pos):
+    def f(x, blocks, ha, hb, sign, qw, sw, kp, ks, vp, vs, lyr, pid, rid,
+          pos):
         return decode_qkv_prologue(
-            x, blocks, ha, hb, sign, qw, sw, kp, ks, vp, vs, pid, rid, pos,
-            n_q=NEMO_Q, head_dim=NEMO_HD, rope_theta=1e6, interpret=False)
+            x, blocks, ha, hb, sign, qw, sw, kp, ks, vp, vs, lyr, pid, rid,
+            pos, n_q=NEMO_Q, head_dim=NEMO_HD, rope_theta=1e6,
+            interpret=False)
 
     _compile(f, _nemo_cat_shapes(8, NEMO_D)
              + [((NEMO_D // 2, n), jnp.int8), ((1, n), jnp.float32)]
@@ -247,10 +260,134 @@ def test_paged_attention_ragged_compiles_cell_budget(one_chip, no_cache, kvh,
     of 8 pages fit the default scoped VMEM at both models' widths."""
     from repro.kernels.paged_attention import paged_attention_ragged
     nq, n_ptab = 512, 320
-    pools = _nemo_pool(n_ptab) if pool == "nemo" else [
-        ((1 + 8 * n_ptab, PAGE, kvh, hd), jnp.int8),
-        ((1 + 8 * n_ptab, PAGE, kvh, 1), jnp.float32)] * 2
+    pools = _pool(1 + 8 * n_ptab, kvh, hd)
     _compile(lambda q, *a: paged_attention_ragged(q, *a, interpret=False),
              [((8, nq, kvh, g, hd), jnp.bfloat16)] + pools
              + [((8, n_ptab), jnp.int32), ((8,), jnp.int32),
                 ((8, nq), jnp.int32)], one_chip)
+
+
+# ------------------------------------------------- whole step programs
+
+# A pool page count that no other dimension of the step programs has, so
+# that any shape holding it is a pool leaf or a slice of one.
+GUARD_PAGES = 2579
+STEP_WIDTHS = {
+    # catlm_60m as served: d_model 512, 8/4 heads of 64, d_ff 1536
+    "catlm": dict(),
+    # Mistral-NeMo-12B's KV shape (8 KV heads of 128, groups of 4) on
+    # narrow linears: the pool's layout follows from KVH, page and hd
+    "nemo": dict(d_model=1024, n_heads=32, n_kv_heads=8, head_dim=128,
+                 d_ff=1024, vocab=1024, n_layers=2),
+}
+
+
+def _serving_params(cfg):
+    """The parameter tree ``ServeEngine`` serves on the chip for ``cfg``
+    (zeros; nothing runs): every linear a packed CAT-W4A8 ``QLinear``
+    (block CAT of 32, then Hadamard) as ``quantize_model`` makes it, fused
+    by ``make_serving_params`` for the Pallas linears."""
+    from repro.core import transforms as T
+    from repro.core.hadamard import hadamard_factors
+    from repro.core.qlinear import QLinear
+    from repro.models import dense
+
+    n = cfg.n_layers
+
+    def linear(d_in, d_out):
+        ha, hb = hadamard_factors(d_in)
+        blocks = jnp.zeros((n, d_in // 32, 32, 32))
+        t = T.Compose((T.BlockDiag(blocks, blocks),
+                       T.Hadamard(jnp.zeros((n,) + ha.shape),
+                                  jnp.zeros((n,) + hb.shape),
+                                  jnp.ones((n, d_in)))))
+        return QLinear(jnp.zeros((n, d_in // 2, d_out), jnp.int8),
+                       jnp.ones((n, 1, d_out)), t, act_bits=8, w_bits=4,
+                       d_in=d_in)
+
+    d, f = cfg.d_model, cfg.d_ff
+    layers = {"ln1": jnp.zeros((n, d)), "ln2": jnp.zeros((n, d)),
+              "wq": linear(d, cfg.q_dim), "wk": linear(d, cfg.kv_dim),
+              "wv": linear(d, cfg.kv_dim), "wo": linear(cfg.q_dim, d),
+              "wg": linear(d, f), "wu": linear(d, f), "wd": linear(f, d)}
+    params = {"embed": jnp.zeros((cfg.vocab, d)),
+              "final_norm": jnp.zeros((d,)), "layers": layers}
+    if not cfg.tie_embeddings:
+        params["unembed"] = jnp.zeros((d, cfg.vocab))
+    return dense.make_serving_params(cfg, params, keep_packed=True)
+
+
+@pytest.fixture
+def chip_routes(monkeypatch):
+    """Trace the step programs as the chip runs them: the fused decode
+    path, the Pallas linears and Mosaic kernels (not interpret mode)."""
+    from repro.core import qlinear
+    monkeypatch.setenv("REPRO_DECODE_FUSED", "1")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    monkeypatch.setattr(qlinear, "_use_fused_kernel", lambda: True)
+
+
+def _pool_copies(text: str, n_pages: int) -> list:
+    """The compiled program's ``copy`` ops whose result shape holds
+    ``n_pages``: copies of a pool leaf or of a layer's slice of one."""
+    dim = re.compile(rf"\[([0-9]+,)*{n_pages}(,[0-9]+)*\]")
+    return [line.strip() for line in text.splitlines()
+            if " copy(" in line and dim.search(line.split(" copy(")[0])]
+
+
+@pytest.mark.parametrize("step", ["decode", "unified"])
+@pytest.mark.parametrize("widths", sorted(STEP_WIDTHS))
+def test_step_program_copies_no_pool(one_chip, no_cache, chip_routes,
+                                     widths, step):
+    """``_fused_decode_step`` (the pure-decode fast path: QKV prologue and
+    paged attention) and ``_unified_step`` (XLA scatter of the step's K/V,
+    then the ragged kernel) over an 8-slot, 320-page-table pool: the
+    compiled programs hold the kernels and copy no pool leaf and no layer
+    slice of one, in the layer loop or around it."""
+    from repro.configs import get_config
+    from repro.launch import executor
+    from repro.models import build, dense
+
+    cfg = get_config("catlm_60m").scaled(kv_quant_bits=8,
+                                         **STEP_WIDTHS[widths])
+    model = build(cfg)
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(sds, jax.eval_shape(lambda: _serving_params(cfg)))
+    cache = jax.tree.map(sds, jax.eval_shape(
+        lambda: dense.init_paged_cache(cfg, GUARD_PAGES, PAGE)))
+    slots, n_ptab, budget = 8, 320, 512
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if step == "decode":
+        decode_fn = (lambda p, t, c: model.decode(p, t, c,
+                                                  paged_kernel=True))
+        lowered = executor._fused_decode_step.lower(
+            decode_fn, params, cache, i32(slots, 1), i32(slots),
+            i32(slots, n_ptab), i32(slots), i32(slots))
+        kernels = ("decode_qkv_prologue", "paged_attention_decode")
+    else:
+        desc = {"qidx": i32(slots, budget), "qpos": i32(slots, budget),
+                "lengths": i32(slots), "table": i32(slots, n_ptab),
+                "inv_seq": i32(budget), "inv_qi": i32(budget)}
+        lowered = executor._unified_step.lower(
+            model.ragged_step, True, True, params, cache, i32(budget, 1),
+            i32(budget), i32(budget, n_ptab), i32(slots), desc, i32(slots),
+            i32(budget))
+        kernels = ("paged_attention_ragged",)
+    text = lowered.compile().as_text()
+    for name in kernels:
+        assert name in text, name
+    assert _pool_copies(text, GUARD_PAGES) == []
+
+
+def test_pool_copy_finder_finds_a_pool_copy(one_chip, no_cache):
+    """The guard is not vacuous: a relayout of a pool leaf is found."""
+    text = jax.jit(lambda a: jnp.swapaxes(a, 2, 3) + 1).lower(
+        jax.ShapeDtypeStruct((2, GUARD_PAGES, 4, 16), jnp.float32,
+                             sharding=one_chip)).compile().as_text()
+    assert _pool_copies(text, GUARD_PAGES)
